@@ -26,8 +26,6 @@ def main(small: bool = True) -> None:
     emit("kernel_searchsorted", t * 1e6, f"nk={nk};nq={nq}")
     t = timed(lambda: ops.walk_hop(keys, qs, u), repeats=3)
     emit("kernel_walk_hop_fused", t * 1e6, "fuses refine+pick (1 pass)")
-    t = timed(lambda: ops.segdegree(keys), repeats=3)
-    emit("kernel_segdegree", t * 1e6, f"nk={nk}")
 
     B, H, KVH, D, S = (2, 8, 4, 128, 1024) if small else (4, 16, 8, 128, 4096)
     q = rng.standard_normal((B, H, D)).astype(np.float32)

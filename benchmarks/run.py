@@ -43,6 +43,8 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write structured records as a JSON trajectory")
     args = ap.parse_args()
+    from repro.launch.jax_cache import use_persistent_cache
+    use_persistent_cache()
     header()
     t0 = time.time()
     failures = 0

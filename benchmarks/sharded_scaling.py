@@ -8,10 +8,12 @@ size and the 1→K speedup.  Weak-scaling configuration: the per-shard round
 batch is fixed, so a K-shard mesh processes ``K×`` candidates per fused
 round — the regime a real multi-device deployment runs in.
 
-Needs K visible devices; on CPU the module sets
+Needs K visible devices.  With ``JAX_PLATFORMS=cpu`` the module sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=<K>`` *before* importing
-jax when run as a script.  From ``benchmarks.run`` (where jax is already
-initialised) the sweep re-executes itself in a subprocess with the flag set.
+jax when run as a script.  From ``benchmarks.run`` the sweep runs in the
+orchestrator's process when it holds accelerator devices (a chip belongs to
+one process, so a child could not reach them); on the CPU it re-executes
+itself in a subprocess with the flag set.
 
 Reading the numbers: host-platform devices *emulate* a mesh by running each
 shard's program in its own thread of one CPU, so the attainable samples/sec
@@ -139,11 +141,15 @@ def _respawn(argv, devices: int) -> int:
 
 
 def main(small: bool = True) -> None:
-    """benchmarks.run entry point — jax is already live there, so re-exec."""
+    """benchmarks.run entry point: jax is already live there."""
+    import jax
     argv = ["--smoke"] if small else []
-    rc = _respawn(argv, _DEF_DEVICES)
+    if jax.default_backend() == "cpu":
+        rc = _respawn(argv, _DEF_DEVICES)
+    else:
+        rc = _sweep(_parse(argv))
     if rc:
-        raise RuntimeError(f"sharded_scaling subprocess failed (rc={rc})")
+        raise RuntimeError(f"sharded_scaling sweep failed (rc={rc})")
 
 
 def _parse(argv=None):
@@ -172,8 +178,9 @@ def _parse(argv=None):
 
 if __name__ == "__main__":
     args = _parse()
-    if "xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", "") and "jax" not in sys.modules:
+    if (os.environ.get("JAX_PLATFORMS") == "cpu"
+            and "xla_force_host_platform_device_count"
+            not in os.environ.get("XLA_FLAGS", "")):
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                    " --xla_force_host_platform_device_count="
                                    f"{args.devices}").strip()

@@ -146,7 +146,7 @@ def _device_trace_args(eng, C: int) -> Tuple:
 
     eng._ensure_device_inputs()
     return (eng._init_state(), eng._out_buffer(C), jnp.int32(8),
-            eng._probs_base)
+            eng._probs_base, eng._catalog_args())
 
 
 def _host_twin_args(eng) -> Tuple:
@@ -170,8 +170,9 @@ def audit_unsharded(eng, label: str, C: int = 1024
     dev_args = _device_trace_args(eng, C)
     loop = eng._loop_for(C)
     dev_prims = collect_primitives(jax.make_jaxpr(loop)(*dev_args))
-    host_prims = collect_primitives(
-        jax.make_jaxpr(eng._round_impl)(*_host_twin_args(eng)))
+    host_prims = collect_primitives(jax.make_jaxpr(
+        lambda *a: eng._round_impl(*a[:-1], cat=a[-1]))(
+            *_host_twin_args(eng), eng._catalog_args()))
 
     findings: List[Finding] = []
     dev_rng, host_rng = rng_kinds(dev_prims), rng_kinds(host_prims)
@@ -230,11 +231,11 @@ def audit_sharded(eng, label: str, C: int = 1024
     shr = {k: state[k] for k in ("bank", "bank_head", "bank_count")}
     rep = {k: state[k] for k in run._rep_keys}
     dev_args = (shr, rep, eng._out_buffer(C), jnp.int32(8),
-                eng._probs_base, run._st_global)
+                eng._probs_base, eng._catalog_args())
     dev_prims = collect_primitives(jax.make_jaxpr(prog)(*dev_args))
     # mesh round program: (probs, dead, carry, extra, key, st[, ema, gcount])
     twin = _host_twin_args(eng)
-    host_args = twin[:5] + (run._st_global,) + twin[5:]
+    host_args = twin[:5] + (eng._catalog_args(),) + twin[5:]
     host_prims = collect_primitives(
         jax.make_jaxpr(eng._round_prog)(*host_args))
 

@@ -449,25 +449,44 @@ class DeviceTreeJoin:
     def is_empty(self) -> bool:
         return self._empty
 
+    # -- device state as a pytree -------------------------------------------
+    def device_arrays(self) -> Dict[str, object]:
+        """Every device array a draw reads, as one pytree.
+
+        The device loops pass it into their jitted programs as an argument,
+        so the catalog is never compiled into a program as constants:
+        program size, compile time and the compile-cache key stay
+        independent of the data.  Per non-root node, ``probe`` is the sorted
+        key column (``jnp.searchsorted``) or the Pallas layout (fences +
+        key blocks)."""
+        nodes = []
+        for i in range(len(self.node_cfgs)):
+            prep = self._prepped[i]
+            probe = (self.sorted_keys[i] if prep is None
+                     else (prep.f_hi2, prep.f_lo2, prep.keys2d_hi,
+                           prep.keys2d_lo))
+            nodes.append({"probe": probe, "perm": self.perm[i],
+                          "wprefix": self.wprefix[i], "cols": self.cols[i]})
+        return {"root_wprefix": self.root_wprefix,
+                "root_cols": self.root_cols, "nodes": nodes}
+
     # -- range probe: jnp.searchsorted, or the two-phase Pallas pipeline ------
     # analysis: traced
-    def _ranges(self, i: int, q: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def _ranges(self, i: int, probe, q: jnp.ndarray
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         if not self.use_pallas:
-            sk = self.sorted_keys[i]
-            return (jnp.searchsorted(sk, q, side="left").astype(jnp.int32),
-                    jnp.searchsorted(sk, q, side="right").astype(jnp.int32))
+            return (jnp.searchsorted(probe, q, side="left").astype(jnp.int32),
+                    jnp.searchsorted(probe, q, side="right").astype(jnp.int32))
         from ...kernels.ops import default_interpret
-        from ...kernels.searchsorted import QUERY_TILE, _searchsorted_i32
+        from ...kernels.searchsorted import _searchsorted_i32, to_tiles
         prep = self._prepped[i]
+        f_hi2, f_lo2, keys2d_hi, keys2d_lo = probe
         b = q.shape[0]
-        pad = (-b) % QUERY_TILE
-        qp = jnp.pad(q, (0, pad))
-        qt = qp.shape[0] // QUERY_TILE
         # keys are non-negative int32, so the 64-bit split is (hi=0, lo=q^MIN)
-        q_lo = (qp ^ jnp.int32(-(1 << 31))).reshape(qt, QUERY_TILE)
+        q_lo = to_tiles(q) ^ jnp.int32(-(1 << 31))
         q_hi = jnp.zeros_like(q_lo)
-        lo, hi = _searchsorted_i32(q_hi, q_lo, prep.f_hi2, prep.f_lo2,
-                                   prep.keys2d_hi, prep.keys2d_lo,
+        lo, hi = _searchsorted_i32(q_hi, q_lo, f_hi2, f_lo2,
+                                   keys2d_hi, keys2d_lo,
                                    n_chunks=prep.n_chunks,
                                    n_fences=prep.n_blocks,
                                    interpret=default_interpret())
@@ -477,16 +496,23 @@ class DeviceTreeJoin:
 
     # -- one batch of EW tree draws (traced; jit at the call site) ------------
     # analysis: traced
-    def draw(self, key: jax.Array, batch: int
+    def draw(self, key: jax.Array, batch: int, arrays=None
              ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray]:
-        return self.draw_with_root(key, batch, self.root_wprefix,
-                                   self.root_cols, self.n_root)
+        """``arrays`` is :meth:`device_arrays` (or its traced twin);
+        ``None`` reads this tree's own arrays."""
+        if arrays is None:
+            arrays = self.device_arrays()
+        return self.draw_with_root(key, batch, arrays["root_wprefix"],
+                                   arrays["root_cols"], self.n_root,
+                                   arrays["nodes"])
 
     # analysis: traced
-    def _residual_step(self, i: int, cfg: _NodeCfg, rows, ok, acc_ratio, u):
-        """One residual edge: sorted-key probe, uniform pick, d/M factor."""
+    def _residual_step(self, i: int, cfg: _NodeCfg, node, rows, ok,
+                       acc_ratio, u):
+        """One residual edge: sorted-key probe, uniform pick, d/M factor.
+        ``node`` is node ``i`` of :meth:`device_arrays`."""
         q = _pack_jnp(rows, cfg.edge_attrs, cfg.radices)
-        lo, hi = self._ranges(i, q)
+        lo, hi = self._ranges(i, node["probe"], q)
         d = hi - lo
         off = jnp.floor(u * jnp.maximum(d, 1).astype(jnp.float32)
                         ).astype(jnp.int32)
@@ -494,24 +520,26 @@ class DeviceTreeJoin:
         ok = ok & (d > 0)
         acc_ratio = acc_ratio * (d.astype(jnp.float32)
                                  / jnp.float32(max(cfg.max_degree, 1)))
-        child = self.perm[i][jnp.clip(pos, 0, self.perm[i].shape[0] - 1)]
-        for a, c in self.cols[i].items():
+        perm = node["perm"]
+        child = perm[jnp.clip(pos, 0, perm.shape[0] - 1)]
+        for a, c in node["cols"].items():
             rows[a] = c[child]
         return rows, ok, acc_ratio
 
     # analysis: traced
     def draw_with_root(self, key: jax.Array, batch: int,
                        root_wprefix: jnp.ndarray,
-                       root_cols: Dict[str, jnp.ndarray], n_root
+                       root_cols: Dict[str, jnp.ndarray], n_root, nodes
                        ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray,
                                   jnp.ndarray]:
         """Tree draw with a caller-supplied root slice.
 
         The sharding layer passes each shard's local root range (weight
         prefix, payload columns, row count); the non-root node indexes are
-        this tree's replicated device arrays.  ``draw`` is the degenerate
-        whole-root call, so both paths share one op sequence (and a 1-shard
-        mesh reproduces unsharded draws bit for bit).
+        this tree's replicated device arrays (``nodes``, the ``"nodes"``
+        entry of :meth:`device_arrays`).  ``draw`` is the degenerate whole-root call, so both paths
+        share one op sequence (and a 1-shard mesh reproduces unsharded
+        draws bit for bit).
 
         Returns ``(rows, accept, walk_ok)``: ``walk_ok`` marks walks whose
         every edge (tree and residual) had a match; ``accept`` additionally
@@ -527,14 +555,14 @@ class DeviceTreeJoin:
             jnp.full((batch,), n_root, jnp.int32), u0)
         rows = {a: c[r_pos] for a, c in root_cols.items()}
         acc_ratio = jnp.ones((batch,), jnp.float32)
-        for i, cfg in enumerate(self.node_cfgs):
+        for i, (cfg, node) in enumerate(zip(self.node_cfgs, nodes)):
             u = jax.random.uniform(keys[i + 1], (batch,))
             if cfg.kind == "residual":
                 rows, ok, acc_ratio = self._residual_step(
-                    i, cfg, rows, ok, acc_ratio, u)
+                    i, cfg, node, rows, ok, acc_ratio, u)
                 continue
             q = _pack_jnp(rows, cfg.edge_attrs, cfg.radices)
-            lo, hi = self._ranges(i, q)
+            lo, hi = self._ranges(i, node["probe"], q)
             if cfg.uniform:
                 d = hi - lo
                 off = jnp.floor(u * jnp.maximum(d, 1).astype(jnp.float32)
@@ -542,10 +570,11 @@ class DeviceTreeJoin:
                 pos = lo + jnp.minimum(off, jnp.maximum(d - 1, 0))
                 ok = ok & (d > 0)
             else:
-                pos, alive = _inverse_cdf_pick(self.wprefix[i], lo, hi, u)
+                pos, alive = _inverse_cdf_pick(node["wprefix"], lo, hi, u)
                 ok = ok & alive & (hi > lo)
-            child = self.perm[i][jnp.clip(pos, 0, self.perm[i].shape[0] - 1)]
-            for a, c in self.cols[i].items():
+            perm = node["perm"]
+            child = perm[jnp.clip(pos, 0, perm.shape[0] - 1)]
+            for a, c in node["cols"].items():
                 rows[a] = c[child]
         if not self.has_residual:
             return rows, ok, ok
@@ -606,13 +635,24 @@ class DeviceJoinMembership:
             self.rels.append((attrs, jnp.asarray(s1), jnp.asarray(fp2[order]),
                               kmax, int(rel.nrows)))
 
+    def device_arrays(self) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
+        """``(sorted fp1, fp2)`` per base relation: the probe's device state
+        as a pytree, passed into the device loops as an argument (see
+        :meth:`DeviceTreeJoin.device_arrays`)."""
+        return [(s1, s2) for _attrs, s1, s2, _kmax, _n in self.rels]
+
     # analysis: traced
-    def contains(self, rows: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-        """Traced probe: rows are device int32 columns of the output schema."""
+    def contains(self, rows: Dict[str, jnp.ndarray],
+                 arrays=None) -> jnp.ndarray:
+        """Traced probe: rows are device int32 columns of the output schema.
+        ``arrays`` is :meth:`device_arrays` or its traced twin (``None``
+        reads this index's own arrays)."""
+        if arrays is None:
+            arrays = self.device_arrays()
         b = rows[next(iter(rows))].shape[0]
         res = (jnp.ones((b,), bool) if self._pred_fn is None
                else self._pred_fn(rows))
-        for attrs, s1, s2, kmax, n in self.rels:
+        for (attrs, _s1, _s2, kmax, n), (s1, s2) in zip(self.rels, arrays):
             if n == 0:
                 return jnp.zeros((b,), bool)
             q1 = fp32_jnp([rows[a] for a in attrs], salt=1)
@@ -1225,12 +1265,21 @@ class JaxUnionSampler:
         overrides this to a no-op."""
         _ = self.backend.members
 
+    def _catalog_args(self) -> Dict[str, list]:
+        """The catalog's device arrays (trees + membership indexes, cover
+        order) as the pytree argument of the round programs."""
+        return {"trees": [t.device_arrays() for t in self.trees],
+                "members": [self.backend.members[n].device_arrays()
+                            for n in self.order]}
+
     def _round_core(self, key: jax.Array, probs_cum: jnp.ndarray,
                     carry_need: jnp.ndarray, extra_target: jnp.ndarray,
+                    cat: Dict[str, list],
                     ema: Optional[jnp.ndarray] = None,
                     bank_count: Optional[jnp.ndarray] = None):
         """One Algorithm-1 round (traceable; shared by the host-driven
-        wrapper and the device loop body).  Returns per join the
+        wrapper and the device loop body).  ``cat`` is
+        :meth:`_catalog_args` as traced by the caller.  Returns per join the
         accepted-compacted candidate columns plus (ok, residual, accepted,
         predicate-reject) counts and the per-piece need = carry + this
         round's targets.  Under ``plan="adaptive"`` the acceptance EMAs and
@@ -1238,10 +1287,11 @@ class JaxUnionSampler:
         budget goes out as a seventh element."""
         with jax.named_scope("algo1_fused_round"):
             return self._round_core_impl(key, probs_cum, carry_need,
-                                         extra_target, ema, bank_count)
+                                         extra_target, cat, ema, bank_count)
 
     def _round_core_impl(self, key: jax.Array, probs_cum: jnp.ndarray,
                          carry_need: jnp.ndarray, extra_target: jnp.ndarray,
+                         cat: Dict[str, list],
                          ema: Optional[jnp.ndarray] = None,
                          bank_count: Optional[jnp.ndarray] = None):
         nj = len(self.trees)
@@ -1272,7 +1322,7 @@ class JaxUnionSampler:
         cols, okc, resc, accc, predc = [], [], [], [], []
         for j, tree in enumerate(self.trees):
             bj = self.piece_batches[j]
-            rows, acc, walk_ok = tree.draw(jks[j], bj)
+            rows, acc, walk_ok = tree.draw(jks[j], bj, cat["trees"][j])
             if budget is not None:
                 # budget mask: the first budget[j] slots of an i.i.d.
                 # candidate stream — a count-derived prefix, so the
@@ -1289,7 +1339,7 @@ class JaxUnionSampler:
                 predc.append(jnp.sum(acc & ~pok).astype(jnp.int32))
                 acc = acc & pok
             for q in range(j):             # pieces earlier in cover order
-                acc = acc & ~members[q].contains(rows)
+                acc = acc & ~members[q].contains(rows, cat["members"][q])
             # (4) compaction: accepted rows to the front in slot order — a
             # rank scatter (cumsum - 1) on the (B_j, A+1) row matrix (last
             # column = home piece id, so it rides every later scatter for
@@ -1313,11 +1363,11 @@ class JaxUnionSampler:
     def _round_impl(self, probs_base: jnp.ndarray, dead: jnp.ndarray,
                     carry_need: jnp.ndarray, extra_target: jnp.ndarray,
                     key: jax.Array, ema: Optional[jnp.ndarray] = None,
-                    bank_count: Optional[jnp.ndarray] = None):
+                    bank_count: Optional[jnp.ndarray] = None, *, cat):
         """Host-driven entry point: one jitted round (fused_rounds="host")."""
         probs_cum, bad = _cover_cum(probs_base, dead)
         res = self._round_core(key, probs_cum, carry_need, extra_target,
-                               ema, bank_count)
+                               cat, ema, bank_count)
         return res + (bad,)
 
     # -- the persistent device loop -------------------------------------------
@@ -1344,7 +1394,8 @@ class JaxUnionSampler:
         The carry (state + output buffers) is donated, so repeated calls
         reuse the same device allocations; everything the host needs back —
         samples, home pieces, total, round count and the stats vector —
-        comes out of the single program invocation."""
+        comes out of the single program invocation.  The catalog comes in
+        as the last argument (:meth:`_catalog_args`), never as constants."""
         cap = self.surplus_cap
         W = min(self._drain_w, cap)
         bt = int(sum(self.piece_batches))
@@ -1355,7 +1406,7 @@ class JaxUnionSampler:
         pbatch = jnp.asarray(self.piece_batches, jnp.int32)
         shifts = jnp.asarray(self._ema_shifts)
 
-        def loop_fn(state, out, n, probs_base):
+        def loop_fn(state, out, n, probs_base, cat):
             self._trace_events.append(("loop", C, self.plan))
 
             def cond(c):
@@ -1371,12 +1422,12 @@ class JaxUnionSampler:
                 if adaptive:
                     cols, okc, resc, accc, predc, need, budget = \
                         self._round_core(kround, probs_cum, state["owed"],
-                                         extra, state["ema"],
+                                         extra, cat, state["ema"],
                                          state["bank_count"])
                 else:
                     budget = None
                     cols, okc, resc, accc, predc, need = self._round_core(
-                        kround, probs_cum, state["owed"], extra)
+                        kround, probs_cum, state["owed"], extra, cat)
                 # bank take (FIFO, capped) → fresh take → carried shortfall
                 dt = jnp.minimum(jnp.minimum(need, state["bank_count"]),
                                  self._drain_w)
@@ -1476,7 +1527,8 @@ class JaxUnionSampler:
         out = self._out_buffer(C)
         with _dispatch_annotation():
             st, out, total, rounds, fail, stats, pstats = self._loop_for(C)(
-                self._dev_state, out, jnp.int32(n), self._probs_base)
+                self._dev_state, out, jnp.int32(n), self._probs_base,
+                self._catalog_args())
         self._dev_state = st
         # the output shuffle is host randomness, drawn at dispatch time so
         # both modes consume host_rng identically (one permutation per call)
@@ -1638,14 +1690,15 @@ class JaxUnionSampler:
                         self._probs_base, jnp.asarray(dead),
                         jnp.asarray(owed.astype(np.int32)),
                         jnp.int32(extra), sub, jnp.asarray(self._h_ema),
-                        jnp.asarray(count.astype(np.int32)))
+                        jnp.asarray(count.astype(np.int32)),
+                        cat=self._catalog_args())
                 budget = np.asarray(budget)
             else:
                 budget = None
                 cols, okc, resc, accc, predc, need, bad = self._round_jit(
                     self._probs_base, jnp.asarray(dead),
                     jnp.asarray(owed.astype(np.int32)), jnp.int32(extra),
-                    sub)
+                    sub, cat=self._catalog_args())
             if bool(np.asarray(bad)):
                 raise RuntimeError("all cover pieces unreachable")
             okc = np.asarray(okc).astype(np.int64)

@@ -135,18 +135,14 @@ class DeviceWalkJoin:
             off = jnp.minimum(off, jnp.maximum(d - 1, 0))
             return lo + off, d
         from ...kernels.ops import default_interpret
-        from ...kernels.searchsorted import QUERY_TILE
+        from ...kernels.searchsorted import to_tiles
         from ...kernels.walk import _hop_i32
         prep = self._prepped[i]
         b = q.shape[0]
-        pad = (-b) % QUERY_TILE
-        qp = jnp.pad(q, (0, pad))
-        up = jnp.pad(u.astype(jnp.float32), (0, pad))
-        qt = qp.shape[0] // QUERY_TILE
         # keys are non-negative int32, so the 64-bit split is (hi=0, lo=q^MIN)
-        q_lo = (qp ^ jnp.int32(-(1 << 31))).reshape(qt, QUERY_TILE)
+        q_lo = to_tiles(q) ^ jnp.int32(-(1 << 31))
         q_hi = jnp.zeros_like(q_lo)
-        pos, deg = _hop_i32(q_hi, q_lo, up.reshape(qt, QUERY_TILE),
+        pos, deg = _hop_i32(q_hi, q_lo, to_tiles(u.astype(jnp.float32)),
                             prep.f_hi2, prep.f_lo2,
                             prep.keys2d_hi, prep.keys2d_lo,
                             n_chunks=prep.n_chunks, n_fences=prep.n_blocks,
@@ -326,7 +322,6 @@ class JaxEstimator(EstimationLoop):
                                                    *_batch_moments(contrib))
                     return rows, prob, size_state, overlap_state
             else:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
                 from ..sharding.stats import psum_merge_moments
                 axis, world = self.mesh_axis, self.world
@@ -348,9 +343,9 @@ class JaxEstimator(EstimationLoop):
                             tuple(x[None] for x in smom),
                             tuple(x[None] for x in omom))
 
-                sharded = shard_map(shard_run, mesh=self.mesh,
-                                    in_specs=(P(),), out_specs=P(axis),
-                                    check_rep=False)
+                sharded = jax.shard_map(shard_run, mesh=self.mesh,
+                                        in_specs=(P(),), out_specs=P(axis),
+                                        check_vma=False)
 
                 def run(k, size_state, overlap_state):
                     rows, prob, smom, omom = sharded(k)

@@ -135,6 +135,10 @@ class ShardedTreeJoin:
                           for a, c in cols_stk.items()}
         self.n_root = _shard_put(
             mesh, axis, np.full(world, n_root, dtype=np.int32))
+        # the non-root node indexes, replicated once on every device (the
+        # sampler passes them into its programs as a P() argument)
+        self.nodes = jax.device_put(tree.device_arrays()["nodes"],
+                                    NamedSharding(mesh, P()))
 
     def is_empty(self) -> bool:
         return self.tree.is_empty()

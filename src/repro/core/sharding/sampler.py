@@ -62,7 +62,6 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..backends.jax_backend import (PIECE_STAT_FIELDS, _STAT_FIELDS,
@@ -136,8 +135,13 @@ class ShardedUnionSampler(JaxUnionSampler):
         self.strees = [scat.trees[n] for n in self.order]
         self.smems = [scat.members[n] for n in self.order]
         self._dtrees = [t.tree for t in self.strees]
+        # the catalog argument of the programs: per-shard root slices and
+        # membership partitions, plus the replicated non-root node indexes
         self._state = {"roots": [t.state() for t in self.strees],
-                       "mem": [m.state() for m in self.smems]}
+                       "mem": [m.state() for m in self.smems],
+                       "trees": [t.nodes for t in self.strees]}
+        self._state_spec = {"roots": P(self.saxis), "mem": P(self.saxis),
+                            "trees": P()}
         # flat probe plan: (join j, earlier piece q, relation ridx, ...)
         self._probe_plan: List[Tuple[int, int, int, Tuple[str, ...], int]] = []
         for j in range(len(self.order)):
@@ -147,7 +151,12 @@ class ShardedUnionSampler(JaxUnionSampler):
         self._round_prog = self._build_round_prog()
         self._round_jit = self._sharded_round      # host-loop entry point
 
-    # -- device-input hook ----------------------------------------------------
+    # -- device-input hooks ---------------------------------------------------
+    def _catalog_args(self):
+        """The mesh programs' catalog argument: per-shard roots and
+        membership partitions plus the replicated node indexes."""
+        return self._state
+
     def _ensure_device_inputs(self) -> None:
         """No-op: the sharded engine's tree/membership state is prebuilt in
         ``self._state`` (hash-partitioned device arrays), so nothing lazy
@@ -201,7 +210,7 @@ class ShardedUnionSampler(JaxUnionSampler):
             kd = (jks[j] if world == 1          # bit-for-bit unsharded
                   else jax.random.fold_in(jks[j], sid))
             rows, ok, wok = self._dtrees[j].draw_with_root(
-                kd, bs[j], prefix, cols, rst["n_root"][0])
+                kd, bs[j], prefix, cols, rst["n_root"][0], st["trees"][j])
             if bshard is not None:
                 elig = jnp.arange(bs[j]) < bshard[j]
                 ok = ok & elig
@@ -336,7 +345,7 @@ class ShardedUnionSampler(JaxUnionSampler):
                         accc[None], predc[None], need[None], gb[None],
                         bad[None])
 
-            in_specs = (P(), P(), P(), P(), P(), P(axis), P(), P())
+            in_specs = (P(), P(), P(), P(), P(), self._state_spec, P(), P())
         else:
             def round_fn(probs_base, dead, carry_need, extra_target, key,
                          st):
@@ -347,15 +356,15 @@ class ShardedUnionSampler(JaxUnionSampler):
                 return ([m[None] for m in mats], okc[None], resc[None],
                         accc[None], predc[None], need[None], bad[None])
 
-            in_specs = (P(), P(), P(), P(), P(), P(axis))
+            in_specs = (P(), P(), P(), P(), P(), self._state_spec)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             round_fn, mesh=mesh,
             in_specs=in_specs,
-            out_specs=P(axis), check_rep=False))
+            out_specs=P(axis), check_vma=False))
 
     def _sharded_round(self, probs_base, dead, carry_need, extra_target,
-                       key, ema=None, bank_count=None):
+                       key, ema=None, bank_count=None, *, cat):
         """Run one mesh round; adapt it to the host-loop contract.
 
         ``cols[j]``'s first ``accc[j]`` rows are the accepted rows in
@@ -369,11 +378,11 @@ class ShardedUnionSampler(JaxUnionSampler):
             (mats, okc, resc, accc, predc, need, budget,
              bad) = self._round_prog(
                 probs_base, dead, carry_need, extra_target, key,
-                self._state, ema, bank_count)
+                cat, ema, bank_count)
             budget = np.asarray(budget)[0]
         else:
             mats, okc, resc, accc, predc, need, bad = self._round_prog(
-                probs_base, dead, carry_need, extra_target, key, self._state)
+                probs_base, dead, carry_need, extra_target, key, cat)
         okc = np.asarray(okc)
         resc = np.asarray(resc)
         accc = np.asarray(accc)                     # (world, nj)
@@ -436,7 +445,6 @@ class ShardedUnionSampler(JaxUnionSampler):
         adaptive = self.plan == "adaptive"
         max_rounds = jnp.int32(self.max_rounds)
         dead_rounds = jnp.int32(self.dead_rounds)
-        st_global = self._state
 
         pbatch = jnp.asarray(self.piece_batches, jnp.int32)
         shifts = jnp.asarray(self._ema_shifts)
@@ -577,17 +585,18 @@ class ShardedUnionSampler(JaxUnionSampler):
         if adaptive:
             rep_keys = rep_keys + ("ema", "gcount")
         rep_spec = {k: P() for k in rep_keys}
-        prog = jax.jit(shard_map(
+        prog = jax.jit(jax.shard_map(
             loop_fn, mesh=mesh,
-            in_specs=(shr_spec, rep_spec, P(axis), P(), P(), P(axis)),
-            out_specs=P(axis), check_rep=False),
+            in_specs=(shr_spec, rep_spec, P(axis), P(), P(),
+                      self._state_spec),
+            out_specs=P(axis), check_vma=False),
             donate_argnums=(0, 2))
 
-        def run(state, out, n, probs_base):
+        def run(state, out, n, probs_base, cat):
             shr = {k: state[k] for k in ("bank", "bank_head", "bank_count")}
             rep = {k: state[k] for k in rep_keys}
             shr2, rep2, out2, total, rounds, fail, stats, pstats = prog(
-                shr, rep, out, n, probs_base, st_global)
+                shr, rep, out, n, probs_base, cat)
             state2 = dict(shr2)
             state2.update({k: v[0] for k, v in rep2.items()})
             return (state2, out2, total[0], rounds[0], fail[0], stats[0],
@@ -597,5 +606,4 @@ class ShardedUnionSampler(JaxUnionSampler):
         # analyzer (repro.analysis.jaxpr_audit) can lower it without running
         run._prog = prog
         run._rep_keys = rep_keys
-        run._st_global = st_global
         return run

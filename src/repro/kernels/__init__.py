@@ -2,9 +2,8 @@
 
 searchsorted — two-phase tiled sorted probe (fence sweep + refine)
 walk         — fused wander-join hop (refine + ranged uniform pick)
-segdegree    — single-pass distinct/max-degree over sorted keys
 attention    — flash-decoding GQA w/ softcap + sliding window (model-side)
-ops          — public jit'd wrappers (interpret=True off-TPU)
+ops          — public jit'd wrappers (interpret=True on the CPU only)
 ref          — pure jnp/numpy oracles
 """
 

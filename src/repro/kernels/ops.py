@@ -1,7 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; TPU v5e is
-the compile target).  On a real TPU backend the same calls lower via Mosaic.
+``interpret`` is True only on the CPU backend, where Pallas has no compiler.
+On any other backend the kernels lower via Mosaic, or the call fails: a
+kernel never falls back to the interpreter on an accelerator.
 
 ``ranged_weighted_pick`` — the Exact-Weight child-pick primitive — composes
 the searchsorted kernel over the *bit-cast* prefix-sum array: non-negative
@@ -18,7 +19,6 @@ import numpy as np
 
 from .attention import decode_attention_pallas
 from .searchsorted import PreparedKeys, searchsorted_pallas
-from .segdegree import segdegree_pallas
 from .walk import walk_hop_pallas
 
 
@@ -27,7 +27,7 @@ def on_tpu() -> bool:
 
 
 def default_interpret() -> bool:
-    return not on_tpu()
+    return jax.default_backend() == "cpu"
 
 
 def searchsorted(keys, queries) -> Tuple[np.ndarray, np.ndarray]:
@@ -36,10 +36,6 @@ def searchsorted(keys, queries) -> Tuple[np.ndarray, np.ndarray]:
 
 def walk_hop(keys, queries, u) -> Tuple[np.ndarray, np.ndarray]:
     return walk_hop_pallas(keys, queries, u, interpret=default_interpret())
-
-
-def segdegree(sorted_keys) -> Tuple[int, int]:
-    return segdegree_pallas(sorted_keys, interpret=default_interpret())
 
 
 def decode_attention(q, k, v, lengths, scale: Optional[float] = None,

@@ -24,13 +24,6 @@ def walk_hop_ref(keys: np.ndarray, queries: np.ndarray, u: np.ndarray
     return lo + off, d
 
 
-def segdegree_ref(sorted_keys: np.ndarray) -> Tuple[int, int]:
-    if sorted_keys.shape[0] == 0:
-        return 0, 0
-    _, counts = np.unique(sorted_keys, return_counts=True)
-    return int(counts.shape[0]), int(counts.max())
-
-
 def ranged_weighted_pick_ref(cs: np.ndarray, lo: np.ndarray, hi: np.ndarray,
                              u: np.ndarray) -> np.ndarray:
     """Weighted pick inside [lo, hi) via prefix sums cs (len n+1)."""

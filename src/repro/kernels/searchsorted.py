@@ -74,8 +74,8 @@ def fence_count_kernel(q_hi_ref, q_lo_ref, f_hi_ref, f_lo_ref,
                        blk_l_ref, blk_r_ref, *, n_chunks: int,
                        n_fences: int):
     """Per query: block ids of the lo/hi boundaries (broadcast-compare sweep)."""
-    q_hi = q_hi_ref[0, :]                     # (TQ,)
-    q_lo = q_lo_ref[0, :]
+    q_hi = q_hi_ref[0, 0, :]                  # (TQ,)
+    q_lo = q_lo_ref[0, 0, :]
     tq = q_hi.shape[0]
     acc_l = jnp.zeros((tq,), jnp.int32)
     acc_r = jnp.zeros((tq,), jnp.int32)
@@ -93,8 +93,8 @@ def fence_count_kernel(q_hi_ref, q_lo_ref, f_hi_ref, f_lo_ref,
                 acc_r + jnp.sum(le.astype(jnp.int32), axis=1))
 
     acc_l, acc_r = jax.lax.fori_loop(0, n_chunks, body, (acc_l, acc_r))
-    blk_l_ref[0, :] = jnp.clip(acc_l - 1, 0, None)
-    blk_r_ref[0, :] = jnp.clip(acc_r - 1, 0, None)
+    blk_l_ref[0, 0, :] = jnp.clip(acc_l - 1, 0, None)
+    blk_r_ref[0, 0, :] = jnp.clip(acc_r - 1, 0, None)
 
 
 # ---------------------------------------------------------------------------
@@ -105,37 +105,62 @@ def fence_count_kernel(q_hi_ref, q_lo_ref, f_hi_ref, f_lo_ref,
 def refine_kernel(q_hi_ref, q_lo_ref, blk_l_ref, blk_r_ref,
                   row_l_hi_ref, row_l_lo_ref, row_r_hi_ref, row_r_lo_ref,
                   lo_ref, hi_ref):
-    q_hi = q_hi_ref[0, :][:, None]            # (TQ, 1)
-    q_lo = q_lo_ref[0, :][:, None]
+    q_hi = q_hi_ref[0, 0, :][:, None]         # (TQ, 1)
+    q_lo = q_lo_ref[0, 0, :][:, None]
     lt = _lt(row_l_hi_ref[0], row_l_lo_ref[0], q_hi, q_lo)
     le = _le(row_r_hi_ref[0], row_r_lo_ref[0], q_hi, q_lo)
-    lo_ref[0, :] = blk_l_ref[0, :] * KEY_BLOCK + jnp.sum(lt.astype(jnp.int32), axis=1)
-    hi_ref[0, :] = blk_r_ref[0, :] * KEY_BLOCK + jnp.sum(le.astype(jnp.int32), axis=1)
+    lo_ref[0, 0, :] = (blk_l_ref[0, 0, :] * KEY_BLOCK
+                       + jnp.sum(lt.astype(jnp.int32), axis=1))
+    hi_ref[0, 0, :] = (blk_r_ref[0, 0, :] * KEY_BLOCK
+                       + jnp.sum(le.astype(jnp.int32), axis=1))
 
 
 # ---------------------------------------------------------------------------
 # Jitted int32 pipeline + host prep
 # ---------------------------------------------------------------------------
 
+# Per-query vectors travel as (qt, 1, QUERY_TILE) arrays in (1, 1, QUERY_TILE)
+# blocks: Mosaic requires a block's last two dims to be multiples of (8, 128)
+# or equal to the array's, and the unit axis makes the sublane dim equal.
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_chunks", "n_fences", "interpret"))
-def _searchsorted_i32(q_hi2, q_lo2, f_hi2, f_lo2, keys2d_hi, keys2d_lo,
-                      n_chunks: int, n_fences: int, interpret: bool = True):
-    qt = q_hi2.shape[0]
-    tile_specs = [pl.BlockSpec((1, QUERY_TILE), lambda i: (i, 0))] * 2
-    blk_l, blk_r = pl.pallas_call(
+
+def tile_spec() -> pl.BlockSpec:
+    return pl.BlockSpec((1, 1, QUERY_TILE), lambda i: (i, 0, 0))
+
+
+def tiles_shape(qt: int, dtype=jnp.int32) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct((qt, 1, QUERY_TILE), dtype)
+
+
+def to_tiles(x: jnp.ndarray) -> jnp.ndarray:
+    """Zero-pad a (b,) query vector to whole tiles: (qt, 1, QUERY_TILE)."""
+    x = jnp.pad(x, (0, (-x.shape[0]) % QUERY_TILE))
+    return x.reshape(-1, 1, QUERY_TILE)
+
+
+def fence_blocks(q_hi3, q_lo3, f_hi2, f_lo2, n_chunks: int, n_fences: int,
+                 interpret: bool):
+    """Phase A over all query tiles: per-query lo/hi boundary block ids."""
+    qt = q_hi3.shape[0]
+    fences = pl.BlockSpec((n_chunks, FENCE_CHUNK), lambda i: (0, 0))
+    return pl.pallas_call(
         functools.partial(fence_count_kernel, n_chunks=n_chunks,
                           n_fences=n_fences),
         grid=(qt,),
-        in_specs=tile_specs + [
-            pl.BlockSpec((n_chunks, FENCE_CHUNK), lambda i: (0, 0)),
-            pl.BlockSpec((n_chunks, FENCE_CHUNK), lambda i: (0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, QUERY_TILE), lambda i: (i, 0))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((qt, QUERY_TILE), jnp.int32)] * 2,
+        in_specs=[tile_spec(), tile_spec(), fences, fences],
+        out_specs=[tile_spec()] * 2,
+        out_shape=[tiles_shape(qt)] * 2,
         interpret=interpret,
-    )(q_hi2, q_lo2, f_hi2, f_lo2)
+    )(q_hi3, q_lo3, f_hi2, f_lo2)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_chunks", "n_fences", "interpret"))
+def _searchsorted_i32(q_hi3, q_lo3, f_hi2, f_lo2, keys2d_hi, keys2d_lo,
+                      n_chunks: int, n_fences: int, interpret: bool = True):
+    qt = q_hi3.shape[0]
+    blk_l, blk_r = fence_blocks(q_hi3, q_lo3, f_hi2, f_lo2, n_chunks,
+                                n_fences, interpret)
 
     # XLA row-gather of refinement blocks
     bl = blk_l.reshape(-1)
@@ -148,12 +173,12 @@ def _searchsorted_i32(q_hi2, q_lo2, f_hi2, f_lo2, keys2d_hi, keys2d_lo,
     lo, hi = pl.pallas_call(
         refine_kernel,
         grid=(qt,),
-        in_specs=tile_specs * 2 + [
+        in_specs=[tile_spec()] * 4 + [
             pl.BlockSpec((1, QUERY_TILE, KEY_BLOCK), lambda i: (i, 0, 0))] * 4,
-        out_specs=[pl.BlockSpec((1, QUERY_TILE), lambda i: (i, 0))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((qt, QUERY_TILE), jnp.int32)] * 2,
+        out_specs=[tile_spec()] * 2,
+        out_shape=[tiles_shape(qt)] * 2,
         interpret=interpret,
-    )(q_hi2, q_lo2, blk_l, blk_r, row_l_hi, row_l_lo, row_r_hi, row_r_lo)
+    )(q_hi3, q_lo3, blk_l, blk_r, row_l_hi, row_l_lo, row_r_hi, row_r_lo)
     return lo, hi
 
 
@@ -185,8 +210,8 @@ def searchsorted_pallas(keys, queries, interpret: bool = True
     q_hi, q_lo = split64_np(qp)
     qt = qp.shape[0] // QUERY_TILE
     lo, hi = _searchsorted_i32(
-        jnp.asarray(q_hi.reshape(qt, QUERY_TILE)),
-        jnp.asarray(q_lo.reshape(qt, QUERY_TILE)),
+        jnp.asarray(q_hi.reshape(qt, 1, QUERY_TILE)),
+        jnp.asarray(q_lo.reshape(qt, 1, QUERY_TILE)),
         prep.f_hi2, prep.f_lo2, prep.keys2d_hi, prep.keys2d_lo,
         n_chunks=prep.n_chunks, n_fences=prep.n_blocks, interpret=interpret)
     lo = np.minimum(np.asarray(lo).reshape(-1)[:nq], prep.n)

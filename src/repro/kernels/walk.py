@@ -20,7 +20,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from .searchsorted import (KEY_BLOCK, QUERY_TILE, PreparedKeys, _le, _lt,
-                           _pad_np, fence_count_kernel, split64_np)
+                           _pad_np, fence_blocks, split64_np, tile_spec,
+                           tiles_shape)
 
 
 def hop_refine_pick_kernel(q_hi_ref, q_lo_ref, blk_l_ref, blk_r_ref,
@@ -28,37 +29,27 @@ def hop_refine_pick_kernel(q_hi_ref, q_lo_ref, blk_l_ref, blk_r_ref,
                            row_r_hi_ref, row_r_lo_ref,
                            u_ref, pos_ref, deg_ref):
     """Fused: exact [lo,hi) + ranged uniform pick + degree output."""
-    q_hi = q_hi_ref[0, :][:, None]
-    q_lo = q_lo_ref[0, :][:, None]
+    q_hi = q_hi_ref[0, 0, :][:, None]
+    q_lo = q_lo_ref[0, 0, :][:, None]
     lt = _lt(row_l_hi_ref[0], row_l_lo_ref[0], q_hi, q_lo)
     le = _le(row_r_hi_ref[0], row_r_lo_ref[0], q_hi, q_lo)
-    lo = blk_l_ref[0, :] * KEY_BLOCK + jnp.sum(lt.astype(jnp.int32), axis=1)
-    hi = blk_r_ref[0, :] * KEY_BLOCK + jnp.sum(le.astype(jnp.int32), axis=1)
+    lo = blk_l_ref[0, 0, :] * KEY_BLOCK + jnp.sum(lt.astype(jnp.int32), axis=1)
+    hi = blk_r_ref[0, 0, :] * KEY_BLOCK + jnp.sum(le.astype(jnp.int32), axis=1)
     d = hi - lo
-    u = u_ref[0, :]
+    u = u_ref[0, 0, :]
     off = jnp.floor(u * jnp.maximum(d, 1).astype(jnp.float32)).astype(jnp.int32)
     off = jnp.minimum(off, jnp.maximum(d - 1, 0))
-    pos_ref[0, :] = lo + off
-    deg_ref[0, :] = d
+    pos_ref[0, 0, :] = lo + off
+    deg_ref[0, 0, :] = d
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_chunks", "n_fences", "interpret"))
-def _hop_i32(q_hi2, q_lo2, u2, f_hi2, f_lo2, keys2d_hi, keys2d_lo,
+def _hop_i32(q_hi3, q_lo3, u3, f_hi2, f_lo2, keys2d_hi, keys2d_lo,
              n_chunks: int, n_fences: int, interpret: bool = True):
-    qt = q_hi2.shape[0]
-    tile = pl.BlockSpec((1, QUERY_TILE), lambda i: (i, 0))
-    blk_l, blk_r = pl.pallas_call(
-        functools.partial(fence_count_kernel, n_chunks=n_chunks,
-                          n_fences=n_fences),
-        grid=(qt,),
-        in_specs=[tile, tile,
-                  pl.BlockSpec((n_chunks, 128), lambda i: (0, 0)),
-                  pl.BlockSpec((n_chunks, 128), lambda i: (0, 0))],
-        out_specs=[tile, tile],
-        out_shape=[jax.ShapeDtypeStruct((qt, QUERY_TILE), jnp.int32)] * 2,
-        interpret=interpret,
-    )(q_hi2, q_lo2, f_hi2, f_lo2)
+    qt = q_hi3.shape[0]
+    blk_l, blk_r = fence_blocks(q_hi3, q_lo3, f_hi2, f_lo2, n_chunks,
+                                n_fences, interpret)
 
     bl, br = blk_l.reshape(-1), blk_r.reshape(-1)
     rl_hi = keys2d_hi[bl].reshape(qt, QUERY_TILE, KEY_BLOCK)
@@ -66,15 +57,16 @@ def _hop_i32(q_hi2, q_lo2, u2, f_hi2, f_lo2, keys2d_hi, keys2d_lo,
     rr_hi = keys2d_hi[br].reshape(qt, QUERY_TILE, KEY_BLOCK)
     rr_lo = keys2d_lo[br].reshape(qt, QUERY_TILE, KEY_BLOCK)
 
+    tile = tile_spec()
     row = pl.BlockSpec((1, QUERY_TILE, KEY_BLOCK), lambda i: (i, 0, 0))
     pos, deg = pl.pallas_call(
         hop_refine_pick_kernel,
         grid=(qt,),
         in_specs=[tile, tile, tile, tile, row, row, row, row, tile],
         out_specs=[tile, tile],
-        out_shape=[jax.ShapeDtypeStruct((qt, QUERY_TILE), jnp.int32)] * 2,
+        out_shape=[tiles_shape(qt)] * 2,
         interpret=interpret,
-    )(q_hi2, q_lo2, blk_l, blk_r, rl_hi, rl_lo, rr_hi, rr_lo, u2)
+    )(q_hi3, q_lo3, blk_l, blk_r, rl_hi, rl_lo, rr_hi, rr_lo, u3)
     return pos, deg
 
 
@@ -89,9 +81,9 @@ def walk_hop_pallas(keys, queries, u, interpret: bool = True
     q_hi, q_lo = split64_np(qp)
     qt = qp.shape[0] // QUERY_TILE
     pos, deg = _hop_i32(
-        jnp.asarray(q_hi.reshape(qt, QUERY_TILE)),
-        jnp.asarray(q_lo.reshape(qt, QUERY_TILE)),
-        jnp.asarray(up.reshape(qt, QUERY_TILE)),
+        jnp.asarray(q_hi.reshape(qt, 1, QUERY_TILE)),
+        jnp.asarray(q_lo.reshape(qt, 1, QUERY_TILE)),
+        jnp.asarray(up.reshape(qt, 1, QUERY_TILE)),
         prep.f_hi2, prep.f_lo2, prep.keys2d_hi, prep.keys2d_lo,
         n_chunks=prep.n_chunks, n_fences=prep.n_blocks, interpret=interpret)
     pos = np.minimum(np.asarray(pos).reshape(-1)[:nq], max(prep.n - 1, 0))

@@ -39,7 +39,7 @@ from ..models.transformer import (ModelConfig, logical_axes, param_specs)
 from ..train.optimizer import default_opt_for
 from ..train.train_step import (TrainConfig, make_train_step,
                                 train_state_logical_axes, train_state_specs)
-from .mesh import make_production_mesh, set_mesh
+from .mesh import make_production_mesh
 from .sharding import (batch_is_sharded, batch_sharding, frontend_sharding,
                        replicated, tree_shardings)
 
@@ -239,7 +239,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
                for k in batch}
         fn = jax.jit(step_fn, in_shardings=(state_sh, bsh),
                      donate_argnums=(0,))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(state_specs, batch)
     elif cell.kind == "prefill":
         def fn_prefill(params, batch):
@@ -251,7 +251,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
                    if k == "frontend" else batch_sharding(mesh, cell.global_batch))
                for k in batch}
         fn = jax.jit(fn_prefill, in_shardings=(psh, bsh))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(pspecs, batch)
     else:  # decode
         def fn_decode(params, cache, tokens, lengths):
@@ -266,7 +266,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
         tsh = batch_sharding(mesh, cell.global_batch)
         fn = jax.jit(fn_decode, in_shardings=(psh, csh, tsh, tsh),
                      donate_argnums=(1,))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(pspecs, specs["cache"], specs["tokens"],
                                specs["lengths"])
 
@@ -295,8 +295,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
     # raw cost_analysis counts loop bodies ONCE (a lax.scan over 88 layers is
     # under-counted 88x) — kept for reference; the census below re-derives
     # FLOPs/bytes/collectives from the HLO text with while-trip scaling.
-    from .mesh import cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     res["cost_raw"] = {"flops": float(ca.get("flops", 0.0)),
                        "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
 

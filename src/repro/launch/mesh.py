@@ -1,4 +1,4 @@
-"""Production mesh construction + JAX mesh-API compatibility shims.
+"""Production mesh construction.
 
 Single pod: (16, 16) = ("data", "model") — 256 chips (one TPU v5e pod).
 Multi-pod: (2, 16, 16) = ("pod", "data", "model") — 512 chips.  The "pod"
@@ -8,15 +8,6 @@ optional int8-compressed variant in train/grad_compress.py).
 
 A FUNCTION, not a module constant: importing this module must never touch
 jax device state (the dry-run sets XLA_FLAGS before any jax import).
-
-Compatibility: the pinned JAX (0.4.x) predates ``jax.sharding.AxisType``,
-``jax.set_mesh``, ``jax.shard_map``, and ``jax.sharding.get_abstract_mesh``.
-The shims below (:func:`make_mesh`, :func:`set_mesh`, :func:`ambient_mesh`,
-``shard_map``) present the new-style surface on both API generations; all
-mesh construction in src/ and tests/ routes through them so an API drift
-fails in exactly one module with a clear error instead of scattering
-``AttributeError: module 'jax.sharding' has no attribute ...`` across the
-suite (see requirements-dev.txt for the version floor).
 """
 
 from __future__ import annotations
@@ -25,68 +16,19 @@ from typing import Sequence
 
 import jax
 
-try:                                    # JAX >= 0.6
-    from jax import shard_map           # noqa: F401  (re-exported shim)
-except ImportError:                     # 0.4.x: the experimental module
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def _axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=`` keyword for ``jax.make_mesh`` where supported.
-
-    ``jax.sharding.AxisType`` only exists on newer JAX; the pinned 0.4.x
-    ``make_mesh`` neither has the keyword nor needs it (all axes are Auto).
-    """
-    if _AXIS_TYPE is None:
-        return {}
-    return {"axis_types": (_AXIS_TYPE.Auto,) * n_axes}
-
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
+    """``jax.make_mesh`` with every axis of the Auto type."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_types_kw(len(axes)))
-
-
-def set_mesh(mesh):
-    """Ambient-mesh context manager across JAX generations.
-
-    Newer JAX: ``jax.set_mesh(mesh)``.  0.4.x: ``jax.sharding.Mesh`` is its
-    own context manager (the pjit-era thread-resident mesh), so the mesh
-    object itself is returned for use in a ``with`` statement.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def ambient_mesh():
-    """The mesh set by :func:`set_mesh`, or ``None`` when there isn't one.
-
-    Newer JAX reads the abstract mesh (``jax.sharding.get_abstract_mesh``);
-    0.4.x reads the thread-resident physical mesh the ``with mesh:`` context
-    installs.
-    """
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        am = get_am()
-        if am is None or not getattr(am, "axis_names", ()):
-            return None
-        return am
-    from jax._src.mesh import thread_resources
-    pm = thread_resources.env.physical_mesh
-    return None if pm.empty else pm
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as one dict on both JAX generations
-    (0.4.x returns a list with one dict per program)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """The mesh set by ``jax.set_mesh``, or ``None`` when there isn't one."""
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or not am.axis_names:
+        return None
+    return am
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -39,24 +39,38 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def serve_samples(args) -> None:
-    """Union-sample serving loop through the streaming SampleService."""
+def build_sampler(workload: str, scale: float, seed: int = 0,
+                  backend: str = "jax", round_batch: int = 8192,
+                  shards: int = 0, plan: str = "static", **workload_kw):
+    """The served union: workload data, histogram warm-up, the union
+    estimate and a ``SetUnionSampler`` over it.  ``shards > 0`` builds the
+    mesh-sharded engine over that many devices.  Returns
+    ``(workload, sampler)``."""
     from ..core.framework import estimate_union, warmup
     from ..core.union_sampler import SetUnionSampler
     from ..data.workloads import WORKLOADS
-    from ..serve import SampleService
 
-    wl = WORKLOADS[args.workload](scale=args.scale, seed=args.seed)
+    wl = WORKLOADS[workload](scale=scale, seed=seed, **workload_kw)
     wr = warmup(wl.cat, wl.joins, method="histogram")
     est = estimate_union(wr.oracle)
     mesh = None
-    if args.shards:
+    if shards:
         from ..core.sharding import make_sampler_mesh
-        mesh = make_sampler_mesh(world=args.shards)
-    sampler = SetUnionSampler(wl.cat, wl.joins, est.cover, seed=args.seed,
-                              backend=args.backend,
-                              round_batch=args.round_batch, mesh=mesh,
-                              plan=args.plan)
+        mesh = make_sampler_mesh(world=shards)
+    sampler = SetUnionSampler(wl.cat, wl.joins, est.cover, seed=seed,
+                              backend=backend, round_batch=round_batch,
+                              mesh=mesh, plan=plan)
+    return wl, sampler
+
+
+def serve_samples(args) -> None:
+    """Union-sample serving loop through the streaming SampleService."""
+    from ..serve import SampleService
+
+    _, sampler = build_sampler(args.workload, args.scale, seed=args.seed,
+                               backend=args.backend,
+                               round_batch=args.round_batch,
+                               shards=args.shards, plan=args.plan)
     sampler.sample(256)                     # warm up / compile
     metrics = None
     if args.metrics_port is not None:
@@ -130,6 +144,8 @@ def main(argv: Optional[list] = None) -> None:
                     help="keep the service + /metrics up this many seconds "
                          "after the request loop (for external scrapers)")
     args = ap.parse_args(argv)
+    from .jax_cache import use_persistent_cache
+    use_persistent_cache()
 
     if args.mode == "samples":
         serve_samples(args)

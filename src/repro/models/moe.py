@@ -193,8 +193,7 @@ def moe_ffn_dist(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
             aux = jax.lax.pmean(aux, da)   # model axis is already invariant
         return out.reshape(xb.shape), aux
 
-    from ..launch.mesh import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         block, mesh=am,
         in_specs=(P(da_spec, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),

@@ -152,7 +152,8 @@ def test_residual_rejection_matches_host_on_shared_trace():
     rows_dev = {a: jnp.asarray(c.astype(np.int32))
                 for a, c in sb.rows.items()}
     _, ok_d, ratio = tree._residual_step(
-        ridx, rcfg, rows_dev, jnp.asarray(walk_ok),
+        ridx, rcfg, tree.device_arrays()["nodes"][ridx], rows_dev,
+        jnp.asarray(walk_ok),
         jnp.ones(4096, jnp.float32), jnp.asarray(u_pick))
     accept_d = np.asarray(ok_d & (jnp.asarray(u_acc) < ratio))
 

@@ -167,14 +167,13 @@ def test_pipelined_serve_uniform_uq4_cyclic():
 def test_psum_counters_matches_host_merge():
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.sharding import (SHARD_AXIS, make_sampler_mesh,
                                      psum_counters)
     from repro.core.union_sampler import SamplerStats
     mesh = make_sampler_mesh(world=1)
     vec = jnp.array([3, 7, 1, 0, 2], jnp.int32)
-    merged = jax.jit(shard_map(
+    merged = jax.jit(jax.shard_map(
         lambda v: psum_counters(v, SHARD_AXIS), mesh=mesh,
         in_specs=P(), out_specs=P()))(vec)
     host = SamplerStats(iterations=3, candidate_draws=7, cover_rejects=1,

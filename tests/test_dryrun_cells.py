@@ -61,8 +61,7 @@ batch = {"tokens": jax.ShapeDtypeStruct((2, 64), jnp.int32),
 co = jax.jit(lambda p, b: forward_train(p, cfg, b)).lower(
     param_specs(cfg), batch).compile()
 cs = census(co.as_text())
-from repro.launch.mesh import cost_analysis_dict
-raw = float(cost_analysis_dict(co).get("flops", 0.0))
+raw = float((co.cost_analysis() or {}).get("flops", 0.0))
 assert cs.flops > 0 and raw > 0
 ratio = cs.flops / raw
 assert 0.4 < ratio < 2.0, (cs.flops, raw)

@@ -250,14 +250,14 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.train.grad_compress import compressed_psum
 mesh = make_mesh((4,), ("d",))
 x = jnp.asarray(np.random.default_rng(0).standard_normal((4, 128)), jnp.float32)
 def f(x):
     return compressed_psum(x, "d"), jax.lax.psum(x, "d")
-got, want = jax.jit(shard_map(f, mesh=mesh, in_specs=P("d"),
-                              out_specs=(P("d"), P("d"))))(x)
+got, want = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("d"),
+                                  out_specs=(P("d"), P("d"))))(x)
 err = float(jnp.max(jnp.abs(got - want)))
 scale = float(jnp.max(jnp.abs(want)))
 assert err <= 0.05 * scale + 1e-5, (err, scale)
@@ -327,7 +327,7 @@ def test_moe_shard_map_equivalence_subprocess():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.moe import MoEDims, moe_ffn, moe_ffn_dist, moe_param_shapes
 mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
@@ -336,11 +336,11 @@ params = {k: jnp.asarray(rng.standard_normal(s) * 0.1, jnp.float32)
           for k, s in moe_param_shapes(dims).items()}
 x = jnp.asarray(rng.standard_normal((4, 16, 32)), jnp.float32)
 dense_out, _ = jax.jit(lambda p, x: moe_ffn(p, x, dims, capacity=64))(params, x)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     dist_out, _ = jax.jit(lambda p, x: moe_ffn_dist(p, x, dims))(params, x)
     # production loss shape (transformer.forward_train: loss + 0.01*aux) —
     # a loss that drops aux feeds a symbolic-Zero cotangent into the aux
-    # pmean, which 0.4.x shard_map cannot transpose
+    # pmean that shard_map must transpose
     def loss(p, x):
         out, aux = moe_ffn_dist(p, x, dims)
         return out.sum() + 0.01 * aux

@@ -67,26 +67,6 @@ def test_walk_hop_sweep(seed, nk, nq):
 
 
 # ---------------------------------------------------------------------------
-# segdegree
-# ---------------------------------------------------------------------------
-
-
-@given(st.integers(0, 2**31), st.integers(1, 4000), st.integers(1, 200))
-@settings(max_examples=20, deadline=None)
-def test_segdegree_sweep(seed, n, dom):
-    rng = np.random.default_rng(seed)
-    keys = np.sort(rng.integers(0, dom, n).astype(np.int64))
-    d, m = ops.segdegree(keys)
-    d_r, m_r = ref.segdegree_ref(keys)
-    assert (d, m) == (d_r, m_r)
-
-
-def test_segdegree_run_spanning_many_blocks():
-    keys = np.full(1000, 42, dtype=np.int64)
-    assert ops.segdegree(keys) == (1, 1000)
-
-
-# ---------------------------------------------------------------------------
 # weighted pick
 # ---------------------------------------------------------------------------
 

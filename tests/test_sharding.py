@@ -229,7 +229,6 @@ def test_hash_partition_geometric_growth_completes():
 def test_on_mesh_moment_merge_matches_host_merge_statistics():
     out = _run_sub(r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core.sharding import make_sampler_mesh, psum_merge_moments
 from repro.core.size_estimation import RunningMean
@@ -246,8 +245,8 @@ def f(x):
     m2 = jnp.sum((x - mean) ** 2)
     n, gm, gm2 = psum_merge_moments(jnp.int32(x.shape[0]), mean, m2, "shards")
     return n[None], gm[None], gm2[None]
-n, gm, gm2 = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("shards"),),
-                               out_specs=P("shards"), check_rep=False))(
+n, gm, gm2 = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("shards"),),
+                                   out_specs=P("shards"), check_vma=False))(
     jnp.asarray(xs, jnp.float32))
 
 host_parts = []
