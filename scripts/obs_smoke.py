@@ -9,7 +9,8 @@ Spawns ``python -m repro.launch.serve --mode samples`` with an ephemeral
   belongs to a ``# TYPE``-declared family, histogram ``_bucket`` series are
   cumulative and end at ``+Inf`` = ``_count``);
 * the serve-tier request histogram saw traffic (nonzero ``_count``) and the
-  derived p50/p99 gauges are positive;
+  p50/p99 a Prometheus client derives from its ``_bucket`` series are
+  positive;
 * the queue-depth gauge is present.
 
 Exit 0 on success; nonzero with a diagnostic otherwise.  Used by the CI
@@ -110,6 +111,23 @@ def check_exposition(body: str) -> None:
             f"{name} +Inf bucket != _count"
 
 
+def bucket_quantile(body: str, name: str, q: float) -> float:
+    """The ``q`` quantile of histogram ``name`` from its cumulative
+    ``_bucket`` series, interpolated within the bucket as Prometheus's
+    ``histogram_quantile`` does."""
+    buckets = [(float(le), int(c)) for le, c in re.findall(
+        rf'^{re.escape(name)}_bucket{{le="([^"]+)"}} (\d+)$', body, re.M)]
+    total = buckets[-1][1]
+    lo, below = 0.0, 0
+    for le, cum in buckets:
+        if cum >= q * total and cum > below:
+            if le == float("inf"):
+                return lo
+            return lo + (le - lo) * (q * total - below) / (cum - below)
+        lo, below = le, cum
+    return lo
+
+
 def main() -> int:
     env = dict(os.environ)
     env.setdefault("PYTHONPATH", "src")
@@ -125,14 +143,12 @@ def main() -> int:
         for required in ("repro_serve_request_seconds", "repro_serve_queue_depth",
                          "repro_serve_requests_total"):
             assert f"# TYPE {required}" in body, f"missing metric {required}"
-        p50 = re.search(r"^repro_serve_request_seconds_p50 (\S+)$", body, re.M)
-        p99 = re.search(r"^repro_serve_request_seconds_p99 (\S+)$", body, re.M)
-        assert p50 and float(p50.group(1)) > 0, "p50 gauge not positive"
-        assert p99 and float(p99.group(1)) > 0, "p99 gauge not positive"
-        assert float(p99.group(1)) >= float(p50.group(1)), "p99 < p50"
+        p50, p99 = (bucket_quantile(body, "repro_serve_request_seconds", q)
+                    for q in (0.5, 0.99))
+        assert p50 > 0, "p50 not positive"
+        assert p99 >= p50, "p99 < p50"
         print(f"obs_smoke: PASS — {url}/metrics well-formed, "
-              f"p50={float(p50.group(1))*1e3:.2f}ms "
-              f"p99={float(p99.group(1))*1e3:.2f}ms")
+              f"p50={p50*1e3:.2f}ms p99={p99*1e3:.2f}ms")
         return 0
     except (AssertionError, RuntimeError) as e:
         print(f"obs_smoke: FAIL — {e}")

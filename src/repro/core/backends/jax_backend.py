@@ -56,7 +56,6 @@ the whole union.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import time
@@ -914,13 +913,6 @@ PIECE_STAT_FIELDS = ("draws", "accepts", "residual_rejects",
                      "bank_drained", "bank_hwm")
 
 
-def _dispatch_annotation():
-    """Host-side profiler annotation around loop dispatch (REPRO_OBS_TRACE)."""
-    if obs.trace_annotations_enabled():
-        return jax.profiler.TraceAnnotation("repro/sample_dispatch")
-    return contextlib.nullcontext()
-
-
 def _cover_cum(probs_base: jnp.ndarray, dead: jnp.ndarray):
     """Dead-masked, renormalised selection CDF + unreachable flag.
 
@@ -1030,11 +1022,19 @@ class _PendingSample:
     ``result()`` performs the single device→host fetch, folds the stats
     vector, applies the host-drawn output shuffle and builds the SampleSet.
     The serving path dispatches call *k+1* before draining call *k*.
+
+    The drain has two parts, timed apart: the wait until the loop's scalar
+    outputs are on the host (``repro_engine_drain_wait_seconds``: the block
+    on the device), then the host's own assembly — fetch, widening, shuffle,
+    column split and ``fingerprint128`` (``repro_engine_assemble_seconds``).
+    The two add up to the call's ``repro_engine_drain_seconds``.  ``call``
+    is the engine's dispatch number, the ``call`` argument of its spans.
     """
 
     def __init__(self, sampler, n, out, total, rounds, fail,
-                 stats_vec, piece_vec, shuffle):
+                 stats_vec, piece_vec, shuffle, call: int):
         self._sampler = sampler
+        self.call = int(call)
         self._n = int(n)
         self._out = out
         self._total = total
@@ -1048,36 +1048,52 @@ class _PendingSample:
     def result(self):
         if self._done is not None:
             return self._done
-        s = self._sampler
-        t0 = time.perf_counter() if obs.enabled() else 0.0
-        if bool(np.asarray(self._fail)):
-            raise RuntimeError("all cover pieces unreachable")
-        total = int(np.asarray(self._total))
-        s.last_rounds = int(np.asarray(self._rounds))
-        if total < self._n:
-            raise RuntimeError("JaxUnionSampler: top-up budget exhausted")
-        vec = np.asarray(self._stats_vec)
-        for f, v in zip(_STAT_FIELDS, vec):
-            setattr(s.stats, f, getattr(s.stats, f) + int(v))
-        ema = None
-        if s.plan == "adaptive" and obs.enabled() and s._dev_state is not None:
-            # snapshot the latest carried EMAs (tiny fetch; result() already
-            # syncs) for the repro_engine_piece_ema gauges
-            ema = np.asarray(s._dev_state["ema"])
-        s._fold_piece_stats(np.asarray(self._piece_vec),
-                            rounds=s.last_rounds, samples=self._n, ema=ema)
-        mat = s._merge_out(self._out)[:self._n].astype(np.int64)[
-            self._shuffle]
-        rows = {a: np.ascontiguousarray(mat[:, i])
-                for i, a in enumerate(s.attrs)}
-        home = np.ascontiguousarray(mat[:, -1])
-        from ..relation import fingerprint128
-        from ..union_sampler import SampleSet
-        fp = fingerprint128([rows[a] for a in sorted(s.attrs)])
-        self._done = SampleSet(list(s.attrs), rows, home, fp, s.stats)
-        if obs.enabled():
-            s._obs_drain_hist().observe(time.perf_counter() - t0)
+        with obs.span("repro/engine/drain", call=self.call):
+            self._done = self._drain()
         return self._done
+
+    def _drain(self):
+        s = self._sampler
+        timed = obs.enabled()
+        t0 = time.perf_counter() if timed else 0.0
+        with obs.span("repro/engine/drain_wait", call=self.call):
+            if bool(np.asarray(self._fail)):
+                raise RuntimeError("all cover pieces unreachable")
+            total = int(np.asarray(self._total))
+            s.last_rounds = int(np.asarray(self._rounds))
+            vec = np.asarray(self._stats_vec)
+        t1 = time.perf_counter() if timed else 0.0
+        with obs.span("repro/engine/assemble", call=self.call):
+            if total < self._n:
+                raise RuntimeError("JaxUnionSampler: top-up budget exhausted")
+            for f, v in zip(_STAT_FIELDS, vec):
+                setattr(s.stats, f, getattr(s.stats, f) + int(v))
+            ema = None
+            if (s.plan == "adaptive" and timed
+                    and s._dev_state is not None):
+                # snapshot the latest carried EMAs (tiny fetch; result()
+                # already syncs) for the repro_engine_piece_ema gauges
+                ema = np.asarray(s._dev_state["ema"])
+            s._fold_piece_stats(np.asarray(self._piece_vec),
+                                rounds=s.last_rounds, samples=self._n,
+                                ema=ema)
+            mat = s._merge_out(self._out)[:self._n].astype(np.int64)[
+                self._shuffle]
+            rows = {a: np.ascontiguousarray(mat[:, i])
+                    for i, a in enumerate(s.attrs)}
+            home = np.ascontiguousarray(mat[:, -1])
+            from ..relation import fingerprint128
+            from ..union_sampler import SampleSet
+            with obs.span("repro/engine/fingerprint", call=self.call):
+                fp = fingerprint128([rows[a] for a in sorted(s.attrs)])
+            done = SampleSet(list(s.attrs), rows, home, fp, s.stats)
+        if timed:
+            t2 = time.perf_counter()
+            h = s._obs_handles()
+            h["drain_wait"].observe(t1 - t0)
+            h["assemble"].observe(t2 - t1)
+            h["drain"].observe(t2 - t0)
+        return done
 
 
 class JaxUnionSampler:
@@ -1223,6 +1239,8 @@ class JaxUnionSampler:
         # the body only while tracing); the recompile audit reads this
         self._trace_events: List[Tuple[str, int, str]] = []
         self._dev_state = None
+        self._calls = 0             # device-loop dispatches (span `call`)
+        self._phases_published: set = set()    # loops with published phases
         # host-loop twin state (fused_rounds="host"): numpy ring banks with
         # identical FIFO semantics; allocated on first host sample
         nj = len(self.order)
@@ -1299,65 +1317,78 @@ class JaxUnionSampler:
         # resolved at trace time (first round): keeps the lazy backend
         # membership unbuilt for subclasses that override the round program
         members = [self.backend.members[n] for n in self.order]
-        kpick, *jks = jax.random.split(key, nj + 1)
-        # (1) multinomial cover selection: categorical picks → histogram
-        u = jax.random.uniform(kpick, (self._slot_width,))
-        pick = jnp.clip(jnp.searchsorted(probs_cum, u, side="right"
-                                         ).astype(jnp.int32), 0, nj - 1)
-        valid = (jnp.arange(self._slot_width)
-                 < extra_target).astype(jnp.int32)
-        need = carry_need + jnp.zeros((nj,), jnp.int32).at[pick].add(valid)
-        budget = None
-        if adaptive:
-            # integer candidate budget from counts only (owed work minus
-            # usable bank coverage over the accept EMA) — planner.budget_for
-            # is the same fixed-point arithmetic the numpy twin runs, so
-            # host/device budgets are bit-identical from identical carries
-            budget = planner.budget_for(
-                need, bank_count, ema[:, 0],
-                jnp.asarray(self._pbatch_i32), self._drain_w, jnp)
+        # Each step sits in a named scope of its phase (repro.obs.LOOP_PHASES;
+        # per-piece phases carry the join name), so a profile splits the
+        # round's device time by phase.  Scopes are op metadata only: the
+        # compiled program and the stream are the same without them.
+        with jax.named_scope("select"):
+            kpick, *jks = jax.random.split(key, nj + 1)
+            # (1) multinomial cover selection: categorical picks → histogram
+            u = jax.random.uniform(kpick, (self._slot_width,))
+            pick = jnp.clip(jnp.searchsorted(probs_cum, u, side="right"
+                                             ).astype(jnp.int32), 0, nj - 1)
+            valid = (jnp.arange(self._slot_width)
+                     < extra_target).astype(jnp.int32)
+            need = carry_need + jnp.zeros((nj,), jnp.int32).at[pick].add(
+                valid)
+            budget = None
+            if adaptive:
+                # integer candidate budget from counts only (owed work minus
+                # usable bank coverage over the accept EMA) —
+                # planner.budget_for is the same fixed-point arithmetic the
+                # numpy twin runs, so host/device budgets are bit-identical
+                # from identical carries
+                budget = planner.budget_for(
+                    need, bank_count, ema[:, 0],
+                    jnp.asarray(self._pbatch_i32), self._drain_w, jnp)
         # (2)+(3) per join: batched candidate draw (incl. §8.2 residual-edge
         # verification for cyclic pieces) + fused §8.3 predicate acceptance
         # + earlier-piece rejection
         cols, okc, resc, accc, predc = [], [], [], [], []
         for j, tree in enumerate(self.trees):
             bj = self.piece_batches[j]
-            rows, acc, walk_ok = tree.draw(jks[j], bj, cat["trees"][j])
-            if budget is not None:
-                # budget mask: the first budget[j] slots of an i.i.d.
-                # candidate stream — a count-derived prefix, so the
-                # surviving candidates stay i.i.d. uniform
-                elig = jnp.arange(bj) < budget[j]
-                acc = acc & elig
-                walk_ok = walk_ok & elig
-            resc.append(jnp.sum(walk_ok) - jnp.sum(acc))
-            pf = self._pred_fns[j]
-            if pf is None:
-                predc.append(jnp.int32(0))
-            else:
-                pok = pf(rows)
-                predc.append(jnp.sum(acc & ~pok).astype(jnp.int32))
-                acc = acc & pok
-            for q in range(j):             # pieces earlier in cover order
-                acc = acc & ~members[q].contains(rows, cat["members"][q])
+            name = self.order[j]
+            with jax.named_scope(f"walk/{name}"):
+                rows, acc, walk_ok = tree.draw(jks[j], bj, cat["trees"][j])
+            with jax.named_scope(f"filter/{name}"):
+                if budget is not None:
+                    # budget mask: the first budget[j] slots of an i.i.d.
+                    # candidate stream — a count-derived prefix, so the
+                    # surviving candidates stay i.i.d. uniform
+                    elig = jnp.arange(bj) < budget[j]
+                    acc = acc & elig
+                    walk_ok = walk_ok & elig
+                resc.append(jnp.sum(walk_ok) - jnp.sum(acc))
+                pf = self._pred_fns[j]
+                if pf is None:
+                    predc.append(jnp.int32(0))
+                else:
+                    pok = pf(rows)
+                    predc.append(jnp.sum(acc & ~pok).astype(jnp.int32))
+                    acc = acc & pok
+            with jax.named_scope(f"member/{name}"):
+                for q in range(j):         # pieces earlier in cover order
+                    acc = acc & ~members[q].contains(rows, cat["members"][q])
             # (4) compaction: accepted rows to the front in slot order — a
             # rank scatter (cumsum - 1) on the (B_j, A+1) row matrix (last
             # column = home piece id, so it rides every later scatter for
             # free): one scatter per piece, cheaper than the per-attr argsort
-            dst = jnp.where(acc, jnp.cumsum(acc) - 1, bj)
-            mat = jnp.stack([rows[a].astype(jnp.int32)
-                             for a in self.attrs]
-                            + [jnp.full(bj, j, jnp.int32)], axis=1)
-            cols.append(jnp.zeros((bj, mat.shape[1]), jnp.int32)
-                        .at[dst].set(mat, mode="drop"))
-            okc.append(jnp.sum(walk_ok))
-            accc.append(jnp.sum(acc))
-        out = (cols, jnp.stack(okc).astype(jnp.int32),
-               jnp.stack(resc).astype(jnp.int32),
-               jnp.stack(accc).astype(jnp.int32),
-               jnp.stack(predc).astype(jnp.int32), need)
-        if adaptive:
-            out = out + (budget.astype(jnp.int32),)
+            with jax.named_scope(f"compact/{name}"):
+                dst = jnp.where(acc, jnp.cumsum(acc) - 1, bj)
+                mat = jnp.stack([rows[a].astype(jnp.int32)
+                                 for a in self.attrs]
+                                + [jnp.full(bj, j, jnp.int32)], axis=1)
+                cols.append(jnp.zeros((bj, mat.shape[1]), jnp.int32)
+                            .at[dst].set(mat, mode="drop"))
+                okc.append(jnp.sum(walk_ok))
+                accc.append(jnp.sum(acc))
+        with jax.named_scope("carry"):
+            out = (cols, jnp.stack(okc).astype(jnp.int32),
+                   jnp.stack(resc).astype(jnp.int32),
+                   jnp.stack(accc).astype(jnp.int32),
+                   jnp.stack(predc).astype(jnp.int32), need)
+            if adaptive:
+                out = out + (budget.astype(jnp.int32),)
         return out
 
     def _round_impl(self, probs_base: jnp.ndarray, dead: jnp.ndarray,
@@ -1415,10 +1446,11 @@ class JaxUnionSampler:
 
             def body(c):
                 state, out, total, rounds, fail, stats, pstats = c
-                probs_cum, bad = _cover_cum(probs_base, state["dead"])
-                key, kround = jax.random.split(state["key"])
-                extra = jnp.clip(n - total - jnp.sum(state["owed"]),
-                                 0, self._slot_width)
+                with jax.named_scope("select"):
+                    probs_cum, bad = _cover_cum(probs_base, state["dead"])
+                    key, kround = jax.random.split(state["key"])
+                    extra = jnp.clip(n - total - jnp.sum(state["owed"]),
+                                     0, self._slot_width)
                 if adaptive:
                     cols, okc, resc, accc, predc, need, budget = \
                         self._round_core(kround, probs_cum, state["owed"],
@@ -1429,66 +1461,72 @@ class JaxUnionSampler:
                     cols, okc, resc, accc, predc, need = self._round_core(
                         kround, probs_cum, state["owed"], extra, cat)
                 # bank take (FIFO, capped) → fresh take → carried shortfall
-                dt = jnp.minimum(jnp.minimum(need, state["bank_count"]),
-                                 self._drain_w)
-                ft = jnp.minimum(need - dt, accc)
-                out2, total2, bank2, head2, count2 = _emit_and_bank(
-                    out, total, state["bank"],
-                    state["bank_head"], state["bank_count"],
-                    cols, dt, ft, accc, cap, C, W)
-                shortfall = need - dt - ft
-                # dead-piece bookkeeping (same rules as the host twin):
-                # stray picks on dead pieces are dropped; a live piece that
-                # keeps a target but yields nothing for dead_rounds rounds
-                # is empty in reality (estimation noise) — drop it
-                dropped = jnp.sum(jnp.where(state["dead"], shortfall, 0))
-                shortfall = jnp.where(state["dead"], 0, shortfall)
-                trig = (shortfall > 0) & (accc == 0) & (count2 == 0)
-                streak = jnp.where(state["dead"], state["streak"],
-                                   jnp.where(trig, state["streak"] + 1, 0))
-                newly = ~state["dead"] & (streak >= dead_rounds)
-                dropped = dropped + jnp.sum(jnp.where(newly, shortfall, 0))
-                shortfall = jnp.where(newly, 0, shortfall)
-                # adaptive rounds draw only the budgeted slots; static rounds
-                # spend the full static width every round
-                drawn = (jnp.sum(budget) if adaptive
-                         else jnp.int32(bt))
-                stats2 = stats + jnp.stack(
-                    [drawn.astype(jnp.int32), drawn.astype(jnp.int32),
-                     (jnp.sum(okc) - jnp.sum(resc) - jnp.sum(predc)
-                      - jnp.sum(accc)).astype(jnp.int32),
-                     jnp.sum(resc).astype(jnp.int32),
-                     jnp.sum(predc).astype(jnp.int32),
-                     dropped.astype(jnp.int32)])
-                # per-piece telemetry rides the same carry (PIECE_STAT_FIELDS
-                # columns); pure extra outputs — nothing feeds back into the
-                # sampling arithmetic, so the emitted stream is unchanged
-                pstats2 = jnp.stack(
-                    [pstats[:, 0] + (budget if adaptive else pbatch),
-                     pstats[:, 1] + accc,
-                     pstats[:, 2] + resc,
-                     pstats[:, 3] + dt.astype(jnp.int32),
-                     jnp.maximum(pstats[:, 4], count2.astype(jnp.int32))],
-                    axis=1)
-                state2 = {"key": key,
-                          "owed": shortfall.astype(jnp.int32),
-                          "dead": state["dead"] | newly,
-                          "streak": streak.astype(jnp.int32),
-                          "bank": bank2,
-                          "bank_head": head2.astype(jnp.int32),
-                          "bank_count": count2.astype(jnp.int32)}
-                if adaptive:
-                    # one EMA step from this round's counts (accept /
-                    # walk_ok / residual / pred per budgeted slot)
-                    counts = jnp.stack([accc, okc, resc, predc], axis=1)
-                    state2["ema"] = planner.ema_update(
-                        state["ema"], budget, counts, shifts, jnp)
-                # `bad` (unreachable cover) is terminal: the loop exits on
-                # `fail` and the host raises, discarding the buffers — no
-                # need to gate the state updates (which would force a full
-                # copy of the banks + output every round)
-                return (state2, out2, total2, rounds + 1,
-                        fail | bad, stats2, pstats2)
+                with jax.named_scope("emit"):
+                    dt = jnp.minimum(jnp.minimum(need, state["bank_count"]),
+                                     self._drain_w)
+                    ft = jnp.minimum(need - dt, accc)
+                    out2, total2, bank2, head2, count2 = _emit_and_bank(
+                        out, total, state["bank"],
+                        state["bank_head"], state["bank_count"],
+                        cols, dt, ft, accc, cap, C, W)
+                with jax.named_scope("carry"):
+                    shortfall = need - dt - ft
+                    # dead-piece bookkeeping (same rules as the host twin):
+                    # stray picks on dead pieces are dropped; a live piece
+                    # that keeps a target but yields nothing for dead_rounds
+                    # rounds is empty in reality (estimation noise) — drop it
+                    dropped = jnp.sum(jnp.where(state["dead"], shortfall, 0))
+                    shortfall = jnp.where(state["dead"], 0, shortfall)
+                    trig = (shortfall > 0) & (accc == 0) & (count2 == 0)
+                    streak = jnp.where(
+                        state["dead"], state["streak"],
+                        jnp.where(trig, state["streak"] + 1, 0))
+                    newly = ~state["dead"] & (streak >= dead_rounds)
+                    dropped = dropped + jnp.sum(
+                        jnp.where(newly, shortfall, 0))
+                    shortfall = jnp.where(newly, 0, shortfall)
+                    # adaptive rounds draw only the budgeted slots; static
+                    # rounds spend the full static width every round
+                    drawn = (jnp.sum(budget) if adaptive
+                             else jnp.int32(bt))
+                    stats2 = stats + jnp.stack(
+                        [drawn.astype(jnp.int32), drawn.astype(jnp.int32),
+                         (jnp.sum(okc) - jnp.sum(resc) - jnp.sum(predc)
+                          - jnp.sum(accc)).astype(jnp.int32),
+                         jnp.sum(resc).astype(jnp.int32),
+                         jnp.sum(predc).astype(jnp.int32),
+                         dropped.astype(jnp.int32)])
+                    # per-piece telemetry rides the same carry
+                    # (PIECE_STAT_FIELDS columns); pure extra outputs —
+                    # nothing feeds back into the sampling arithmetic, so
+                    # the emitted stream is unchanged
+                    pstats2 = jnp.stack(
+                        [pstats[:, 0] + (budget if adaptive else pbatch),
+                         pstats[:, 1] + accc,
+                         pstats[:, 2] + resc,
+                         pstats[:, 3] + dt.astype(jnp.int32),
+                         jnp.maximum(pstats[:, 4],
+                                     count2.astype(jnp.int32))],
+                        axis=1)
+                    state2 = {"key": key,
+                              "owed": shortfall.astype(jnp.int32),
+                              "dead": state["dead"] | newly,
+                              "streak": streak.astype(jnp.int32),
+                              "bank": bank2,
+                              "bank_head": head2.astype(jnp.int32),
+                              "bank_count": count2.astype(jnp.int32)}
+                    if adaptive:
+                        # one EMA step from this round's counts (accept /
+                        # walk_ok / residual / pred per budgeted slot)
+                        counts = jnp.stack([accc, okc, resc, predc], axis=1)
+                        state2["ema"] = planner.ema_update(
+                            state["ema"], budget, counts, shifts, jnp)
+                    # `bad` (unreachable cover) is terminal: the loop exits
+                    # on `fail` and the host raises, discarding the buffers
+                    # — no need to gate the state updates (which would
+                    # force a full copy of the banks + output every round)
+                    rounds2, fail2 = rounds + 1, fail | bad
+                return (state2, out2, total2, rounds2, fail2, stats2, pstats2)
 
             init = (state, out, jnp.int32(0), jnp.int32(0),
                     jnp.bool_(False), jnp.zeros(len(_STAT_FIELDS),
@@ -1524,19 +1562,34 @@ class JaxUnionSampler:
         C = 1 << max(10, (int(n) - 1).bit_length())
         if self._dev_state is None:
             self._dev_state = self._init_state()
-        out = self._out_buffer(C)
-        with _dispatch_annotation():
-            st, out, total, rounds, fail, stats, pstats = self._loop_for(C)(
-                self._dev_state, out, jnp.int32(n), self._probs_base,
-                self._catalog_args())
+        call, self._calls = self._calls, self._calls + 1
+        fn = self._loop_for(C)
+        args = (self._dev_state, self._out_buffer(C), jnp.int32(n),
+                self._probs_base, self._catalog_args())
+        # under REPRO_OBS_TRACE, the first call of a capacity class also
+        # publishes which phase each op of the compiled loop belongs to
+        # (the profiler's trace names the ops but carries no name stack);
+        # the shapes are taken now, as the call donates the carry.  The
+        # sharded loop is a plain function over shard_map and publishes none
+        publish = (obs.trace_annotations_enabled()
+                   and fn not in self._phases_published
+                   and hasattr(fn, "lower"))
+        shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                              args) if publish else None
+        with obs.span("repro/sample_dispatch", call=call):
+            st, out, total, rounds, fail, stats, pstats = fn(*args)
         self._dev_state = st
         # the output shuffle is host randomness, drawn at dispatch time so
         # both modes consume host_rng identically (one permutation per call)
         shuffle = self.host_rng.permutation(n)
         if obs.enabled():
-            self._obs_dispatch_hist().observe(time.perf_counter() - t0)
+            self._obs_handles()["dispatch"].observe(time.perf_counter() - t0)
+        if publish:
+            # lowering and compiling again hit the caches the call filled
+            obs.publish_op_phases(fn.lower(*shapes).compile().as_text())
+            self._phases_published.add(fn)
         return _PendingSample(self, n, out, total, rounds, fail, stats,
-                              pstats, shuffle)
+                              pstats, shuffle, call)
 
     def _out_buffer(self, C: int):
         """Fresh output buffer for one device-loop call (donated away)."""
@@ -1589,10 +1642,6 @@ class JaxUnionSampler:
                 "hwm": reg.gauge("repro_engine_piece_bank_hwm",
                                  "surplus-bank occupancy high-water mark",
                                  ("join",)),
-                "waste": reg.gauge(
-                    "repro_round_waste_ratio",
-                    "1 - accepted/drawn per cover piece (cumulative)",
-                    ("join",)),
                 "ema": reg.gauge(
                     "repro_engine_piece_ema",
                     "adaptive-planner acceptance EMA (fraction of budget)",
@@ -1607,14 +1656,16 @@ class JaxUnionSampler:
                 "drain": reg.histogram(
                     "repro_engine_drain_seconds",
                     "host wall-clock of result fetch + assembly"),
+                "drain_wait": reg.histogram(
+                    "repro_engine_drain_wait_seconds",
+                    "part of the drain blocked until the loop's scalar "
+                    "outputs are on the host"),
+                "assemble": reg.histogram(
+                    "repro_engine_assemble_seconds",
+                    "part of the drain in host assembly: fetch, widen, "
+                    "shuffle, column split, fingerprint128"),
             }
         return self._obs_metrics
-
-    def _obs_dispatch_hist(self):
-        return self._obs_handles()["dispatch"]
-
-    def _obs_drain_hist(self):
-        return self._obs_handles()["drain"]
 
     def _fold_piece_stats(self, p: np.ndarray, rounds: int = 0,
                           samples: int = 0,
@@ -1635,10 +1686,6 @@ class JaxUnionSampler:
                 if v:
                     child.inc(v)
             h["hwm"].labels(join=name).set(int(self.piece_stats[j, 4]))
-            draws = int(self.piece_stats[j, 0])
-            if draws:
-                h["waste"].labels(join=name).set(
-                    1.0 - int(self.piece_stats[j, 1]) / draws)
             if ema is not None:
                 for i, comp in enumerate(planner.EMA_COMPONENTS):
                     h["ema"].labels(join=name, component=comp).set(

@@ -20,9 +20,10 @@ The global kill switch is the ``REPRO_OBS`` environment variable: set it to
 (sites check :func:`enabled` before doing host-side work; the registry keeps
 functioning so late scrapes never crash).  Tests and benchmarks toggle at
 runtime with :func:`set_enabled`; ``set_enabled(None)`` re-reads the
-environment.  ``REPRO_OBS_TRACE=1`` additionally turns on host-side
-``jax.profiler`` trace annotations around engine dispatch (off by default —
-they cost a little even without an active profiler trace).
+environment.  ``REPRO_OBS_TRACE=1`` additionally turns on the host-side
+``jax.profiler`` spans of :data:`repro.obs.SPANS` in the engine and the
+serve tier (off by default — they cost a little even without an active
+profiler trace).
 """
 
 from __future__ import annotations
@@ -364,7 +365,7 @@ class MetricsRegistry:
     """Get-or-create metric registry with snapshot + Prometheus rendering.
 
     ``collectors`` are pull-time hooks (e.g. the serve tier refreshing its
-    queue-depth and quantile gauges) run at the top of every
+    per-replica engine-stat gauges) run at the top of every
     :meth:`snapshot`/:meth:`render`; a collector that raises is dropped from
     the scrape, never propagated into it.
     """

@@ -1,4 +1,5 @@
-"""Bounded ring-buffer event log — the φ-trajectory tracer.
+"""Bounded ring-buffer event log — the φ-trajectory tracer — and the
+profiler spans and device-loop phases of the program.
 
 ONLINE-UNION's whole pitch is refining cheap initial parameter estimates on
 the fly; :class:`TraceRing` makes that refinement observable.  The sampler
@@ -14,10 +15,15 @@ may refine φ from a producer thread while a scraper drains the ring).
 
 from __future__ import annotations
 
+import re
 import threading
 from typing import Dict, List, Optional
 
-__all__ = ["TraceRing"]
+from .metrics import trace_annotations_enabled
+
+__all__ = ["LOOP_PHASES", "PIECE_PHASES", "SPANS", "TraceRing", "UNSCOPED",
+           "hlo_op_phases", "op_phases", "phase_of", "publish_op_phases",
+           "span"]
 
 
 class TraceRing:
@@ -69,3 +75,119 @@ class TraceRing:
         with self._lock:
             self._buf = [None] * self.capacity
             # seq keeps counting: consumers can detect drops across clears
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans and device-loop phases
+# ---------------------------------------------------------------------------
+
+#: Every host span the program opens under ``REPRO_OBS_TRACE=1``.  They are
+#: ``jax.profiler.TraceAnnotation``s, so they land on the profiler's host
+#: plane on the same clock as the device events; a trace reduction loads
+#: them by these names.
+SPANS = (
+    "repro/sample_dispatch",      # device-loop dispatch (call=<k>)
+    "repro/engine/drain",         # _PendingSample.result (call=<k>) ...
+    "repro/engine/drain_wait",    # ... blocked until the loop's scalars land
+    "repro/engine/assemble",      # ... fetch, widen, shuffle, split
+    "repro/engine/fingerprint",   # ... fingerprint128, inside assemble
+    "repro/serve/request",        # SampleService.request (batches=<ids>) ...
+    "repro/serve/lock_wait",      # ... waiting for the request lock
+    "repro/serve/queue_wait",     # ... blocked on the prefetch queue
+    "repro/serve/assemble",       # ... concatenation into the answer
+    "repro/serve/put_wait",       # producer blocked on a full queue
+)
+
+#: Phases of one device-loop round, as ``jax.named_scope``s in the loop body.
+#: The per-piece phases carry the piece's join name as a second component
+#: (``walk/<join>``); the others stand alone.
+PIECE_PHASES = ("walk", "filter", "member", "compact")
+LOOP_PHASES = ("select",) + PIECE_PHASES + ("emit", "carry")
+UNSCOPED = "unscoped"
+
+
+class _NoSpan:
+    """What :func:`span` returns while trace annotations are off."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **_args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str, **args):
+    """A profiler span named ``name`` (one of :data:`SPANS`) with ``args``
+    as its metadata, or a no-op while ``REPRO_OBS_TRACE`` is off.  Either
+    way the result is a context manager with ``set_metadata(**args)``."""
+    if not trace_annotations_enabled():
+        return _NO_SPAN
+    if name not in SPANS:
+        raise ValueError(f"{name!r} is not in repro.obs.SPANS")
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **args)
+
+
+def phase_of(op_name: str) -> str:
+    """The loop phase of an HLO op from its ``op_name`` metadata (the JAX
+    name stack, e.g. ``jit(loop_fn)/while/body/algo1_fused_round/walk/J1/
+    gather``): ``"walk/J1"``, ``"emit"``, ..., or :data:`UNSCOPED`.  The
+    outermost phase scope wins; a phase is never the last component (that
+    is the primitive)."""
+    parts = op_name.split("/")
+    for i, part in enumerate(parts[:-1]):
+        if part in PIECE_PHASES and i + 2 < len(parts):
+            return f"{part}/{parts[i + 1]}"
+        if part in LOOP_PHASES and part not in PIECE_PHASES:
+            return part
+    return UNSCOPED
+
+
+_HLO_OP = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+) = (.*)$')
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def hlo_op_phases(hlo_text: str) -> Dict[str, str]:
+    """Instruction name -> loop phase for every instruction of a compiled
+    HLO module's text (``compiled.as_text()``).  Instruction names are the
+    op names a profiler trace gives the module's device ops."""
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_OP.match(line)
+        if m is None:
+            continue
+        meta = _OP_NAME.search(m.group(2))
+        out[m.group(1)] = phase_of(meta.group(1)) if meta else UNSCOPED
+    return out
+
+
+_op_phases: Dict[str, Dict[str, str]] = {}
+_op_phases_lock = threading.Lock()
+_HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)")
+
+
+def publish_op_phases(hlo_text: str) -> None:
+    """Record the op -> phase map (:func:`hlo_op_phases`) of a compiled
+    program under its module name, replacing an earlier one.  Engines do
+    this under ``REPRO_OBS_TRACE=1`` so that a reduction of this process's
+    profiler trace can split the program's device time by phase: the trace
+    names each op but carries no name stack."""
+    m = _HLO_MODULE.match(hlo_text)
+    if m is None:
+        raise ValueError("not the text of an HLO module")
+    phases = hlo_op_phases(hlo_text)
+    with _op_phases_lock:
+        _op_phases[m.group(1)] = phases
+
+
+def op_phases(module: str) -> Dict[str, str]:
+    """The op -> phase map last published for the program ``module`` (the
+    name a trace gives its runs, e.g. ``jit_loop_fn``); empty if none."""
+    with _op_phases_lock:
+        return dict(_op_phases.get(module, {}))

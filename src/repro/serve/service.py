@@ -18,12 +18,19 @@ source for serving traffic:
   host or per mesh) and their streams interleave into one queue; per-engine
   cost accounting combines with :meth:`SamplerStats.merge`.
 * **telemetry** — every ``request()`` lands in the
-  ``repro_serve_request_seconds`` latency histogram (p50/p99 gauges derived
-  at scrape time), with request/sample counters, a queue-depth /
+  ``repro_serve_request_seconds`` latency histogram, with its time blocked
+  on the prefetch queue (``repro_serve_queue_wait_seconds``) and in
+  concatenating the answer (``repro_serve_assemble_seconds``) as
+  histograms of their own, request/sample counters, a queue-depth /
   prefetch-occupancy gauge, and per-replica merged ``SamplerStats`` gauges;
   ``python -m repro.launch.serve --mode samples --metrics-port P`` exposes
   all of it on ``http://127.0.0.1:P/metrics`` (Prometheus text) next to a
-  ``/healthz`` liveness probe.  ``REPRO_OBS=off`` disables it.
+  ``/healthz`` liveness probe.  ``REPRO_OBS=off`` disables it.  Under
+  ``REPRO_OBS_TRACE=1`` a request opens the profiler span
+  ``repro/serve/request`` (its ``batches``: the ids of the prefetched
+  batches it consumed) around ``repro/serve/lock_wait``,
+  ``repro/serve/queue_wait`` and ``repro/serve/assemble``; a producer
+  blocked on a full queue opens ``repro/serve/put_wait``.
 
 ``python -m repro.launch.serve --mode samples`` and
 ``examples/long_context_serving.py`` route through this class.
@@ -31,10 +38,12 @@ source for serving traffic:
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import queue
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Union
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -55,12 +64,15 @@ class SampleService:
         self.batch = int(batch)
         self.prefetch = int(prefetch)
         self.attrs = list(self.samplers[0].attrs)
-        self._queue: "queue.Queue[SampleSet]" = queue.Queue(
+        # (batch id, batch): ids number the batches in production order
+        self._queue: "queue.Queue[Tuple[int, SampleSet]]" = queue.Queue(
             maxsize=max(self.prefetch, 1))
+        self._batch_ids = itertools.count()
         self._stop = threading.Event()
         self._error: Optional[BaseException] = None
         self._threads: List[threading.Thread] = []
         self._cursor: Optional[SampleSet] = None    # partially drained batch
+        self._cursor_id = -1
         self._cursor_pos = 0
         self._lock = threading.Lock()               # request serialisation
         self.served = 0
@@ -101,7 +113,7 @@ class SampleService:
         self._threads = []
         if self._collector is not None:     # single-use: stop scraping us
             reg, fn = self._collector
-            fn()        # final quantile/engine refresh (producers quiesced)
+            fn()        # final engine-stat refresh (producers quiesced)
             reg.remove_collector(fn)
             self._collector = None
 
@@ -138,15 +150,22 @@ class SampleService:
                 self._error = e
                 self._stop.set()
                 return
-            while not self._stop.is_set():
-                try:
-                    self._queue.put(ss, timeout=0.1)
-                    break
-                except queue.Full:
-                    continue
+            item = (next(self._batch_ids), ss)
+            try:
+                self._queue.put_nowait(item)
+                continue
+            except queue.Full:
+                pass
+            with obs.span("repro/serve/put_wait", batch=item[0]):
+                while not self._stop.is_set():
+                    try:
+                        self._queue.put(item, timeout=0.1)
+                        break
+                    except queue.Full:
+                        continue
 
     # -------------------------------------------------------------- consumer
-    def _next_batch(self, timeout: float) -> SampleSet:
+    def _next_batch(self, timeout: float) -> Tuple[int, SampleSet]:
         while True:
             if self._error is not None:
                 raise RuntimeError("sample producer failed") from self._error
@@ -162,8 +181,9 @@ class SampleService:
     # ------------------------------------------------------------- telemetry
     def _obs_handles(self) -> Dict:
         """Serve-tier metric handles (get-or-create in the registry); the
-        queue-depth gauge and p50/p99 + per-replica stat gauges refresh at
-        scrape time via a registry collector (removed again on stop)."""
+        queue-depth gauge and the per-replica stat gauges refresh at scrape
+        time (the latter via a registry collector, removed again on
+        stop)."""
         if self._obs_m is None:
             reg = (self._registry if self._registry is not None
                    else obs.get_registry())
@@ -171,6 +191,12 @@ class SampleService:
                 "latency": reg.histogram(
                     "repro_serve_request_seconds",
                     "end-to-end SampleService.request latency"),
+                "queue_wait": reg.histogram(
+                    "repro_serve_queue_wait_seconds",
+                    "part of a request blocked on the prefetch queue"),
+                "assemble": reg.histogram(
+                    "repro_serve_assemble_seconds",
+                    "part of a request concatenating its answer"),
                 "requests": reg.counter(
                     "repro_serve_requests_total",
                     "sample requests served"),
@@ -183,12 +209,6 @@ class SampleService:
                 "capacity": reg.gauge(
                     "repro_serve_prefetch_capacity",
                     "prefetch queue capacity (batches)"),
-                "p50": reg.gauge(
-                    "repro_serve_request_seconds_p50",
-                    "median request latency (bucket-interpolated)"),
-                "p99": reg.gauge(
-                    "repro_serve_request_seconds_p99",
-                    "p99 request latency (bucket-interpolated)"),
                 "engine": reg.gauge(
                     "repro_serve_engine_stat",
                     "per-replica engine SamplerStats fields",
@@ -198,8 +218,6 @@ class SampleService:
             m["capacity"].set(self._queue.maxsize)
 
             def collect():
-                m["p50"].set(m["latency"].quantile(0.5))
-                m["p99"].set(m["latency"].quantile(0.99))
                 for i, s in enumerate(self.samplers):
                     for field, v in s.stats.as_dict().items():
                         m["engine"].labels(str(i), field).set(v)
@@ -220,34 +238,62 @@ class SampleService:
         if n <= 0:
             from ..core.union_sampler import empty_sample_set
             return empty_sample_set(self.attrs, self.stats())
-        parts: List[SampleSet] = []
-        got = 0
-        with self._lock:
-            while got < n:
-                if self._cursor is None:
-                    self._cursor = self._next_batch(timeout)
-                    self._cursor_pos = 0
-                cur, lo = self._cursor, self._cursor_pos
-                hi = min(lo + n - got, len(cur))
-                parts.append(SampleSet(
-                    cur.attrs, {a: c[lo:hi] for a, c in cur.rows.items()},
-                    cur.home[lo:hi], cur.fingerprint[lo:hi], cur.stats))
-                got += hi - lo
-                if hi >= len(cur):
-                    self._cursor = None
-                else:
-                    self._cursor_pos = hi
-            self.served += got
-        rows = {a: np.concatenate([p.rows[a] for p in parts])
-                for a in self.attrs}
-        home = np.concatenate([p.home for p in parts])
-        fp = np.concatenate([p.fingerprint for p in parts])
+        with obs.span("repro/serve/request", n=n) as span:
+            parts, batches, waited = self._take(n, timeout)
+            # TraceMe metadata is `k=v,k=v`: the ids are joined with `|`
+            span.set_metadata(batches="|".join(map(str, batches)))
+            t_asm = time.perf_counter() if t0 is not None else None
+            with obs.span("repro/serve/assemble"):
+                rows = {a: np.concatenate([p.rows[a] for p in parts])
+                        for a in self.attrs}
+                home = np.concatenate([p.home for p in parts])
+                fp = np.concatenate([p.fingerprint for p in parts])
+                out = SampleSet(self.attrs, rows, home, fp, self.stats())
         if t0 is not None:
+            t1 = time.perf_counter()
             m = self._obs_handles()
-            m["latency"].observe(time.perf_counter() - t0)
+            m["latency"].observe(t1 - t0)
+            m["queue_wait"].observe(waited)
+            m["assemble"].observe(t1 - t_asm)
             m["requests"].inc()
-            m["samples"].inc(got)
-        return SampleSet(self.attrs, rows, home, fp, self.stats())
+            m["samples"].inc(len(out))
+        return out
+
+    def _take(self, n: int, timeout: float
+              ) -> Tuple[List[SampleSet], List[int], float]:
+        """Slice ``n`` samples off the shared stream under the request lock:
+        the slices (views), the ids of the batches they come from, and the
+        seconds spent blocked on the prefetch queue."""
+        parts: List[SampleSet] = []
+        batches: List[int] = []
+        waited = 0.0
+        got = 0
+        lock_wait = contextlib.ExitStack()
+        with lock_wait:
+            lock_wait.enter_context(obs.span("repro/serve/lock_wait"))
+            with self._lock:
+                lock_wait.close()           # the wait ends with the lock held
+                while got < n:
+                    if self._cursor is None:
+                        tq = time.perf_counter()
+                        with obs.span("repro/serve/queue_wait"):
+                            self._cursor_id, self._cursor = \
+                                self._next_batch(timeout)
+                        waited += time.perf_counter() - tq
+                        self._cursor_pos = 0
+                    cur, lo = self._cursor, self._cursor_pos
+                    hi = min(lo + n - got, len(cur))
+                    parts.append(SampleSet(
+                        cur.attrs, {a: c[lo:hi] for a, c in cur.rows.items()},
+                        cur.home[lo:hi], cur.fingerprint[lo:hi], cur.stats))
+                    batches.append(self._cursor_id)
+                    got += hi - lo
+                    if hi >= len(cur):
+                        self._cursor = None
+                    else:
+                        self._cursor_pos = hi
+                self.served += got
+        return parts, batches, waited
 
     def stats(self) -> SamplerStats:
         """Merged cost accounting across all engines (associative merge)."""

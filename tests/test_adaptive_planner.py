@@ -13,12 +13,14 @@ argument of the shortfall carry is untouched.  Pinned here:
 * chi-square uniformity of adaptive streams on UQ1 (acyclic) and UQ4
   (cyclic), jax engine and 1-device mesh;
 * ``SamplerStats.psi()`` / ``samples_emitted`` accounting and the
-  ``repro_round_waste_ratio`` gauge;
+  per-piece draw/accept counters a waste ratio derives from;
 * the ONLINE-UNION host twin (``OnlineUnionSampler(plan="adaptive")``)
   batches fresh draws by the same EMAs and reseeds them at φ-refresh;
 * :class:`PlanCache` cost-model fit/suggest determinism and the
   ``round_batch=None`` autotune entry point.
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -240,7 +242,17 @@ def test_waste_gauge_published():
                         plan="adaptive")
     s.sample(1000)
     text = obs.get_registry().render()
-    assert "repro_round_waste_ratio" in text
+    # per piece, waste = 1 - accepts_total / draws_total of the exported
+    # counters (what a Prometheus query derives)
+    for j in s._engine.order:
+        draws = re.search(rf'^repro_engine_piece_draws_total{{join="{j}"}}'
+                          r" (\S+)$", text, re.M)
+        accepts = re.search(
+            rf'^repro_engine_piece_accepts_total{{join="{j}"}} (\S+)$',
+            text, re.M)
+        assert draws and accepts
+        assert 0.0 <= 1.0 - float(accepts.group(1)) / float(draws.group(1)) \
+            < 1.0
     assert "repro_engine_piece_ema" in text
 
 

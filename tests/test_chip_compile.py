@@ -119,3 +119,12 @@ def test_device_loop_with_pallas_probes_compiles_for_v5e(one_chip,
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
     assert " while(" in text
+    # the loop's phase scopes are metadata only: the probe kernels keep the
+    # names the benchmark's trace reduction finds them by, and each sits in
+    # the walk phase of its piece
+    from repro import obs
+    phases = obs.hlo_op_phases(text)
+    kernels = re.findall(r"^\s*%([\w.-]+) = [^\n]*custom_call_target="
+                         r'"tpu_custom_call"', text, re.M)
+    assert kernels and all(k.startswith("_searchsorted_i32") for k in kernels)
+    assert {phases[k].split("/")[0] for k in kernels} == {"walk"}

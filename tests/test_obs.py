@@ -11,7 +11,12 @@ Pinned here:
   merged accounting under concurrent producers;
 * **parity** — the per-piece carry counters ride in the jitted programs
   unconditionally, so device/host streams stay bitwise identical whether
-  telemetry is on or off, and ``piece_stats`` itself agrees bit for bit;
+  telemetry or its profiler spans are on or off, and ``piece_stats``
+  itself agrees bit for bit;
+* spans and phases — every span the program opens is in ``obs.SPANS``,
+  the engine's drain splits exactly into its wait and its assembly, the
+  serve histograms take one observation per request, and every op of the
+  device loop's body sits in one of the loop phases;
 * BENCH ``write_json`` appending runs to ``history`` instead of clobbering;
 * ONLINE-UNION exposing its refinement history (``refresh_count``,
   ``last_refresh_at``, trace events) instead of discarding it.
@@ -210,24 +215,145 @@ def _assert_same(a, b):
     np.testing.assert_array_equal(a.fingerprint, b.fingerprint)
 
 
-def test_parity_unchanged_by_telemetry(registry):
-    """Samples are bitwise identical device vs host, obs on vs off — the
-    per-piece counters are pure extra carry outputs, never inputs."""
+def test_parity_unchanged_by_telemetry(registry, monkeypatch):
+    """Samples are bitwise identical device vs host, obs on vs off, spans
+    (``REPRO_OBS_TRACE=1``) on vs off — the per-piece counters are pure
+    extra carry outputs, never inputs, and spans and phase scopes are host
+    annotations and op metadata."""
     wl = uq1(scale=0.02, overlap=0.4, seed=0, n_joins=2)
     cover = _cover(wl)
     streams = {}
-    for obs_state in (True, False):
+    for obs_state, trace in ((True, "1"), (True, ""), (False, "")):
         obs.set_enabled(obs_state)
+        monkeypatch.setenv("REPRO_OBS_TRACE", trace)
+        assert obs.trace_annotations_enabled() == bool(trace)
         try:
             dev, host = _engine(wl, cover, "device"), _engine(wl, cover, "host")
             for n in (700, 333):
                 _assert_same(dev.sample(n), host.sample(n))
             assert dev.stats.as_dict() == host.stats.as_dict()
             assert np.array_equal(dev.piece_stats, host.piece_stats)
-            streams[obs_state] = dev.sample(200)
+            streams[obs_state, trace] = dev.sample(200)
         finally:
             obs.set_enabled(None)
-    _assert_same(streams[True], streams[False])
+    _assert_same(streams[True, "1"], streams[True, ""])
+    _assert_same(streams[True, ""], streams[False, ""])
+    # the traced engine published its loop's op phases
+    assert "walk/" + cover.order[0] in set(
+        obs.op_phases("jit_loop_fn").values())
+
+
+def test_drain_splits_into_wait_and_assembly(registry, obs_on):
+    """Per call, the drain observation is the device wait plus the host
+    assembly, to rounding."""
+    wl = uq1(scale=0.02, overlap=0.4, seed=0, n_joins=2)
+    s = _engine(wl, _cover(wl), "device")
+
+    def sums():
+        snap = registry.snapshot()
+        return [snap[name]["series"][()] for name in (
+            "repro_engine_drain_seconds", "repro_engine_drain_wait_seconds",
+            "repro_engine_assemble_seconds")]
+
+    s.sample(500)
+    for n in (700, 1500):
+        before = sums()
+        s.sample(n)
+        drain, wait, asm = [{k: a[k] - b[k] for k in ("sum", "count")}
+                            for a, b in zip(sums(), before)]
+        assert drain["count"] == wait["count"] == asm["count"] == 1
+        assert wait["sum"] > 0 and asm["sum"] > 0
+        assert wait["sum"] + asm["sum"] == pytest.approx(drain["sum"],
+                                                         rel=1e-9)
+
+
+def test_spans_are_named_in_spans(monkeypatch):
+    """Spans are no-ops while REPRO_OBS_TRACE is off; on, a name outside
+    ``obs.SPANS`` is refused, so the constant lists every span."""
+    monkeypatch.setenv("REPRO_OBS_TRACE", "")
+    with obs.span("not/a/span") as sp:      # off: nothing is checked
+        sp.set_metadata(x=1)
+    monkeypatch.setenv("REPRO_OBS_TRACE", "1")
+    obs.set_enabled(True)
+    try:
+        with obs.span("repro/serve/request", n=3) as sp:
+            sp.set_metadata(batches="0|1")
+        with pytest.raises(ValueError):
+            obs.span("repro/not_listed")
+    finally:
+        obs.set_enabled(None)
+    assert len(set(obs.SPANS)) == len(obs.SPANS)
+    assert all(n.startswith("repro/") for n in obs.SPANS)
+
+
+@pytest.mark.parametrize("op_name,phase", [
+    ("jit(loop_fn)/while/body/algo1_fused_round/walk/J1/gather", "walk/J1"),
+    ("jit(loop_fn)/while/body/algo1_fused_round/member/J2/jit(searchsorted)"
+     "/jit(loop_fn)/while/body/algo1_fused_round/member/J2/lt", "member/J2"),
+    ("jit(loop_fn)/while/body/select/jit(_threefry_split)/add", "select"),
+    ("jit(loop_fn)/while/body/emit/scatter", "emit"),
+    ("jit(loop_fn)/while/body/algo1_fused_round/carry/concatenate", "carry"),
+    ("jit(loop_fn)/while/body/add", obs.UNSCOPED),
+    ("jit(loop_fn)/while/body/algo1_fused_round/select_n", obs.UNSCOPED),
+    ("gather", obs.UNSCOPED),
+])
+def test_phase_of_name_stacks(op_name, phase):
+    assert obs.phase_of(op_name) == phase
+
+
+def test_hlo_op_phases_and_publication():
+    text = "\n".join([
+        "HloModule jit_toy, entry_computation_layout={()->s32[]}",
+        "",
+        "ENTRY %main.1 () -> s32[] {",
+        '  %gather.3 = s32[8]{0} gather(), metadata={op_name="jit(toy)/'
+        'while/body/algo1_fused_round/walk/UQ2_JN/gather" '
+        'stack_frame_id=2}',
+        "  %constant.1 = s32[] constant(0)",
+        '  ROOT %fusion.7 = s32[] fusion(), kind=kLoop, metadata={'
+        'op_name="jit(toy)/while/body/emit/add"}',
+        "}"])
+    phases = obs.hlo_op_phases(text)
+    assert phases == {"gather.3": "walk/UQ2_JN", "constant.1": obs.UNSCOPED,
+                      "fusion.7": "emit"}
+    obs.publish_op_phases(text)
+    assert obs.op_phases("jit_toy") == phases
+    assert obs.op_phases("jit_other") == {}
+    with pytest.raises(ValueError):
+        obs.publish_op_phases("not hlo")
+
+
+@pytest.mark.parametrize("workload", ["uq1", "uq2"])
+def test_every_loop_body_op_sits_in_a_phase(workload):
+    """Lower the device loop of a small UQ1 and UQ2 union: every op of the
+    ``while`` body carries one of the phase scopes in its ``op_name``
+    metadata."""
+    import jax
+    import jax.numpy as jnp
+    from repro.data.workloads import uq2
+    wl = (uq1(scale=0.1, seed=0, n_joins=2) if workload == "uq1"
+          else uq2(scale=0.05, seed=0))
+    eng = _engine(wl, _cover(wl), "device")
+    eng._ensure_device_inputs()
+    C = 1024
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+        (eng._init_state(), eng._out_buffer(C), jnp.int32(C),
+         eng._probs_base, eng._catalog_args()))
+    # the program as lowered, before XLA's passes add ops of their own
+    hlo = eng._build_loop(C).lower(*shapes).compiler_ir(
+        "hlo").as_hlo_module().to_string()
+    body_ops = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.-]+ = .*? ([a-z][\w-]*)\(.*?"
+        r'op_name="(jit\(loop_fn\)/while/body/[^"]*)"', hlo, re.M)
+    assert len(body_ops) > 100
+    bare = [(op, name) for op, name in body_ops
+            if obs.phase_of(name) == obs.UNSCOPED]
+    assert not bare, bare[:10]
+    phases = {obs.phase_of(name) for _, name in body_ops}
+    for j in eng.order:
+        assert {f"walk/{j}", f"compact/{j}"} <= phases
+    assert {"select", "emit", "carry", f"member/{eng.order[-1]}"} <= phases
 
 
 def test_piece_stats_consistency(registry, obs_on):
@@ -281,7 +407,14 @@ def test_serve_concurrent_accounting_and_metrics(registry, obs_on):
     assert snap["repro_serve_samples_total"]["series"][()] == 2550
     lat = snap["repro_serve_request_seconds"]["series"][()]
     assert lat["count"] == 4 and lat["sum"] > 0
-    assert snap["repro_serve_request_seconds_p50"]["series"][()] > 0
+    # the median, as a Prometheus client derives it from the _bucket series
+    assert registry.get("repro_serve_request_seconds").quantile(0.5) > 0
+    # one queue-wait and one assembly observation per request, each a part
+    # of the request's latency
+    for part in ("repro_serve_queue_wait_seconds",
+                 "repro_serve_assemble_seconds"):
+        h = snap[part]["series"][()]
+        assert h["count"] == 4 and 0 <= h["sum"] <= lat["sum"]
     # engine stat gauges carry the replica label
     eng = snap["repro_serve_engine_stat"]["series"]
     assert eng[(("replica", "0"), ("field", "candidate_draws"))] \
