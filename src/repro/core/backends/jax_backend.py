@@ -444,6 +444,15 @@ class DeviceTreeJoin:
         self.root_wprefix = jnp.asarray(self.host_root_wprefix, jnp.float32)
         self.total_weight = float(js.root_weight_total)
         self._empty = js.is_empty()
+        if obs.enabled():
+            levels = obs.get_registry().gauge(
+                "repro_engine_probe_levels",
+                "fence levels of a node's Pallas range probe (1 or 2)",
+                ("join", "node"))
+            for cfg, prep in zip(self.node_cfgs, self._prepped):
+                if prep is not None:
+                    levels.labels(join=self.name, node=cfg.alias).set(
+                        prep.levels)
 
     def is_empty(self) -> bool:
         return self._empty
@@ -456,20 +465,18 @@ class DeviceTreeJoin:
         so the catalog is never compiled into a program as constants:
         program size, compile time and the compile-cache key stay
         independent of the data.  Per non-root node, ``probe`` is the sorted
-        key column (``jnp.searchsorted``) or the Pallas layout (fences +
-        key blocks)."""
+        key column (``jnp.searchsorted``) or the Pallas layout
+        (``PreparedKeys.arrays``: top fences, fences, key blocks)."""
         nodes = []
         for i in range(len(self.node_cfgs)):
             prep = self._prepped[i]
-            probe = (self.sorted_keys[i] if prep is None
-                     else (prep.f_hi2, prep.f_lo2, prep.keys2d_hi,
-                           prep.keys2d_lo))
+            probe = self.sorted_keys[i] if prep is None else prep.arrays()
             nodes.append({"probe": probe, "perm": self.perm[i],
                           "wprefix": self.wprefix[i], "cols": self.cols[i]})
         return {"root_wprefix": self.root_wprefix,
                 "root_cols": self.root_cols, "nodes": nodes}
 
-    # -- range probe: jnp.searchsorted, or the two-phase Pallas pipeline ------
+    # -- range probe: jnp.searchsorted, or the Pallas fence search ----------
     # analysis: traced
     def _ranges(self, i: int, probe, q: jnp.ndarray
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -478,18 +485,13 @@ class DeviceTreeJoin:
                     jnp.searchsorted(probe, q, side="right").astype(jnp.int32))
         from ...kernels.ops import default_interpret
         from ...kernels.searchsorted import _searchsorted_i32, to_tiles
-        prep = self._prepped[i]
-        f_hi2, f_lo2, keys2d_hi, keys2d_lo = probe
         b = q.shape[0]
         # keys are non-negative int32, so the 64-bit split is (hi=0, lo=q^MIN)
         q_lo = to_tiles(q) ^ jnp.int32(-(1 << 31))
         q_hi = jnp.zeros_like(q_lo)
-        lo, hi = _searchsorted_i32(q_hi, q_lo, f_hi2, f_lo2,
-                                   keys2d_hi, keys2d_lo,
-                                   n_chunks=prep.n_chunks,
-                                   n_fences=prep.n_blocks,
+        lo, hi = _searchsorted_i32(q_hi, q_lo, *probe,
                                    interpret=default_interpret())
-        n = jnp.int32(prep.n)
+        n = jnp.int32(self._prepped[i].n)
         return (jnp.minimum(lo.reshape(-1)[:b], n),
                 jnp.minimum(hi.reshape(-1)[:b], n))
 

@@ -143,10 +143,7 @@ class DeviceWalkJoin:
         q_lo = to_tiles(q) ^ jnp.int32(-(1 << 31))
         q_hi = jnp.zeros_like(q_lo)
         pos, deg = _hop_i32(q_hi, q_lo, to_tiles(u.astype(jnp.float32)),
-                            prep.f_hi2, prep.f_lo2,
-                            prep.keys2d_hi, prep.keys2d_lo,
-                            n_chunks=prep.n_chunks, n_fences=prep.n_blocks,
-                            interpret=default_interpret())
+                            *prep.arrays(), interpret=default_interpret())
         return pos.reshape(-1)[:b], deg.reshape(-1)[:b]
 
     # -- one batch of walks (traced; jit at the call site) --------------------

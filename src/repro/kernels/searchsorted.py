@@ -3,16 +3,26 @@
 Every probe / degree / membership / EW-aggregation primitive in the sampler
 reduces to ``lo = #keys < q`` / ``hi = #keys <= q`` against a sorted key
 column.  TPUs have no efficient per-lane gather, so the paper's hash-probe
-becomes a **two-phase dense-compare search** (DESIGN.md §2/§6):
+becomes a **dense-compare search over fences** (DESIGN.md §2/§6):
 
-* **Phase A — fence sweep** (`fence_count_kernel`): the fence array
-  (every 128th sorted key) is VMEM-resident; each query tile counts
-  ``#fences < q`` and ``#fences <= q`` by chunked broadcast-compare on the
-  VPU (branchless, gather-free).  This pins each boundary to one 128-key
-  block: for ``blk_l = #fences<q - 1``, every key in an earlier block is
-  ``<= fences[blk_l] < q`` and every key in a later block is
-  ``>= fences[blk_l+1] >= q`` — including runs of equal keys that straddle
-  block boundaries.
+* **Phase A — block ids.**  The fences are every 128th sorted key; a
+  query's boundary block is ``blk_l = #fences<q - 1`` (clipped at 0): every
+  key in an earlier block is ``<= fences[blk_l] < q`` and every key in a
+  later block is ``>= fences[blk_l+1] >= q`` — including runs of equal keys
+  that straddle block boundaries (``blk_r`` likewise from ``#fences<=q``).
+
+  - *One level* (`fence_count_kernel`): the fence array is VMEM-resident;
+    each query tile counts ``#fences < q`` and ``#fences <= q`` by chunked
+    broadcast-compare on the VPU (branchless, gather-free).  Its work is
+    one 128-fence chunk per 16,384 keys, per query.
+  - *Two levels*, on an index of at least ``TWO_LEVEL_MIN_CHUNKS`` fence
+    chunks: the **top fences** (every 128th fence, every 16,384th key) are
+    swept the same way, which pins each boundary to one row of 128 fences
+    (``s = #top<q - 1``, by the same counting argument one level up).  XLA
+    gathers that row and a refine-form compare counts the fences in it:
+    ``#fences<q = 128 s + #row<q``.  The work per query is then a sweep of
+    the top fences plus one row compare, whatever the index size.  The
+    number of levels is static, from the index's size alone.
 * **XLA row-gather**: the per-query 128-key refinement rows are gathered by
   XLA (`keys2d[block_id]`) — irregular data movement is XLA's job on TPU;
   dense compute is Pallas's.
@@ -22,7 +32,9 @@ becomes a **two-phase dense-compare search** (DESIGN.md §2/§6):
 int64 keys are carried as (hi32, biased-lo32) pairs with lexicographic
 compares (TPU vector ALUs are 32-bit; the split happens host-side in numpy so
 the jitted graph is pure int32).  Padding uses +inf sentinels (INT32_MAX
-pairs), which never count as ``< q`` or ``<= q`` for real queries.
+pairs), which never count as ``< q`` or ``<= q`` for real queries; fence
+counts are capped at the number of real fences, so a query equal to the
+sentinel cannot count fence padding either.
 """
 
 from __future__ import annotations
@@ -38,6 +50,9 @@ from jax.experimental import pallas as pl
 KEY_BLOCK = 128          # keys per refinement block (fence stride)
 QUERY_TILE = 256         # queries per grid step
 FENCE_CHUNK = 128        # fences compared per inner iteration
+# An index of at least this many fence chunks (x 16,384 keys) searches its
+# fences in two levels; below it, one sweep is cheaper (chip sweep: PERF.md).
+TWO_LEVEL_MIN_CHUNKS = 5
 
 _I64_MAX = np.iinfo(np.int64).max
 
@@ -70,6 +85,11 @@ def _le(a_hi, a_lo, b_hi, b_lo):
 # ---------------------------------------------------------------------------
 
 
+def probe_levels(n_chunks: int) -> int:
+    """Fence levels of an index with ``n_chunks`` chunks of 128 fences."""
+    return 2 if n_chunks >= TWO_LEVEL_MIN_CHUNKS else 1
+
+
 def fence_count_kernel(q_hi_ref, q_lo_ref, f_hi_ref, f_lo_ref,
                        blk_l_ref, blk_r_ref, *, n_chunks: int,
                        n_fences: int):
@@ -98,21 +118,36 @@ def fence_count_kernel(q_hi_ref, q_lo_ref, f_hi_ref, f_lo_ref,
 
 
 # ---------------------------------------------------------------------------
-# Phase B: refine within the gathered 128-key rows
+# Row compares: fence rows (second level) and key rows (phase B)
 # ---------------------------------------------------------------------------
 
 
-def refine_kernel(q_hi_ref, q_lo_ref, blk_l_ref, blk_r_ref,
-                  row_l_hi_ref, row_l_lo_ref, row_r_hi_ref, row_r_lo_ref,
-                  lo_ref, hi_ref):
+def row_counts(q_hi_ref, q_lo_ref, base_l_ref, base_r_ref,
+               row_l_hi_ref, row_l_lo_ref, row_r_hi_ref, row_r_lo_ref):
+    """``(#sorted < q, #sorted <= q)`` per query, from the gathered
+    ``(TQ, W)`` rows holding each boundary and the row ids ``base``."""
     q_hi = q_hi_ref[0, 0, :][:, None]         # (TQ, 1)
     q_lo = q_lo_ref[0, 0, :][:, None]
+    width = row_l_hi_ref.shape[-1]
     lt = _lt(row_l_hi_ref[0], row_l_lo_ref[0], q_hi, q_lo)
     le = _le(row_r_hi_ref[0], row_r_lo_ref[0], q_hi, q_lo)
-    lo_ref[0, 0, :] = (blk_l_ref[0, 0, :] * KEY_BLOCK
-                       + jnp.sum(lt.astype(jnp.int32), axis=1))
-    hi_ref[0, 0, :] = (blk_r_ref[0, 0, :] * KEY_BLOCK
-                       + jnp.sum(le.astype(jnp.int32), axis=1))
+    return (base_l_ref[0, 0, :] * width + jnp.sum(lt.astype(jnp.int32), axis=1),
+            base_r_ref[0, 0, :] * width + jnp.sum(le.astype(jnp.int32), axis=1))
+
+
+def fence_row_kernel(*refs, n_fences: int):
+    """Second level: boundary block ids from the fence rows under each
+    query's top fences (the counts are capped at the real fences)."""
+    lo, hi = row_counts(*refs[:8])
+    blk_l_ref, blk_r_ref = refs[8:]
+    blk_l_ref[0, 0, :] = jnp.clip(jnp.minimum(lo, n_fences) - 1, 0, None)
+    blk_r_ref[0, 0, :] = jnp.clip(jnp.minimum(hi, n_fences) - 1, 0, None)
+
+
+def refine_kernel(*refs):
+    """Phase B: exact ``(lo, hi)`` from the gathered 128-key rows."""
+    lo_ref, hi_ref = refs[8:]
+    lo_ref[0, 0, :], hi_ref[0, 0, :] = row_counts(*refs[:8])
 
 
 # ---------------------------------------------------------------------------
@@ -138,10 +173,36 @@ def to_tiles(x: jnp.ndarray) -> jnp.ndarray:
     return x.reshape(-1, 1, QUERY_TILE)
 
 
-def fence_blocks(q_hi3, q_lo3, f_hi2, f_lo2, n_chunks: int, n_fences: int,
-                 interpret: bool):
-    """Phase A over all query tiles: per-query lo/hi boundary block ids."""
+def gather_rows(a_hi2, a_lo2, blk_l, blk_r):
+    """XLA row-gather of the rows holding each query's two boundaries:
+    ``(row_l_hi, row_l_lo, row_r_hi, row_r_lo)``, each (qt, TQ, width)."""
+    qt = blk_l.shape[0]
+    return tuple(a[b.reshape(-1)].reshape(qt, QUERY_TILE, a.shape[1])
+                 for b in (blk_l, blk_r) for a in (a_hi2, a_lo2))
+
+
+def row_call(kernel, q_hi3, q_lo3, base_l, base_r, rows, extra=(),
+             interpret: bool = True):
+    """One Pallas row compare over all query tiles: the query tiles, the
+    row ids, the four gathered rows and ``extra`` tiles in; two tiles out."""
     qt = q_hi3.shape[0]
+    tile = tile_spec()
+    row = pl.BlockSpec((1, QUERY_TILE, rows[0].shape[-1]), lambda i: (i, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(qt,),
+        in_specs=[tile] * 4 + [row] * 4 + [tile] * len(extra),
+        out_specs=[tile] * 2,
+        out_shape=[tiles_shape(qt)] * 2,
+        interpret=interpret,
+    )(q_hi3, q_lo3, base_l, base_r, *rows, *extra)
+
+
+def fence_sweep(q_hi3, q_lo3, f_hi2, f_lo2, n_fences: int, interpret: bool):
+    """One sweep over all query tiles: per-query boundary ids among the
+    ``n_fences`` fences of ``f_hi2``/``f_lo2`` (chunks of FENCE_CHUNK)."""
+    qt = q_hi3.shape[0]
+    n_chunks = f_hi2.shape[0]
     fences = pl.BlockSpec((n_chunks, FENCE_CHUNK), lambda i: (0, 0))
     return pl.pallas_call(
         functools.partial(fence_count_kernel, n_chunks=n_chunks,
@@ -154,32 +215,29 @@ def fence_blocks(q_hi3, q_lo3, f_hi2, f_lo2, n_chunks: int, n_fences: int,
     )(q_hi3, q_lo3, f_hi2, f_lo2)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_chunks", "n_fences", "interpret"))
-def _searchsorted_i32(q_hi3, q_lo3, f_hi2, f_lo2, keys2d_hi, keys2d_lo,
-                      n_chunks: int, n_fences: int, interpret: bool = True):
-    qt = q_hi3.shape[0]
-    blk_l, blk_r = fence_blocks(q_hi3, q_lo3, f_hi2, f_lo2, n_chunks,
-                                n_fences, interpret)
+def fence_blocks(q_hi3, q_lo3, t_hi2, t_lo2, f_hi2, f_lo2, n_fences: int,
+                 interpret: bool):
+    """Phase A over all query tiles: per-query lo/hi boundary block ids,
+    in one level or two (``probe_levels`` of the index's fence chunks)."""
+    n_chunks = f_hi2.shape[0]
+    if probe_levels(n_chunks) == 1:
+        return fence_sweep(q_hi3, q_lo3, f_hi2, f_lo2, n_fences, interpret)
+    s_l, s_r = fence_sweep(q_hi3, q_lo3, t_hi2, t_lo2, n_chunks, interpret)
+    return row_call(functools.partial(fence_row_kernel, n_fences=n_fences),
+                    q_hi3, q_lo3, s_l, s_r,
+                    gather_rows(f_hi2, f_lo2, s_l, s_r), interpret=interpret)
 
-    # XLA row-gather of refinement blocks
-    bl = blk_l.reshape(-1)
-    br = blk_r.reshape(-1)
-    row_l_hi = keys2d_hi[bl].reshape(qt, QUERY_TILE, KEY_BLOCK)
-    row_l_lo = keys2d_lo[bl].reshape(qt, QUERY_TILE, KEY_BLOCK)
-    row_r_hi = keys2d_hi[br].reshape(qt, QUERY_TILE, KEY_BLOCK)
-    row_r_lo = keys2d_lo[br].reshape(qt, QUERY_TILE, KEY_BLOCK)
 
-    lo, hi = pl.pallas_call(
-        refine_kernel,
-        grid=(qt,),
-        in_specs=[tile_spec()] * 4 + [
-            pl.BlockSpec((1, QUERY_TILE, KEY_BLOCK), lambda i: (i, 0, 0))] * 4,
-        out_specs=[tile_spec()] * 2,
-        out_shape=[tiles_shape(qt)] * 2,
-        interpret=interpret,
-    )(q_hi3, q_lo3, blk_l, blk_r, row_l_hi, row_l_lo, row_r_hi, row_r_lo)
-    return lo, hi
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _searchsorted_i32(q_hi3, q_lo3, t_hi2, t_lo2, f_hi2, f_lo2, keys2d_hi,
+                      keys2d_lo, interpret: bool = True):
+    """``(lo, hi)`` tiles of the queries against one index's arrays
+    (:meth:`PreparedKeys.arrays`)."""
+    blk_l, blk_r = fence_blocks(q_hi3, q_lo3, t_hi2, t_lo2, f_hi2, f_lo2,
+                                keys2d_hi.shape[0], interpret)
+    return row_call(refine_kernel, q_hi3, q_lo3, blk_l, blk_r,
+                    gather_rows(keys2d_hi, keys2d_lo, blk_l, blk_r),
+                    interpret=interpret)
 
 
 class PreparedKeys:
@@ -193,11 +251,23 @@ class PreparedKeys:
         k_hi, k_lo = split64_np(kp)
         self.keys2d_hi = jnp.asarray(k_hi.reshape(self.n_blocks, KEY_BLOCK))
         self.keys2d_lo = jnp.asarray(k_lo.reshape(self.n_blocks, KEY_BLOCK))
-        fences = _pad_np(kp[::KEY_BLOCK], FENCE_CHUNK, _I64_MAX)
-        f_hi, f_lo = split64_np(fences)
+        fences = kp[::KEY_BLOCK]
+        f_hi, f_lo = split64_np(_pad_np(fences, FENCE_CHUNK, _I64_MAX))
         self.n_chunks = f_hi.shape[0] // FENCE_CHUNK
         self.f_hi2 = jnp.asarray(f_hi.reshape(self.n_chunks, FENCE_CHUNK))
         self.f_lo2 = jnp.asarray(f_lo.reshape(self.n_chunks, FENCE_CHUNK))
+        # top fences: the first fence of every row of f_hi2 / f_lo2
+        t_hi, t_lo = split64_np(_pad_np(fences[::FENCE_CHUNK], FENCE_CHUNK,
+                                        _I64_MAX))
+        self.t_hi2 = jnp.asarray(t_hi.reshape(-1, FENCE_CHUNK))
+        self.t_lo2 = jnp.asarray(t_lo.reshape(-1, FENCE_CHUNK))
+        self.levels = probe_levels(self.n_chunks)
+
+    def arrays(self) -> Tuple[jnp.ndarray, ...]:
+        """The device arrays a probe reads, in ``_searchsorted_i32`` order:
+        top fences, fences, key blocks (each as hi, lo)."""
+        return (self.t_hi2, self.t_lo2, self.f_hi2, self.f_lo2,
+                self.keys2d_hi, self.keys2d_lo)
 
 
 def searchsorted_pallas(keys, queries, interpret: bool = True
@@ -212,8 +282,7 @@ def searchsorted_pallas(keys, queries, interpret: bool = True
     lo, hi = _searchsorted_i32(
         jnp.asarray(q_hi.reshape(qt, 1, QUERY_TILE)),
         jnp.asarray(q_lo.reshape(qt, 1, QUERY_TILE)),
-        prep.f_hi2, prep.f_lo2, prep.keys2d_hi, prep.keys2d_lo,
-        n_chunks=prep.n_chunks, n_fences=prep.n_blocks, interpret=interpret)
+        *prep.arrays(), interpret=interpret)
     lo = np.minimum(np.asarray(lo).reshape(-1)[:nq], prep.n)
     hi = np.minimum(np.asarray(hi).reshape(-1)[:nq], prep.n)
     return lo, hi

@@ -122,6 +122,52 @@ def test_pallas_probe_path_matches_jnp():
         assert np.array_equal(np.asarray(r1[a]), np.asarray(r2[a])), a
 
 
+def _two_level_chain():
+    """A -a- B -b- C: B's index is on the two-level fence search (at least
+    three top fences), A's and C's hold one fence chunk."""
+    from repro.core.relation import Relation
+    from repro.kernels.searchsorted import (FENCE_CHUNK, KEY_BLOCK,
+                                            TWO_LEVEL_MIN_CHUNKS)
+    rng = np.random.default_rng(3)
+    n_b = max(3, TWO_LEVEL_MIN_CHUNKS) * KEY_BLOCK * FENCE_CHUNK + 1_000
+    A = Relation("A", {"a": np.arange(600), "x": rng.integers(0, 9, 600)})
+    B = Relation("B", {"a": rng.integers(0, 600, n_b),
+                       "b": rng.integers(0, 40, n_b)})
+    C = Relation("C", {"b": rng.integers(0, 40, 300),
+                       "y": rng.integers(0, 5, 300)})
+    return Catalog(), chain_join("two_level", [A, B, C], ["a", "b"])
+
+
+def test_pallas_two_level_probe_path_matches_jnp():
+    """An index on the two-level fence search draws as jnp.searchsorted."""
+    import jax
+    cat, spec = _two_level_chain()
+    t_jnp = DeviceTreeJoin(cat, spec, use_pallas=False)
+    t_pal = DeviceTreeJoin(cat, spec, use_pallas=True)
+    assert [p.levels for p in t_pal._prepped] == [2, 1]
+    key = jax.random.PRNGKey(7)
+    r1, ok1, _ = jax.jit(lambda k: t_jnp.draw(k, 512))(key)
+    r2, ok2, _ = jax.jit(lambda k: t_pal.draw(k, 512))(key)
+    assert np.array_equal(np.asarray(ok1), np.asarray(ok2))
+    for a in spec.output_attrs:
+        assert np.array_equal(np.asarray(r1[a]), np.asarray(r2[a])), a
+
+
+def test_probe_levels_gauge():
+    """repro_engine_probe_levels: 2 for B's index, 1 for C's."""
+    from repro import obs
+    reg = obs.MetricsRegistry()
+    prev = obs.set_registry(reg)
+    try:
+        cat, spec = _two_level_chain()
+        DeviceTreeJoin(cat, spec, use_pallas=True)
+        series = reg.snapshot()["repro_engine_probe_levels"]["series"]
+    finally:
+        obs.set_registry(prev)
+    assert series == {(("join", "two_level"), ("node", "B")): 2,
+                      (("join", "two_level"), ("node", "C")): 1}
+
+
 # ---------------------------------------------------------------------------
 # membership oracle: device == host, bit for bit
 # ---------------------------------------------------------------------------

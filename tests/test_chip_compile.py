@@ -4,8 +4,9 @@ Interpret mode (every other kernel test) cannot see what the chip's compiler
 refuses: block shapes off the (8, 128) tiling, too much fast memory, a
 primitive with no Mosaic lowering.  These tests compile for a v5e that is
 described, not attached, at the widths the sampler runs at TPC-H SF1
-(6,000,000 sorted keys, 4,096 queries per probe) and at the smallest layout
-the chip takes (one fence chunk).  Nothing runs, so they check compilation
+(6,000,000 and 3,600,000 sorted keys, 4,096 queries per probe: two fence
+levels) and at the smallest layout the chip takes (one fence chunk: one
+level).  Nothing runs, so they check compilation
 only; results are checked in interpret mode by ``test_kernels.py``.
 
 The topology is described inside a fixture, never at import time: only one
@@ -22,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.searchsorted import (FENCE_CHUNK, KEY_BLOCK, QUERY_TILE,
-                                        _searchsorted_i32)
+                                        _searchsorted_i32, probe_levels)
 from repro.kernels.walk import _hop_i32
 
 
@@ -57,8 +58,11 @@ def no_compile_cache():
 
 
 def _kernel_args(sharding, n_keys: int, n_queries: int, with_u: bool):
+    """Shapes of ``_searchsorted_i32`` / ``_hop_i32``'s arguments: query
+    tiles (and uniforms), then ``PreparedKeys.arrays()``."""
     n_blocks = -(-n_keys // KEY_BLOCK)
     n_chunks = -(-n_blocks // FENCE_CHUNK)
+    n_top = -(-n_chunks // FENCE_CHUNK)
     qt = -(-n_queries // QUERY_TILE)
 
     def sds(shape, dtype=jnp.int32):
@@ -67,33 +71,40 @@ def _kernel_args(sharding, n_keys: int, n_queries: int, with_u: bool):
     tiles = [sds((qt, 1, QUERY_TILE)), sds((qt, 1, QUERY_TILE))]
     if with_u:
         tiles.append(sds((qt, 1, QUERY_TILE), jnp.float32))
-    args = tiles + [sds((n_chunks, FENCE_CHUNK)), sds((n_chunks, FENCE_CHUNK)),
-                    sds((n_blocks, KEY_BLOCK)), sds((n_blocks, KEY_BLOCK))]
-    return args, dict(n_chunks=n_chunks, n_fences=n_blocks, interpret=False)
+    args = tiles + [sds((n_top, FENCE_CHUNK))] * 2 \
+        + [sds((n_chunks, FENCE_CHUNK))] * 2 + [sds((n_blocks, KEY_BLOCK))] * 2
+    return args, probe_levels(n_chunks)
 
 
-# (keys, queries): SF1 lineitem width, and one fence chunk of keys
-WIDTHS = [(6_000_000, 4096), (FENCE_CHUNK * KEY_BLOCK, QUERY_TILE)]
+def _custom_calls(text: str) -> int:
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+# (keys, queries): SF1 lineitem width, the UQ1 variant's lineitem (two
+# fence levels) and one fence chunk of keys (one level: supplier, nation)
+WIDTHS = [(6_000_000, 4096), (3_600_000, 4096),
+          (FENCE_CHUNK * KEY_BLOCK, QUERY_TILE)]
 
 
 @pytest.mark.parametrize("n_keys,n_queries", WIDTHS)
 def test_searchsorted_compiles_for_v5e(one_chip, n_keys, n_queries):
-    args, static = _kernel_args(one_chip, n_keys, n_queries, with_u=False)
-    compiled = _searchsorted_i32.lower(*args, **static).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    args, levels = _kernel_args(one_chip, n_keys, n_queries, with_u=False)
+    text = _searchsorted_i32.lower(*args, interpret=False).compile().as_text()
+    # one fence level: the sweep and the refine, as before two levels
+    # existed; two: the top sweep, the fence-row compare and the refine
+    assert _custom_calls(text) == 1 + levels
 
 
 @pytest.mark.parametrize("n_keys,n_queries", WIDTHS)
 def test_walk_hop_compiles_for_v5e(one_chip, n_keys, n_queries):
-    args, static = _kernel_args(one_chip, n_keys, n_queries, with_u=True)
-    compiled = _hop_i32.lower(*args, **static).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    args, levels = _kernel_args(one_chip, n_keys, n_queries, with_u=True)
+    text = _hop_i32.lower(*args, interpret=False).compile().as_text()
+    assert _custom_calls(text) == 1 + levels
 
 
-def test_device_loop_with_pallas_probes_compiles_for_v5e(one_chip,
-                                                         monkeypatch):
-    """The fused Algorithm-1 loop, catalog passed as an argument, lowered
-    from shapes: the Pallas probes sit inside the compiled while loop."""
+def _compile_loop(one_chip, monkeypatch):
+    """The fused Algorithm-1 loop of a two-join UQ1 union, catalog passed
+    as an argument, lowered from shapes and compiled: (engine, HLO text)."""
     from repro.core.backends.jax_backend import JaxBackend, JaxUnionSampler
     from repro.core.framework import estimate_union, warmup
     from repro.data.workloads import uq1
@@ -116,15 +127,50 @@ def test_device_loop_with_pallas_probes_compiles_for_v5e(one_chip,
     # the catalog is an argument: no data-sized constant in the program
     consts = re.findall(r'dense<"0x([0-9A-Fa-f]+)"', lowered.as_text())
     assert max(map(len, consts), default=0) < 4096
-    text = lowered.compile().as_text()
-    assert "tpu_custom_call" in text
+    return eng, lowered.compile().as_text()
+
+
+def _check_probe_kernels(eng, text: str, levels: int):
+    """Every Pallas call is a probe's: ``1 + levels`` per tree node, each
+    named as the benchmark's trace reduction finds it and in ``walk/*``."""
+    from repro import obs
+    assert {p.levels for t in eng.trees for p in t._prepped} == {levels}
     assert " while(" in text
     # the loop's phase scopes are metadata only: the probe kernels keep the
     # names the benchmark's trace reduction finds them by, and each sits in
     # the walk phase of its piece
-    from repro import obs
     phases = obs.hlo_op_phases(text)
     kernels = re.findall(r"^\s*%([\w.-]+) = [^\n]*custom_call_target="
                          r'"tpu_custom_call"', text, re.M)
-    assert kernels and all(k.startswith("_searchsorted_i32") for k in kernels)
+    nodes = sum(len(t.node_cfgs) for t in eng.trees)
+    assert len(kernels) == (1 + levels) * nodes
+    assert all(k.startswith("_searchsorted_i32") for k in kernels)
     assert {phases[k].split("/")[0] for k in kernels} == {"walk"}
+
+
+def test_device_loop_with_pallas_probes_compiles_for_v5e(one_chip,
+                                                         monkeypatch):
+    """The Pallas probes sit inside the compiled while loop; at this size
+    every index has one fence chunk, so one level."""
+    eng, text = _compile_loop(one_chip, monkeypatch)
+    _check_probe_kernels(eng, text, levels=1)
+
+
+@pytest.fixture
+def two_levels_everywhere(monkeypatch):
+    """Every index on the two-level fence search, so that a small union
+    takes the path of SF1's orders and lineitem.  The levels are chosen
+    while tracing, so traces cached under the real threshold are dropped
+    on the way in and the ones made here on the way out."""
+    import repro.kernels.searchsorted as ss
+    monkeypatch.setattr(ss, "TWO_LEVEL_MIN_CHUNKS", 1)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def test_device_loop_with_two_level_probes_compiles_for_v5e(
+        one_chip, monkeypatch, two_levels_everywhere):
+    """The same loop with every probe searching its fences in two levels."""
+    eng, text = _compile_loop(one_chip, monkeypatch)
+    _check_probe_kernels(eng, text, levels=2)

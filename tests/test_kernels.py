@@ -7,7 +7,9 @@ from _hypothesis_compat import given, settings, st
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
-from repro.kernels.searchsorted import PreparedKeys, searchsorted_pallas
+from repro.kernels.searchsorted import (FENCE_CHUNK, KEY_BLOCK,
+                                        TWO_LEVEL_MIN_CHUNKS, PreparedKeys,
+                                        searchsorted_pallas)
 
 
 # ---------------------------------------------------------------------------
@@ -47,9 +49,66 @@ def test_searchsorted_prepared_reuse():
         assert np.array_equal(lo, lo_r) and np.array_equal(hi, hi_r)
 
 
+# one top fence (and one row of 128 fences) per SUPER keys
+SUPER = KEY_BLOCK * FENCE_CHUNK
+# (keys, fence levels): one level just under the threshold, two levels at it
+# and with at least three top fences
+FENCE_LEVEL_CASES = [
+    ((TWO_LEVEL_MIN_CHUNKS - 1) * SUPER - 5, 1),
+    ((TWO_LEVEL_MIN_CHUNKS - 1) * SUPER + 7, 2),
+    (max(3, TWO_LEVEL_MIN_CHUNKS) * SUPER + 2_000, 2),
+]
+
+
+def _keys_and_queries(n_keys: int, dom: int, seed: int, q_max: int):
+    """Sorted keys with runs of equal keys across a 128-key block and a
+    super-block boundary, and queries below, above, inside and exactly on
+    keys, fences and top fences, down to INT64_MIN and up to ``q_max``."""
+    rng = np.random.default_rng(seed)
+    keys = np.sort(rng.integers(-dom, dom, n_keys).astype(np.int64))
+    for b, half in ((KEY_BLOCK, 40), (SUPER, 300), (2 * SUPER, 3)):
+        if b + half < n_keys:
+            keys[b - half:b + half] = keys[b - half]
+    i64 = np.iinfo(np.int64)
+    qs = np.concatenate([
+        rng.integers(-2 * dom, 2 * dom, 600), keys[::KEY_BLOCK],
+        keys[::SUPER], keys[::SUPER] - 1, keys[::SUPER] + 1,
+        keys[KEY_BLOCK - 1::KEY_BLOCK],
+        [keys[0] - 1, keys[0], keys[-1], keys[-1] + 1, i64.min, q_max]])
+    return keys, qs.astype(np.int64), rng
+
+
+@pytest.mark.parametrize("dom", [2**45, 64])
+@pytest.mark.parametrize("n_keys,levels", FENCE_LEVEL_CASES)
+def test_searchsorted_fence_levels(n_keys, levels, dom):
+    """Exact on both sides of the two-level threshold, against numpy."""
+    keys, qs, _ = _keys_and_queries(n_keys, dom, n_keys,
+                                    np.iinfo(np.int64).max)
+    prep = PreparedKeys(keys)
+    assert prep.levels == levels
+    lo, hi = searchsorted_pallas(prep, qs)
+    lo_r, hi_r = ref.searchsorted_ref(keys, qs)
+    assert np.array_equal(lo, lo_r) and np.array_equal(hi, hi_r)
+
+
 # ---------------------------------------------------------------------------
 # walk hop
 # ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n_keys,levels", FENCE_LEVEL_CASES)
+def test_walk_hop_fence_levels(n_keys, levels):
+    # the hop's queries lie below INT64_MAX, its padding sentinel
+    keys, qs, rng = _keys_and_queries(n_keys, n_keys // 8, n_keys + 1,
+                                      np.iinfo(np.int64).max - 1)
+    prep = PreparedKeys(keys)
+    assert prep.levels == levels
+    u = rng.random(qs.shape[0]).astype(np.float32)
+    pos, deg = ops.walk_hop(prep, qs, u)
+    pos_r, deg_r = ref.walk_hop_ref(keys, qs, u)
+    assert np.array_equal(deg, deg_r)
+    alive = deg_r > 0
+    assert np.array_equal(pos[alive], pos_r[alive])
 
 
 @given(st.integers(0, 2**31), st.integers(1, 2000), st.integers(1, 600))
