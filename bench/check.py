@@ -9,11 +9,11 @@ served in the window and its home piece, judged by the reference alone.
   not a row of the home join, or also a row of an earlier join in cover
   order (exact: limit 0).  This judges the membership probes.
 * ``cover_bucket_chi2`` — chi-square of the rows' (home piece, bucket of
-  their row in one chain relation: row id mod ``buckets``) against the
+  their row in one relation: row id mod ``buckets``) against the
   exact counts of the union.  This judges the cover selection and the
   weighted walk down to that relation.
 * ``position_chi2`` — the same over (home piece, place of the row among
-  the rows of its range) in a deeper chain relation.  This judges the range
+  the rows of its range) in a deeper tree relation.  This judges the range
   probes of the walk's last hops: a probe that misses one end of its ranges
   leaves the row-id buckets nearly even but empties one end of this
   histogram.
@@ -29,7 +29,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from bench.reference import chain
+from bench.reference import tree
 
 
 def served_rows(reqs) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
@@ -41,7 +41,7 @@ def served_rows(reqs) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
     return rows, home
 
 
-def compare(u: chain.Union, sizes: Dict[Tuple[int, ...], int], reqs,
+def compare(u: tree.Union, sizes: Dict[Tuple[int, ...], int], reqs,
             cfg: dict) -> List[Tuple[str, float, float]]:
     """``(name, value, limit)`` for every number compared."""
     limits = cfg["limits"]
@@ -54,7 +54,7 @@ def compare(u: chain.Union, sizes: Dict[Tuple[int, ...], int], reqs,
     n = home.shape[0]
     nj = len(u.joins)
 
-    mem = chain.Membership(u)
+    mem = tree.Membership(u)
     found, ids = mem.row_ids(rows)
     m = mem.matrix(found, ids)
     h = np.clip(home, 0, nj - 1)
@@ -65,14 +65,14 @@ def compare(u: chain.Union, sizes: Dict[Tuple[int, ...], int], reqs,
     names = [r.name for r in u.rels]
     chk = cfg["check"]
     rel = names.index(chk["marginal_relation"])
-    chi2, total = _chi2(u, rel, chain.id_buckets(u, rel, int(chk["buckets"])),
+    chi2, total = _chi2(u, rel, tree.id_buckets(u, rel, int(chk["buckets"])),
                         h, ids)
     rel = names.index(chk["position_relation"])
-    pos_chi2, _ = _chi2(u, rel, chain.position_buckets(
+    pos_chi2, _ = _chi2(u, rel, tree.position_buckets(
         u, rel, int(chk["position_buckets"])), h, ids)
 
     sizes_ = [r.nrows for r in u.rels]
-    pairs = chain.colliding_pairs(chain.tuple_codes(ids, sizes_))
+    pairs = tree.colliding_pairs(tree.tuple_codes(ids, sizes_))
     expected_pairs = n * (n - 1) / 2 / total
     gap = abs(pairs / expected_pairs - 1.0) if expected_pairs > 0 else 0.0
 
@@ -84,12 +84,12 @@ def compare(u: chain.Union, sizes: Dict[Tuple[int, ...], int], reqs,
             ("collision_gap", gap, float(limits["collision_gap"]))]
 
 
-def _chi2(u: chain.Union, rel: int, bucket: np.ndarray, home: np.ndarray,
+def _chi2(u: tree.Union, rel: int, bucket: np.ndarray, home: np.ndarray,
           ids: List[np.ndarray]) -> Tuple[float, float]:
-    """(chi-square of (home piece, ``bucket`` of the row in chain relation
+    """(chi-square of (home piece, ``bucket`` of the row in relation
     ``rel``) against the exact counts, |U|)."""
     nj, nb = len(u.joins), int(bucket.max(initial=0)) + 1
-    pieces = chain.pieces_from(chain.bucket_counts(u, rel, bucket), nj)
+    pieces = tree.pieces_from(tree.bucket_counts(u, rel, bucket), nj)
     expect = np.stack(pieces).astype(np.float64)          # (nj, nb)
     total = float(expect.sum())
     obs = np.bincount(home * nb + bucket[ids[rel]],

@@ -9,7 +9,7 @@ columns to the program.
 """
 
 from bench import tpch
-from bench.reference.chain import JoinDef, Rel, Union
+from bench.reference.tree import JoinDef, Rel, Union, chain
 
 
 def build(cfg: dict) -> Union:
@@ -25,4 +25,4 @@ def build(cfg: dict) -> Union:
              for i, r in enumerate(rels)}
     joins = [JoinDef(f"UQ1_J{v}", {r.name: masks[r.name][v] for r in rels},
                      []) for v in range(n)]
-    return Union(rels, ["nk", "nk", "ck", "ok"], joins)
+    return Union(chain(rels, ["nk", "nk", "ck", "ok"]), joins)

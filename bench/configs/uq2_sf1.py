@@ -8,7 +8,7 @@ reference's description of the union (numpy columns only).
 """
 
 from bench import tpch
-from bench.reference.chain import JoinDef, Rel, Union
+from bench.reference.tree import JoinDef, Rel, Union, chain
 
 
 def build(cfg: dict) -> Union:
@@ -19,4 +19,4 @@ def build(cfg: dict) -> Union:
     rels = [Rel(name, db[name], keys[name]) for name in keys]
     joins = [JoinDef(name, {}, [tuple(p) for p in preds])
              for name, preds in cfg["selections"].items()]
-    return Union(rels, ["rk", "nk", "sk", "pk"], joins)
+    return Union(chain(rels, ["rk", "nk", "sk", "pk"]), joins)

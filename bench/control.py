@@ -39,7 +39,7 @@ if ROOT not in sys.path:
 
 from bench import check, load  # noqa: E402
 from bench.harness import load_config  # noqa: E402
-from bench.reference import chain  # noqa: E402
+from bench.reference import tree  # noqa: E402
 
 
 @dataclasses.dataclass
@@ -64,11 +64,11 @@ def as_requests(rows, home, size: int):
 def readings(cfg: dict, u, dtype, samples: int, seeds,
              draws_per_sample=None, drop_last: bool = False):
     """(seed, numbers compared, samples produced, seconds) per seed."""
-    sizes = chain.intersection_sizes(u)
-    pieces = chain.pieces_from(sizes, len(u.joins))
+    sizes = tree.intersection_sizes(u)
+    pieces = tree.pieces_from(sizes, len(u.joins))
     at = ([r.name for r in u.rels].index(cfg["check"]["position_relation"])
           if drop_last else None)
-    sampler = chain.UnionSampler(u, pieces, dtype, drop_last=at)
+    sampler = tree.UnionSampler(u, pieces, dtype, drop_last=at)
     for seed in seeds:
         t0 = time.perf_counter()
         rows, home = sampler.sample(samples, np.random.default_rng(seed),

@@ -6,6 +6,18 @@ traffic mix (``bench/traffic/<traffic>.json``) and every metric but
 ``setup_s`` (``bench/metrics/<metric>.py``, whose ``read(ctx)`` returns a
 number, or ``None`` where a per-layer metric finds nothing to read).  Adding a cell, a mix or a metric adds files and entries in
 ``BENCHMARK.json``; nothing here changes.
+
+A configuration's ``.py`` provides ``build(cfg)``, which returns the
+union as ``bench.reference.tree.Union``: the relations as a join tree
+(``parent`` and ``edge``, one attribute or several; ``tree.chain`` writes
+a chain in one line), residual relations that close cycles, and the joins
+as row masks and pushed-down selections over them.  The reference counts
+and judges that union; the program is given the same columns as join specs
+built from it (``bench.system.program_joins``).  Where the deployment's
+users submit another layout of the same joins, such as a §5.2 vertical
+split, the ``.py`` also provides ``program_joins(u)``, which returns the
+program's ``JoinSpec`` of every join under the reference's join names, in
+cover order.
 """
 
 from __future__ import annotations
@@ -23,7 +35,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from bench import check, kernels, load, trace_reduce
-from bench.reference import chain
+from bench.reference import tree
 
 TRACE_SPAN = "bench/window"            # host span around the traced window
 REQUEST_SPAN = "bench/request"         # host span around every request
@@ -162,9 +174,10 @@ def run_cell(root: str, bench: dict, cell: dict, seed: int, seconds: float,
                                      cell["traffic"] + ".json"))
     u = mod.build(cfg)
     since = fallbacks()
-    sizes = chain.intersection_sizes(u)
-    pieces = chain.pieces_from(sizes, len(u.joins))
-    sampler = build_sampler(u, sizes, program_seed(seed), cfg["round_batch"])
+    sizes = tree.intersection_sizes(u)
+    pieces = tree.pieces_from(sizes, len(u.joins))
+    sampler = build_sampler(u, sizes, program_seed(seed), cfg["round_batch"],
+                            config=mod)
     got = [sampler.cover.piece_sizes[j.name] for j in u.joins]
     if got != [float(p) for p in pieces]:
         raise EngineFault(f"program cover {got} != exact pieces {pieces}")

@@ -11,27 +11,34 @@ from __future__ import annotations
 
 from typing import Dict, Tuple
 
-from bench.reference.chain import Union
+from bench.reference.tree import Union
 
 
 def program_joins(u: Union) -> list:
-    """The program's ``JoinSpec`` of every join, in cover order: variant
-    relations as filtered copies, selections through ``pushdown``."""
-    from repro.core.joins import chain_join
+    """The program's ``JoinSpec`` of every join, in cover order: the
+    reference's relations as ``JoinNode``s on their parents and edges
+    (residual relations as residual nodes), variant relations as filtered
+    copies, selections through ``pushdown``."""
+    from repro.core.joins import JoinNode, JoinSpec
     from repro.core.predicates import Pred, pushdown
     from repro.core.relation import Relation
 
+    def tree_join(name, rels):
+        return JoinSpec(name, [
+            JoinNode(rels[r.name].name, rels[r.name],
+                     None if r.parent is None else rels[r.parent].name,
+                     r.edge, r.kind) for r in u.rels])
+
     base = {r.name: Relation(r.name, r.cols) for r in u.rels}
-    edges = [(e,) for e in u.edges]
-    shared = chain_join("base", [base[r.name] for r in u.rels], edges)
+    shared = tree_join("base", base)
     specs = []
     for jd in u.joins:
         if jd.variants:
-            rels = [base[r.name].filter(jd.variants[r.name],
-                                        name=f"{r.name}@{jd.name}")
+            rels = {r.name: base[r.name].filter(jd.variants[r.name],
+                                                name=f"{r.name}@{jd.name}")
                     if r.name in jd.variants else base[r.name]
-                    for r in u.rels]
-            spec = chain_join(jd.name, rels, edges)
+                    for r in u.rels}
+            spec = tree_join(jd.name, rels)
         else:
             spec = shared
         if jd.preds:
@@ -41,15 +48,30 @@ def program_joins(u: Union) -> list:
     return specs
 
 
+def joins_for(u: Union, config=None) -> list:
+    """The join specs a deployment submits: the configuration module's own
+    ``program_joins(u)`` where it states a layout of its own (a §5.2 split
+    of the reference's relations), else :func:`program_joins`.  Either way
+    the joins carry the reference's names, in cover order."""
+    own = getattr(config, "program_joins", None)
+    specs = own(u) if own is not None else program_joins(u)
+    names = [s.name for s in specs]
+    if names != [j.name for j in u.joins]:
+        raise ValueError(f"program joins {names} are not the reference's "
+                         f"{[j.name for j in u.joins]}")
+    return specs
+
+
 def build_sampler(u: Union, sizes: Dict[Tuple[int, ...], int], seed: int,
-                  round_batch: int):
-    """``SetUnionSampler(backend="jax")`` over ``u`` with the exact cover."""
+                  round_batch: int, config=None):
+    """``SetUnionSampler(backend="jax")`` over ``u`` with the exact cover;
+    ``config`` is the configuration module (see :func:`joins_for`)."""
     from repro.core.cover import build_cover
     from repro.core.index import Catalog
     from repro.core.koverlap import OverlapOracle
     from repro.core.union_sampler import SetUnionSampler
 
-    specs = program_joins(u)
+    specs = joins_for(u, config)
     index = {s.name: i for i, s in enumerate(specs)}
 
     def subset(joins):
