@@ -497,42 +497,44 @@ class DeviceTreeJoin:
 
     # -- one batch of EW tree draws (traced; jit at the call site) ------------
     # analysis: traced
-    def draw(self, key: jax.Array, batch: int, arrays=None
-             ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray]:
+    def draw(self, key: jax.Array, batch: int, arrays=None,
+             skeleton: bool = False):
         """``arrays`` is :meth:`device_arrays` (or its traced twin);
-        ``None`` reads this tree's own arrays."""
+        ``None`` reads this tree's own arrays.  ``skeleton``: see
+        :meth:`draw_with_root`."""
         if arrays is None:
             arrays = self.device_arrays()
         return self.draw_with_root(key, batch, arrays["root_wprefix"],
                                    arrays["root_cols"], self.n_root,
-                                   arrays["nodes"])
+                                   arrays["nodes"], skeleton=skeleton)
 
     # analysis: traced
     def _residual_step(self, i: int, cfg: _NodeCfg, node, rows, ok,
                        acc_ratio, u):
         """One residual edge: sorted-key probe, uniform pick, d/M factor.
-        ``node`` is node ``i`` of :meth:`device_arrays`."""
-        q = _pack_jnp(rows, cfg.edge_attrs, cfg.radices)
-        lo, hi = self._ranges(i, node["probe"], q)
-        d = hi - lo
-        off = jnp.floor(u * jnp.maximum(d, 1).astype(jnp.float32)
-                        ).astype(jnp.int32)
-        pos = lo + jnp.minimum(off, jnp.maximum(d - 1, 0))
-        ok = ok & (d > 0)
-        acc_ratio = acc_ratio * (d.astype(jnp.float32)
-                                 / jnp.float32(max(cfg.max_degree, 1)))
-        perm = node["perm"]
-        child = perm[jnp.clip(pos, 0, perm.shape[0] - 1)]
-        for a, c in node["cols"].items():
-            rows[a] = c[child]
+        ``node`` is node ``i`` of :meth:`device_arrays`.  Its ops sit in
+        the ``residual/<join>`` phase (``repro.obs.LOOP_PHASES``)."""
+        with jax.named_scope(f"residual/{self.name}"):
+            q = _pack_jnp(rows, cfg.edge_attrs, cfg.radices)
+            lo, hi = self._ranges(i, node["probe"], q)
+            d = hi - lo
+            off = jnp.floor(u * jnp.maximum(d, 1).astype(jnp.float32)
+                            ).astype(jnp.int32)
+            pos = lo + jnp.minimum(off, jnp.maximum(d - 1, 0))
+            ok = ok & (d > 0)
+            acc_ratio = acc_ratio * (d.astype(jnp.float32)
+                                     / jnp.float32(max(cfg.max_degree, 1)))
+            perm = node["perm"]
+            child = perm[jnp.clip(pos, 0, perm.shape[0] - 1)]
+            for a, c in node["cols"].items():
+                rows[a] = c[child]
         return rows, ok, acc_ratio
 
     # analysis: traced
     def draw_with_root(self, key: jax.Array, batch: int,
                        root_wprefix: jnp.ndarray,
-                       root_cols: Dict[str, jnp.ndarray], n_root, nodes
-                       ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray,
-                                  jnp.ndarray]:
+                       root_cols: Dict[str, jnp.ndarray], n_root, nodes,
+                       skeleton: bool = False):
         """Tree draw with a caller-supplied root slice.
 
         The sharding layer passes each shard's local root range (weight
@@ -546,7 +548,11 @@ class DeviceTreeJoin:
         every edge (tree and residual) had a match; ``accept`` additionally
         applies the §8.2 residual ``Π d/M`` acceptance test, so
         ``walk_ok & ~accept`` are exactly the residual rejections.  On
-        acyclic joins the two are the same array.
+        acyclic joins the two are the same array.  ``skeleton=True`` adds
+        a fourth element, ``skel_ok``: the walks whose tree edges all
+        matched, so ``skel_ok & ~accept`` are the walks turned away at a
+        residual edge, by a probe with no match (d = 0) or by the test
+        (``None`` on acyclic joins, which turn no walk away there).
         """
         keys = jax.random.split(key, len(self.node_cfgs) + 1
                                 + (1 if self.has_residual else 0))
@@ -556,11 +562,12 @@ class DeviceTreeJoin:
             jnp.full((batch,), n_root, jnp.int32), u0)
         rows = {a: c[r_pos] for a, c in root_cols.items()}
         acc_ratio = jnp.ones((batch,), jnp.float32)
+        res_ok = jnp.ones((batch,), bool)       # every residual edge matched
         for i, (cfg, node) in enumerate(zip(self.node_cfgs, nodes)):
             u = jax.random.uniform(keys[i + 1], (batch,))
             if cfg.kind == "residual":
-                rows, ok, acc_ratio = self._residual_step(
-                    i, cfg, node, rows, ok, acc_ratio, u)
+                rows, res_ok, acc_ratio = self._residual_step(
+                    i, cfg, node, rows, res_ok, acc_ratio, u)
                 continue
             q = _pack_jnp(rows, cfg.edge_attrs, cfg.radices)
             lo, hi = self._ranges(i, node["probe"], q)
@@ -578,9 +585,13 @@ class DeviceTreeJoin:
             for a, c in node["cols"].items():
                 rows[a] = c[child]
         if not self.has_residual:
-            return rows, ok, ok
+            return (rows, ok, ok, None) if skeleton else (rows, ok, ok)
         u_acc = jax.random.uniform(keys[-1], (batch,))
-        return rows, ok & (u_acc < acc_ratio), ok
+        with jax.named_scope(f"residual/{self.name}"):
+            walk_ok = ok & res_ok
+            accept = walk_ok & (u_acc < acc_ratio)
+        return ((rows, accept, walk_ok, ok) if skeleton
+                else (rows, accept, walk_ok))
 
 
 # ---------------------------------------------------------------------------
@@ -905,14 +916,17 @@ class JaxBackend(Backend):
 _STAT_FIELDS = ("iterations", "candidate_draws", "cover_rejects",
                 "residual_rejects", "pred_rejects", "dropped_slots")
 
-# Per-piece round counters carried as one (nj, 5) int32 matrix in the
+# Per-piece round counters carried as one (nj, 6) int32 matrix in the
 # persistent loop (device mode) / accumulated by the numpy twin (host mode)
 # and surfaced at the same single host sync as the scalar stats vector.
-# Columns: candidate draws, cover-accepted rows, §8.2 residual rejections,
-# rows drained from the surplus bank, and the post-round bank-occupancy
-# high-water mark (max over the call; folded with max across calls).
+# Columns: candidate draws, cover-accepted rows, §8.2 residual rejections
+# (the Π d/M test), §8.2 residual kills (skeleton walks turned away at a
+# residual edge: a probe with no match, or the Π d/M test; 0 on acyclic
+# pieces), rows drained from the surplus bank, and the post-round
+# bank-occupancy high-water mark (max over the call; folded with max
+# across calls).  Every column but the last sums.
 PIECE_STAT_FIELDS = ("draws", "accepts", "residual_rejects",
-                     "bank_drained", "bank_hwm")
+                     "residual_kills", "bank_drained", "bank_hwm")
 
 
 def _cover_cum(probs_base: jnp.ndarray, dead: jnp.ndarray):
@@ -1301,10 +1315,10 @@ class JaxUnionSampler:
         wrapper and the device loop body).  ``cat`` is
         :meth:`_catalog_args` as traced by the caller.  Returns per join the
         accepted-compacted candidate columns plus (ok, residual, accepted,
-        predicate-reject) counts and the per-piece need = carry + this
-        round's targets.  Under ``plan="adaptive"`` the acceptance EMAs and
-        current bank occupancy come in too and the per-piece candidate
-        budget goes out as a seventh element."""
+        predicate-reject, residual-kill) counts and the per-piece need =
+        carry + this round's targets.  Under ``plan="adaptive"`` the
+        acceptance EMAs and current bank occupancy come in too and the
+        per-piece candidate budget goes out as an eighth element."""
         with jax.named_scope("algo1_fused_round"):
             return self._round_core_impl(key, probs_cum, carry_need,
                                          extra_target, cat, ema, bank_count)
@@ -1346,12 +1360,13 @@ class JaxUnionSampler:
         # (2)+(3) per join: batched candidate draw (incl. §8.2 residual-edge
         # verification for cyclic pieces) + fused §8.3 predicate acceptance
         # + earlier-piece rejection
-        cols, okc, resc, accc, predc = [], [], [], [], []
+        cols, okc, resc, accc, predc, killc = [], [], [], [], [], []
         for j, tree in enumerate(self.trees):
             bj = self.piece_batches[j]
             name = self.order[j]
             with jax.named_scope(f"walk/{name}"):
-                rows, acc, walk_ok = tree.draw(jks[j], bj, cat["trees"][j])
+                rows, acc, walk_ok, skel_ok = tree.draw(
+                    jks[j], bj, cat["trees"][j], skeleton=True)
             with jax.named_scope(f"filter/{name}"):
                 if budget is not None:
                     # budget mask: the first budget[j] slots of an i.i.d.
@@ -1360,7 +1375,11 @@ class JaxUnionSampler:
                     elig = jnp.arange(bj) < budget[j]
                     acc = acc & elig
                     walk_ok = walk_ok & elig
+                    if skel_ok is not None:
+                        skel_ok = skel_ok & elig
                 resc.append(jnp.sum(walk_ok) - jnp.sum(acc))
+                killc.append(jnp.int32(0) if skel_ok is None
+                             else jnp.sum(skel_ok) - jnp.sum(acc))
                 pf = self._pred_fns[j]
                 if pf is None:
                     predc.append(jnp.int32(0))
@@ -1388,7 +1407,8 @@ class JaxUnionSampler:
             out = (cols, jnp.stack(okc).astype(jnp.int32),
                    jnp.stack(resc).astype(jnp.int32),
                    jnp.stack(accc).astype(jnp.int32),
-                   jnp.stack(predc).astype(jnp.int32), need)
+                   jnp.stack(predc).astype(jnp.int32),
+                   jnp.stack(killc).astype(jnp.int32), need)
             if adaptive:
                 out = out + (budget.astype(jnp.int32),)
         return out
@@ -1454,14 +1474,15 @@ class JaxUnionSampler:
                     extra = jnp.clip(n - total - jnp.sum(state["owed"]),
                                      0, self._slot_width)
                 if adaptive:
-                    cols, okc, resc, accc, predc, need, budget = \
+                    cols, okc, resc, accc, predc, killc, need, budget = \
                         self._round_core(kround, probs_cum, state["owed"],
                                          extra, cat, state["ema"],
                                          state["bank_count"])
                 else:
                     budget = None
-                    cols, okc, resc, accc, predc, need = self._round_core(
-                        kround, probs_cum, state["owed"], extra, cat)
+                    cols, okc, resc, accc, predc, killc, need = \
+                        self._round_core(kround, probs_cum, state["owed"],
+                                         extra, cat)
                 # bank take (FIFO, capped) → fresh take → carried shortfall
                 with jax.named_scope("emit"):
                     dt = jnp.minimum(jnp.minimum(need, state["bank_count"]),
@@ -1506,8 +1527,9 @@ class JaxUnionSampler:
                         [pstats[:, 0] + (budget if adaptive else pbatch),
                          pstats[:, 1] + accc,
                          pstats[:, 2] + resc,
-                         pstats[:, 3] + dt.astype(jnp.int32),
-                         jnp.maximum(pstats[:, 4],
+                         pstats[:, 3] + killc,
+                         pstats[:, 4] + dt.astype(jnp.int32),
+                         jnp.maximum(pstats[:, 5],
                                      count2.astype(jnp.int32))],
                         axis=1)
                     state2 = {"key": key,
@@ -1635,6 +1657,10 @@ class JaxUnionSampler:
                             "cover-accepted candidates per piece", ("join",)),
                 reg.counter("repro_engine_piece_residual_rejects_total",
                             "§8.2 residual rejections per piece", ("join",)),
+                reg.counter("repro_engine_piece_residual_kills_total",
+                            "skeleton walks turned away at a §8.2 residual "
+                            "edge (no match, or the d/M test) per piece",
+                            ("join",)),
                 reg.counter("repro_engine_piece_bank_drained_total",
                             "rows served from the surplus bank", ("join",)),
             ]
@@ -1675,8 +1701,9 @@ class JaxUnionSampler:
         """Fold one call's per-piece counter matrix into the cumulative
         engine state (+ registry publication unless REPRO_OBS=off)."""
         p = np.asarray(p, np.int64)
-        self.piece_stats[:, :4] += p[:, :4]
-        self.piece_stats[:, 4] = np.maximum(self.piece_stats[:, 4], p[:, 4])
+        self.piece_stats[:, :-1] += p[:, :-1]
+        self.piece_stats[:, -1] = np.maximum(self.piece_stats[:, -1],
+                                             p[:, -1])
         self.stats.samples_emitted += int(samples)
         if not obs.enabled():
             return
@@ -1687,7 +1714,7 @@ class JaxUnionSampler:
                 v = int(p[j, i])
                 if v:
                     child.inc(v)
-            h["hwm"].labels(join=name).set(int(self.piece_stats[j, 4]))
+            h["hwm"].labels(join=name).set(int(self.piece_stats[j, -1]))
             if ema is not None:
                 for i, comp in enumerate(planner.EMA_COMPONENTS):
                     h["ema"].labels(join=name, component=comp).set(
@@ -1734,7 +1761,7 @@ class JaxUnionSampler:
                                self._slot_width))
             self.key, sub = jax.random.split(self.key)
             if adaptive:
-                cols, okc, resc, accc, predc, need, budget, bad = \
+                cols, okc, resc, accc, predc, killc, need, budget, bad = \
                     self._round_jit(
                         self._probs_base, jnp.asarray(dead),
                         jnp.asarray(owed.astype(np.int32)),
@@ -1744,7 +1771,8 @@ class JaxUnionSampler:
                 budget = np.asarray(budget)
             else:
                 budget = None
-                cols, okc, resc, accc, predc, need, bad = self._round_jit(
+                (cols, okc, resc, accc, predc, killc, need,
+                 bad) = self._round_jit(
                     self._probs_base, jnp.asarray(dead),
                     jnp.asarray(owed.astype(np.int32)), jnp.int32(extra),
                     sub, cat=self._catalog_args())
@@ -1754,12 +1782,15 @@ class JaxUnionSampler:
             resc = np.asarray(resc).astype(np.int64)
             accc = np.asarray(accc).astype(np.int64)
             predc = np.asarray(predc).astype(np.int64)
+            killc = np.asarray(killc).astype(np.int64)
             need = np.asarray(need).astype(np.int64)
             drawn = bt if budget is None else int(budget.sum())
             self.stats.iterations += drawn
             self.stats.candidate_draws += drawn
             # residual (§8.2), predicate (§8.3) and membership rejections are
-            # accounted separately (dead walks are none of the three)
+            # accounted separately (dead walks are none of the three; the
+            # per-piece residual_kills column counts those that die at a
+            # residual edge)
             self.stats.residual_rejects += int(resc.sum())
             self.stats.pred_rejects += int(predc.sum())
             self.stats.cover_rejects += int(okc.sum() - resc.sum()
@@ -1791,8 +1822,9 @@ class JaxUnionSampler:
                 np.int64)
             pstats[:, 1] += accc
             pstats[:, 2] += resc
-            pstats[:, 3] += dt
-            pstats[:, 4] = np.maximum(pstats[:, 4], count)
+            pstats[:, 3] += killc
+            pstats[:, 4] += dt
+            pstats[:, 5] = np.maximum(pstats[:, 5], count)
             if adaptive:
                 # numpy EMA step — planner.ema_update with xp=np runs the
                 # same int32 adds/shifts/divides as the device carry
@@ -1938,10 +1970,14 @@ class JaxRecordUnionSampler(JaxUnionSampler):
         keys = jax.random.split(key, nj)
         cols_out, debug = [], []
         ft_l, okc_l, resc_l, predc_l, rejc_l = [], [], [], [], []
-        accc_l, revc_l, inval_l = [], [], []
+        accc_l, revc_l, inval_l, killc_l = [], [], [], []
         for j, tree in enumerate(self.trees):
             bj = self.piece_batches[j]
-            rows, acc, walk_ok = tree.draw(keys[j], bj)
+            rows, acc, walk_ok, skel_ok = tree.draw(keys[j], bj,
+                                                    skeleton=True)
+            killc_l.append(jnp.int32(0) if skel_ok is None
+                           else (jnp.sum(skel_ok) - jnp.sum(acc))
+                           .astype(jnp.int32))
             okc_l.append(jnp.sum(walk_ok).astype(jnp.int32))
             resc_l.append((jnp.sum(walk_ok) - jnp.sum(acc))
                           .astype(jnp.int32))
@@ -2026,7 +2062,8 @@ class JaxRecordUnionSampler(JaxUnionSampler):
             }
         out = (state, cols_out, jnp.stack(ft_l), jnp.stack(okc_l),
                jnp.stack(resc_l), jnp.stack(predc_l), jnp.stack(rejc_l),
-               jnp.stack(accc_l), jnp.stack(revc_l), jnp.stack(inval_l))
+               jnp.stack(accc_l), jnp.stack(revc_l), jnp.stack(inval_l),
+               jnp.stack(killc_l))
         if self.debug_capture:
             return out + (debug,)
         return out
@@ -2080,13 +2117,13 @@ class JaxRecordUnionSampler(JaxUnionSampler):
             res = self._rec_jit(self._rec_state,
                                 jnp.asarray(need.astype(np.int32)), sub)
             (self._rec_state, cols, ft, okc, resc, predc, rejc, accc,
-             revc, inval) = res[:10]
+             revc, inval, killc) = res[:11]
             if self.debug_capture:
                 self.captured.append({
                     "need": need.copy(),
                     "pieces": [({a: np.asarray(c) for a, c in rows.items()},
                                 np.asarray(acc))
-                               for rows, acc in res[10]],
+                               for rows, acc in res[11]],
                 })
             ft = np.asarray(ft).astype(np.int64)
             okc = np.asarray(okc).astype(np.int64)
@@ -2113,7 +2150,8 @@ class JaxRecordUnionSampler(JaxUnionSampler):
             pstats[:, 0] += pbatch
             pstats[:, 1] += accc
             pstats[:, 2] += resc
-            # no surplus banking in record mode: columns 3/4 stay zero
+            pstats[:, 3] += np.asarray(killc).astype(np.int64)
+            # no surplus banking in record mode: the bank columns stay zero
             shortfall = need - ft
             self.stats.dropped_slots += int(shortfall[dead].sum())
             shortfall[dead] = 0

@@ -168,12 +168,13 @@ class ShardedUnionSampler(JaxUnionSampler):
         """One round on one shard: replicated picks, local draws, the
         fingerprint exchange, local acceptance + matrix compaction.
 
-        Returns ``(mats, okc, resc, accc, predc, need)`` where ``mats[j]``
-        is this shard's accepted-compacted ``(B_j, A+1)`` row matrix and the
-        count vectors are per-shard; ``need`` is the replicated global
-        target.  Under ``plan="adaptive"`` the replicated EMAs and global
-        bank occupancy come in, the replicated **global** budget goes out as
-        a seventh element, and each shard draws its near-equal split of it.
+        Returns ``(mats, okc, resc, accc, predc, killc, need)`` where
+        ``mats[j]`` is this shard's accepted-compacted ``(B_j, A+1)`` row
+        matrix and the count vectors are per-shard; ``need`` is the
+        replicated global target.  Under ``plan="adaptive"`` the replicated
+        EMAs and global bank occupancy come in, the replicated **global**
+        budget goes out as an eighth element, and each shard draws its
+        near-equal split of it.
         """
         nj = len(self.order)
         world = self.world
@@ -202,19 +203,23 @@ class ShardedUnionSampler(JaxUnionSampler):
         # (2) local i.i.d. whole-join draws (replicated roots, per-shard
         # fold-in keys; §8.2 residual edges verify locally — their sorted
         # indexes are replicated non-root node state)
-        rows_j, ok_j, wok_j = [], [], []
+        rows_j, ok_j, wok_j, killc = [], [], [], []
         for j in range(nj):
             rst = st["roots"][j]
             prefix = rst["prefix"][0]
             cols = {a: c[0] for a, c in rst["cols"].items()}
             kd = (jks[j] if world == 1          # bit-for-bit unsharded
                   else jax.random.fold_in(jks[j], sid))
-            rows, ok, wok = self._dtrees[j].draw_with_root(
-                kd, bs[j], prefix, cols, rst["n_root"][0], st["trees"][j])
+            rows, ok, wok, skel = self._dtrees[j].draw_with_root(
+                kd, bs[j], prefix, cols, rst["n_root"][0], st["trees"][j],
+                skeleton=True)
             if bshard is not None:
                 elig = jnp.arange(bs[j]) < bshard[j]
                 ok = ok & elig
                 wok = wok & elig
+                skel = None if skel is None else skel & elig
+            killc.append(jnp.int32(0) if skel is None
+                         else jnp.sum(skel) - jnp.sum(ok))
             rows_j.append(rows)
             ok_j.append(ok)
             wok_j.append(wok)
@@ -260,7 +265,8 @@ class ShardedUnionSampler(JaxUnionSampler):
         out = (mats, jnp.stack(okc).astype(jnp.int32),
                jnp.stack(resc).astype(jnp.int32),
                jnp.stack(accc).astype(jnp.int32),
-               jnp.stack(predc).astype(jnp.int32), need)
+               jnp.stack(predc).astype(jnp.int32),
+               jnp.stack(killc).astype(jnp.int32), need)
         if adaptive:
             out = out + (gbudget.astype(jnp.int32),)
         return out
@@ -337,13 +343,13 @@ class ShardedUnionSampler(JaxUnionSampler):
                          st, ema, gcount):
                 sid = jax.lax.axis_index(axis)
                 probs_cum, bad = _cover_cum(probs_base, dead)
-                mats, okc, resc, accc, predc, need, gb = \
+                mats, okc, resc, accc, predc, killc, need, gb = \
                     self._shard_round_core(key, probs_cum, carry_need,
                                            extra_target, st, sid, ema,
                                            gcount)
                 return ([m[None] for m in mats], okc[None], resc[None],
-                        accc[None], predc[None], need[None], gb[None],
-                        bad[None])
+                        accc[None], predc[None], killc[None], need[None],
+                        gb[None], bad[None])
 
             in_specs = (P(), P(), P(), P(), P(), self._state_spec, P(), P())
         else:
@@ -351,10 +357,12 @@ class ShardedUnionSampler(JaxUnionSampler):
                          st):
                 sid = jax.lax.axis_index(axis)
                 probs_cum, bad = _cover_cum(probs_base, dead)
-                mats, okc, resc, accc, predc, need = self._shard_round_core(
-                    key, probs_cum, carry_need, extra_target, st, sid)
+                mats, okc, resc, accc, predc, killc, need = \
+                    self._shard_round_core(key, probs_cum, carry_need,
+                                           extra_target, st, sid)
                 return ([m[None] for m in mats], okc[None], resc[None],
-                        accc[None], predc[None], need[None], bad[None])
+                        accc[None], predc[None], killc[None], need[None],
+                        bad[None])
 
             in_specs = (P(), P(), P(), P(), P(), self._state_spec)
 
@@ -375,14 +383,15 @@ class ShardedUnionSampler(JaxUnionSampler):
         """
         budget = None
         if self.plan == "adaptive":
-            (mats, okc, resc, accc, predc, need, budget,
+            (mats, okc, resc, accc, predc, killc, need, budget,
              bad) = self._round_prog(
                 probs_base, dead, carry_need, extra_target, key,
                 cat, ema, bank_count)
             budget = np.asarray(budget)[0]
         else:
-            mats, okc, resc, accc, predc, need, bad = self._round_prog(
-                probs_base, dead, carry_need, extra_target, key, cat)
+            (mats, okc, resc, accc, predc, killc, need,
+             bad) = self._round_prog(probs_base, dead, carry_need,
+                                     extra_target, key, cat)
         okc = np.asarray(okc)
         resc = np.asarray(resc)
         accc = np.asarray(accc)                     # (world, nj)
@@ -402,7 +411,8 @@ class ShardedUnionSampler(JaxUnionSampler):
                 pos += a
             cols.append(g)
         out = (cols, okc.sum(axis=0), resc.sum(axis=0), accc.sum(axis=0),
-               predc.sum(axis=0), np.asarray(need)[0])
+               predc.sum(axis=0), np.asarray(killc).sum(axis=0),
+               np.asarray(need)[0])
         if budget is not None:
             out = out + (budget,)
         return out + (bool(np.asarray(bad)[0]),)
@@ -466,21 +476,23 @@ class ShardedUnionSampler(JaxUnionSampler):
                                  0, self._slot_width)
                 if adaptive:
                     ema, gcount = c[13], c[14]
-                    (mats, okc_s, resc_s, accc_s, predc_s, need,
+                    (mats, okc_s, resc_s, accc_s, predc_s, killc_s, need,
                      gb) = self._shard_round_core(
                         kround, probs_cum, owed, extra, st, sid, ema,
                         gcount)
                 else:
                     gb = None
-                    (mats, okc_s, resc_s, accc_s, predc_s,
+                    (mats, okc_s, resc_s, accc_s, predc_s, killc_s,
                      need) = self._shard_round_core(
                         kround, probs_cum, owed, extra, st, sid)
                 # one tiny exchange: per-shard (bank count, accepted, ok,
-                # residual, predicate-reject) matrices — every shard then
-                # computes the same global water-filling allocation AND its
-                # own rows' global output offsets with no further collectives
+                # residual, predicate-reject, residual-kill) matrices — every
+                # shard then computes the same global water-filling
+                # allocation AND its own rows' global output offsets with no
+                # further collectives
                 gat = jax.lax.all_gather(
-                    jnp.stack([count, accc_s, okc_s, resc_s, predc_s]), axis)
+                    jnp.stack([count, accc_s, okc_s, resc_s, predc_s,
+                               killc_s]), axis)
                 counts_w, acc_w = gat[:, 0], gat[:, 1]     # (world, nj)
                 okg = jnp.sum(gat[:, 2])
                 resg = jnp.sum(gat[:, 3])
@@ -534,8 +546,10 @@ class ShardedUnionSampler(JaxUnionSampler):
                      pstats[:, 1] + accg_v.astype(jnp.int32),
                      pstats[:, 2] + jnp.sum(gat[:, 3], axis=0)
                                        .astype(jnp.int32),
-                     pstats[:, 3] + dtg.astype(jnp.int32),
-                     jnp.maximum(pstats[:, 4], countg2.astype(jnp.int32))],
+                     pstats[:, 3] + jnp.sum(gat[:, 5], axis=0)
+                                       .astype(jnp.int32),
+                     pstats[:, 4] + dtg.astype(jnp.int32),
+                     jnp.maximum(pstats[:, 5], countg2.astype(jnp.int32))],
                     axis=1)
                 nxt = (key2, shortfall.astype(jnp.int32), dead | newly,
                        streak2.astype(jnp.int32), bank2,

@@ -100,10 +100,14 @@ SPANS = (
 
 #: Phases of one device-loop round, as ``jax.named_scope``s in the loop body.
 #: The per-piece phases carry the piece's join name as a second component
-#: (``walk/<join>``); the others stand alone.
-PIECE_PHASES = ("walk", "filter", "member", "compact")
+#: (``walk/<join>``); the others stand alone.  ``residual/<join>`` (a cyclic
+#: piece's §8.2 residual probes and ``Π d/M`` test) opens inside
+#: ``walk/<join>`` and takes its ops from it.
+PIECE_PHASES = ("walk", "residual", "filter", "member", "compact")
 LOOP_PHASES = ("select",) + PIECE_PHASES + ("emit", "carry")
 UNSCOPED = "unscoped"
+# a phase that refines the phase of the scope around it
+_SUB_PHASES = {"residual": "walk"}
 
 
 class _NoSpan:
@@ -138,15 +142,23 @@ def phase_of(op_name: str) -> str:
     """The loop phase of an HLO op from its ``op_name`` metadata (the JAX
     name stack, e.g. ``jit(loop_fn)/while/body/algo1_fused_round/walk/J1/
     gather``): ``"walk/J1"``, ``"emit"``, ..., or :data:`UNSCOPED`.  The
-    outermost phase scope wins; a phase is never the last component (that
+    outermost phase scope wins, except that ``residual/<join>`` inside
+    ``walk/<join>`` refines it; a phase is never the last component (that
     is the primitive)."""
     parts = op_name.split("/")
+    phase = None
     for i, part in enumerate(parts[:-1]):
         if part in PIECE_PHASES and i + 2 < len(parts):
-            return f"{part}/{parts[i + 1]}"
-        if part in LOOP_PHASES and part not in PIECE_PHASES:
-            return part
-    return UNSCOPED
+            here = f"{part}/{parts[i + 1]}"
+        elif part in LOOP_PHASES and part not in PIECE_PHASES:
+            here = part
+        else:
+            continue
+        if phase is None:
+            phase = here
+        elif phase.split("/")[0] == _SUB_PHASES.get(part):
+            return here
+    return UNSCOPED if phase is None else phase
 
 
 _HLO_OP = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+) = (.*)$')

@@ -187,6 +187,89 @@ def test_residual_reject_stats_populated_on_both_engines():
 
 
 # ---------------------------------------------------------------------------
+# residual kills: skeleton walks turned away at a residual edge, per piece
+# ---------------------------------------------------------------------------
+
+
+def _unique_residual_spec(seed=0, n_q=60):
+    """The cycle of :func:`_cyclic_spec` with Q unique on ``(a, c)``: M = 1,
+    so a walk the residual turns away is a probe with no match (d = 0),
+    never a d/M rejection."""
+    R, S, _ = tiny_db(seed)
+    pairs = np.random.default_rng(seed + 1).choice(144, size=n_q,
+                                                   replace=False)
+    Q = Relation("Q", {"a": pairs // 12, "c": pairs % 12,
+                       "qid": np.arange(n_q)})
+    spec = JoinSpec("CYC1", [
+        JoinNode("R", R, None, ()),
+        JoinNode("S", S, "R", ("b",)),
+        JoinNode("Q", Q, None, ("a", "c"), kind="residual"),
+    ])
+    return Catalog(), spec
+
+
+def _piece_stats(cat, spec, mode, n=2000):
+    """One-piece engine (no cover rejects) in ``fused_rounds`` ``mode``:
+    its per-piece counters and the registry's residual-kill series."""
+    from repro import obs
+    from repro.core.backends import get_backend
+    from repro.core.backends.jax_backend import JaxUnionSampler
+    cover = estimate_union(warmup(cat, [spec], method="exact").oracle).cover
+    reg = obs.MetricsRegistry()
+    prev = obs.set_registry(reg)
+    obs.set_enabled(True)
+    try:
+        eng = JaxUnionSampler(get_backend("jax", cat, [spec], seed=2), cover,
+                              seed=13, round_batch=512, fused_rounds=mode)
+        assert len(eng.sample(n)) == n
+        series = reg.snapshot()["repro_engine_piece_residual_kills_total"][
+            "series"]
+    finally:
+        obs.set_enabled(None)
+        obs.set_registry(prev)
+    return eng.piece_stats_dict()[spec.name], series.get(
+        (("join", spec.name),), 0), eng
+
+
+@pytest.mark.parametrize("mode", ["device", "host"])
+def test_residual_kills_count_the_probes_with_no_match(mode):
+    """M = 1: every walk turned away is a dead residual probe.  The skeleton
+    walk never dies (exact EW weights), so draws = accepts + kills, and the
+    kill rate is the skeleton's share outside the cyclic join."""
+    cat, spec = _unique_residual_spec()
+    skel = JoinSpec("SKEL", [n for n in spec.nodes if n.kind == "tree"])
+    n_skel = next(iter(full_join(cat, skel).values())).shape[0]
+    n_join = len(brute_force_join(spec))
+    assert 0 < n_join < n_skel
+    st, published, eng = _piece_stats(cat, spec, mode)
+    assert st["residual_rejects"] == 0 == eng.stats.residual_rejects
+    assert st["residual_kills"] == st["draws"] - st["accepts"] > 0
+    assert published == st["residual_kills"]
+    assert st["residual_kills"] / st["draws"] == pytest.approx(
+        1 - n_join / n_skel, abs=0.05)
+
+
+def test_residual_kills_also_count_the_d_over_m_rejections():
+    """M = 4: kills are the d/M rejections plus the dead probes, and the
+    device loop and its host twin count them alike."""
+    cat, spec = _cyclic_spec(5)
+    dev, _, eng = _piece_stats(cat, spec, "device")
+    host, _, _ = _piece_stats(cat, spec, "host")
+    assert dev == host
+    assert 0 < dev["residual_rejects"] == eng.stats.residual_rejects
+    assert dev["residual_kills"] == dev["draws"] - dev["accepts"]
+    assert dev["residual_kills"] > dev["residual_rejects"]
+
+
+def test_residual_kills_read_zero_on_an_acyclic_join():
+    R, S, T = tiny_db(0)
+    spec = chain_join("RST", [R, S, T], ["b", "c"])
+    st, published, _ = _piece_stats(Catalog(), spec, "device")
+    assert st["residual_kills"] == 0 == published
+    assert st["draws"] > 0
+
+
+# ---------------------------------------------------------------------------
 # UQ4 end-to-end: device == host uniformity; 1-device mesh bit-for-bit
 # ---------------------------------------------------------------------------
 
@@ -225,6 +308,8 @@ def test_uq4_one_shard_mesh_bitwise_equals_jax_engine(uq4_setup):
     assert np.array_equal(a.home, b.home)
     assert np.array_equal(a.fingerprint, b.fingerprint)
     assert a.stats.as_dict() == b.stats.as_dict()
+    assert np.array_equal(plain._engine.piece_stats,
+                          sharded._engine.piece_stats)
 
 
 def test_uq4_online_refines_on_device(uq4_setup):

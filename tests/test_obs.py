@@ -296,6 +296,15 @@ def test_spans_are_named_in_spans(monkeypatch):
     ("jit(loop_fn)/while/body/add", obs.UNSCOPED),
     ("jit(loop_fn)/while/body/algo1_fused_round/select_n", obs.UNSCOPED),
     ("gather", obs.UNSCOPED),
+    # a cyclic piece: its residual step opens inside its walk
+    ("jit(loop_fn)/while/body/algo1_fused_round/walk/Q5_J0/gather",
+     "walk/Q5_J0"),
+    ("jit(loop_fn)/while/body/algo1_fused_round/walk/Q5_J0/residual/Q5_J0/"
+     "gather", "residual/Q5_J0"),
+    ("jit(loop_fn)/while/body/algo1_fused_round/walk/Q5_J0/residual/Q5_J0/"
+     "pallas_call/_searchsorted_i32", "residual/Q5_J0"),
+    ("jit(loop_fn)/while/body/algo1_fused_round/member/Q5_J1/residual/"
+     "Q5_J1/lt", "member/Q5_J1"),
 ])
 def test_phase_of_name_stacks(op_name, phase):
     assert obs.phase_of(op_name) == phase
@@ -323,16 +332,17 @@ def test_hlo_op_phases_and_publication():
         obs.publish_op_phases("not hlo")
 
 
-@pytest.mark.parametrize("workload", ["uq1", "uq2"])
+@pytest.mark.parametrize("workload", ["uq1", "uq2", "uq4"])
 def test_every_loop_body_op_sits_in_a_phase(workload):
-    """Lower the device loop of a small UQ1 and UQ2 union: every op of the
-    ``while`` body carries one of the phase scopes in its ``op_name``
-    metadata."""
+    """Lower the device loop of a small UQ1, UQ2 and cyclic UQ4 union: every
+    op of the ``while`` body carries one of the phase scopes in its
+    ``op_name`` metadata, and a cyclic piece's residual step has its own."""
     import jax
     import jax.numpy as jnp
-    from repro.data.workloads import uq2
-    wl = (uq1(scale=0.1, seed=0, n_joins=2) if workload == "uq1"
-          else uq2(scale=0.05, seed=0))
+    from repro.data.workloads import uq2, uq4
+    wl = {"uq1": lambda: uq1(scale=0.1, seed=0, n_joins=2),
+          "uq2": lambda: uq2(scale=0.05, seed=0),
+          "uq4": lambda: uq4(scale=0.02, seed=0)}[workload]()
     eng = _engine(wl, _cover(wl), "device")
     eng._ensure_device_inputs()
     C = 1024
@@ -351,9 +361,11 @@ def test_every_loop_body_op_sits_in_a_phase(workload):
             if obs.phase_of(name) == obs.UNSCOPED]
     assert not bare, bare[:10]
     phases = {obs.phase_of(name) for _, name in body_ops}
-    for j in eng.order:
+    for j, tree in zip(eng.order, eng.trees):
         assert {f"walk/{j}", f"compact/{j}"} <= phases
+        assert (f"residual/{j}" in phases) == tree.has_residual
     assert {"select", "emit", "carry", f"member/{eng.order[-1]}"} <= phases
+    assert any(t.has_residual for t in eng.trees) == (workload == "uq4")
 
 
 def test_piece_stats_consistency(registry, obs_on):
