@@ -58,6 +58,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -71,6 +72,7 @@ from ..join_sampler import EmptyJoinError, JoinSampler
 from ..joins import JoinSpec
 from ..membership import rows_length
 from .. import planner
+from ..predicates import selectivity_factor
 from .base import Backend, Rows
 
 _I32_LIM = 1 << 31
@@ -509,23 +511,51 @@ class DeviceTreeJoin:
                                    arrays["nodes"], skeleton=skeleton)
 
     # analysis: traced
+    def _tree_pick(self, i: int, node, rows, ok, u):
+        """The range probe and pick of tree node ``i`` for the walks whose
+        edge keys ``rows`` holds: returns the picked row and ``ok`` cleared
+        where the edge had no match."""
+        cfg = self.node_cfgs[i]
+        q = _pack_jnp(rows, cfg.edge_attrs, cfg.radices)
+        lo, hi = self._ranges(i, node["probe"], q)
+        if cfg.uniform:
+            d = hi - lo
+            off = jnp.floor(u * jnp.maximum(d, 1).astype(jnp.float32)
+                            ).astype(jnp.int32)
+            pos = lo + jnp.minimum(off, jnp.maximum(d - 1, 0))
+            ok = ok & (d > 0)
+        else:
+            pos, alive = _inverse_cdf_pick(node["wprefix"], lo, hi, u)
+            ok = ok & alive & (hi > lo)
+        perm = node["perm"]
+        return perm[jnp.clip(pos, 0, perm.shape[0] - 1)], ok
+
+    # analysis: traced
+    def _residual_pick(self, i: int, cfg: _NodeCfg, node, rows, ok,
+                       acc_ratio, u):
+        """The probe, pick and d/M factor of :meth:`_residual_step`:
+        returns the picked row of node ``i`` instead of its columns."""
+        q = _pack_jnp(rows, cfg.edge_attrs, cfg.radices)
+        lo, hi = self._ranges(i, node["probe"], q)
+        d = hi - lo
+        off = jnp.floor(u * jnp.maximum(d, 1).astype(jnp.float32)
+                        ).astype(jnp.int32)
+        pos = lo + jnp.minimum(off, jnp.maximum(d - 1, 0))
+        ok = ok & (d > 0)
+        acc_ratio = acc_ratio * (d.astype(jnp.float32)
+                                 / jnp.float32(max(cfg.max_degree, 1)))
+        perm = node["perm"]
+        return perm[jnp.clip(pos, 0, perm.shape[0] - 1)], ok, acc_ratio
+
+    # analysis: traced
     def _residual_step(self, i: int, cfg: _NodeCfg, node, rows, ok,
                        acc_ratio, u):
         """One residual edge: sorted-key probe, uniform pick, d/M factor.
         ``node`` is node ``i`` of :meth:`device_arrays`.  Its ops sit in
         the ``residual/<join>`` phase (``repro.obs.LOOP_PHASES``)."""
         with jax.named_scope(f"residual/{self.name}"):
-            q = _pack_jnp(rows, cfg.edge_attrs, cfg.radices)
-            lo, hi = self._ranges(i, node["probe"], q)
-            d = hi - lo
-            off = jnp.floor(u * jnp.maximum(d, 1).astype(jnp.float32)
-                            ).astype(jnp.int32)
-            pos = lo + jnp.minimum(off, jnp.maximum(d - 1, 0))
-            ok = ok & (d > 0)
-            acc_ratio = acc_ratio * (d.astype(jnp.float32)
-                                     / jnp.float32(max(cfg.max_degree, 1)))
-            perm = node["perm"]
-            child = perm[jnp.clip(pos, 0, perm.shape[0] - 1)]
+            child, ok, acc_ratio = self._residual_pick(i, cfg, node, rows,
+                                                       ok, acc_ratio, u)
             for a, c in node["cols"].items():
                 rows[a] = c[child]
         return rows, ok, acc_ratio
@@ -569,19 +599,7 @@ class DeviceTreeJoin:
                 rows, res_ok, acc_ratio = self._residual_step(
                     i, cfg, node, rows, res_ok, acc_ratio, u)
                 continue
-            q = _pack_jnp(rows, cfg.edge_attrs, cfg.radices)
-            lo, hi = self._ranges(i, node["probe"], q)
-            if cfg.uniform:
-                d = hi - lo
-                off = jnp.floor(u * jnp.maximum(d, 1).astype(jnp.float32)
-                                ).astype(jnp.int32)
-                pos = lo + jnp.minimum(off, jnp.maximum(d - 1, 0))
-                ok = ok & (d > 0)
-            else:
-                pos, alive = _inverse_cdf_pick(node["wprefix"], lo, hi, u)
-                ok = ok & alive & (hi > lo)
-            perm = node["perm"]
-            child = perm[jnp.clip(pos, 0, perm.shape[0] - 1)]
+            child, ok = self._tree_pick(i, node, rows, ok, u)
             for a, c in node["cols"].items():
                 rows[a] = c[child]
         if not self.has_residual:
@@ -592,6 +610,62 @@ class DeviceTreeJoin:
             accept = walk_ok & (u_acc < acc_ratio)
         return ((rows, accept, walk_ok, ok) if skeleton
                 else (rows, accept, walk_ok))
+
+    # analysis: traced
+    def walk_keys(self, key: jax.Array, batch: int, arrays):
+        """The walk of :meth:`draw` without its payload.
+
+        Every edge runs as in :meth:`draw_with_root` (whole root): the same
+        key split, uniforms, range probes, picks and ``Π d/M`` test, so a
+        walk picks the same rows.  Of the columns it gathers only those a
+        later edge key reads; :meth:`gather_rows` fetches the rest for the
+        walks a caller keeps.  Returns ``(idx, accept, walk_ok, skel_ok)``
+        as :meth:`draw` with ``skeleton=True``, where ``idx`` is the
+        ``(batch, nodes + 1)`` int32 matrix of each walk's row of the root
+        (column 0) and of every node in :attr:`node_cfgs` order."""
+        nodes = arrays["nodes"]
+        keyed = {a for cfg in self.node_cfgs for a in cfg.edge_attrs}
+        keys = jax.random.split(key, len(self.node_cfgs) + 1
+                                + (1 if self.has_residual else 0))
+        u0 = jax.random.uniform(keys[0], (batch,))
+        r_pos, ok = _inverse_cdf_pick(
+            arrays["root_wprefix"], jnp.zeros((batch,), jnp.int32),
+            jnp.full((batch,), self.n_root, jnp.int32), u0)
+        rows = {a: c[r_pos] for a, c in arrays["root_cols"].items()
+                if a in keyed}
+        picked = [r_pos]
+        acc_ratio = jnp.ones((batch,), jnp.float32)
+        res_ok = jnp.ones((batch,), bool)
+        for i, (cfg, node) in enumerate(zip(self.node_cfgs, nodes)):
+            u = jax.random.uniform(keys[i + 1], (batch,))
+            if cfg.kind == "residual":
+                with jax.named_scope(f"residual/{self.name}"):
+                    child, res_ok, acc_ratio = self._residual_pick(
+                        i, cfg, node, rows, res_ok, acc_ratio, u)
+            else:
+                child, ok = self._tree_pick(i, node, rows, ok, u)
+            picked.append(child)
+            for a, c in node["cols"].items():
+                if a in keyed:
+                    rows[a] = c[child]
+        idx = jnp.stack([p.astype(jnp.int32) for p in picked], axis=1)
+        if not self.has_residual:
+            return idx, ok, ok, None
+        u_acc = jax.random.uniform(keys[-1], (batch,))
+        with jax.named_scope(f"residual/{self.name}"):
+            walk_ok = ok & res_ok
+            accept = walk_ok & (u_acc < acc_ratio)
+        return idx, accept, walk_ok, ok
+
+    # analysis: traced
+    def gather_rows(self, idx: jnp.ndarray, arrays) -> Dict[str, jnp.ndarray]:
+        """Every output column of the walks whose rows ``idx`` holds (the
+        matrix :meth:`walk_keys` returns, or some of its rows)."""
+        rows = {a: c[idx[:, 0]] for a, c in arrays["root_cols"].items()}
+        for i, node in enumerate(arrays["nodes"]):
+            for a, c in node["cols"].items():
+                rows[a] = c[idx[:, i + 1]]
+        return rows
 
 
 # ---------------------------------------------------------------------------
@@ -922,11 +996,13 @@ _STAT_FIELDS = ("iterations", "candidate_draws", "cover_rejects",
 # Columns: candidate draws, cover-accepted rows, §8.2 residual rejections
 # (the Π d/M test), §8.2 residual kills (skeleton walks turned away at a
 # residual edge: a probe with no match, or the Π d/M test; 0 on acyclic
-# pieces), rows drained from the surplus bank, and the post-round
-# bank-occupancy high-water mark (max over the call; folded with max
-# across calls).  Every column but the last sums.
+# pieces), residual survivors dropped because the round's survivor width
+# was full (0 where the piece is not narrowed), rows drained from the
+# surplus bank, and the post-round bank-occupancy high-water mark (max over
+# the call; folded with max across calls).  Every column but the last sums.
 PIECE_STAT_FIELDS = ("draws", "accepts", "residual_rejects",
-                     "residual_kills", "bank_drained", "bank_hwm")
+                     "residual_kills", "residual_overflow", "bank_drained",
+                     "bank_hwm")
 
 
 def _cover_cum(probs_base: jnp.ndarray, dead: jnp.ndarray):
@@ -965,6 +1041,39 @@ def _piece_batches(probs, round_batch: int, balance: str,
         b = max(256, ((want + 127) // 128) * 128)
         out.append(min(int(round_batch), b))
     return tuple(out)
+
+
+def survival_share(tree: DeviceTreeJoin, join_size) -> Optional[float]:
+    """The share of a cyclic piece's skeleton walks that its residual edges
+    accept: each join tuple is reached with probability 1 / (W · Π M),
+    W the skeleton's EW total and M each residual edge's max degree, so
+    the share is |J| / (W · Π M).  ``join_size`` is the cover's size of
+    the join, which counts only the tuples its §8.3 rejection predicates
+    keep; those predicates run after the survivors are cut, so the share is
+    taken over the unfiltered join, the size over
+    :func:`~repro.core.predicates.selectivity_factor`.  ``None`` on an
+    acyclic piece or where the sizes are unknown."""
+    sel = selectivity_factor(tree.spec)
+    if (not tree.has_residual or not join_size or tree.total_weight <= 0
+            or sel <= 0):
+        return None
+    m = 1
+    for cfg in tree.node_cfgs:
+        if cfg.kind == "residual":
+            m *= max(cfg.max_degree, 1)
+    return float(join_size) / sel / (tree.total_weight * m)
+
+
+def survivor_width(batch: int, share: Optional[float]) -> int:
+    """Static width of the residual survivors a cyclic piece keeps from one
+    round's ``batch`` walks, each surviving with probability ``share``: the
+    mean plus 8 binomial sigmas, rounded up to 128 lanes, at most ``batch``
+    (no narrowing where the share is unknown or at least one half)."""
+    if share is None or not 0 <= share < 0.5:
+        return int(batch)
+    mean = batch * share
+    want = math.ceil(mean + 8.0 * math.sqrt(mean * (1.0 - share)))
+    return min(int(batch), max(128, -(-want // 128) * 128))
 
 
 def _emit_and_bank(out, pos, bank, head, count,
@@ -1126,7 +1235,10 @@ class JaxUnionSampler:
        program (sorted-key probes + ``Π d/M`` acceptance, §8.2), so a
        residual rejection simply leaves the slot unaccepted and its target
        flows into the per-piece shortfall carry like any other rejection —
-       round shapes stay static and no piece is ever re-selected,
+       round shapes stay static and no piece is ever re-selected.  A cyclic
+       piece whose residual turns most walks away walks keys and row
+       indices only, keeps the first ``survivor_widths[j]`` walks its
+       residual accepted, and gathers their payload alone for steps 3-4,
     3. **cover-membership acceptance** — a candidate of piece ``j`` survives
        iff no earlier cover piece contains it (batched device probes),
     4. **compaction** — accepted candidates ranked to the front per join
@@ -1151,6 +1263,10 @@ class JaxUnionSampler:
     testing and debugging; the two modes produce bit-identical samples and
     stats from the same seed.
     """
+
+    # whether the round narrows a cyclic piece to its residual survivors
+    # (``_round_core_impl``); engines with rounds of their own draw full rows
+    _narrows_residual = True
 
     def __init__(self, backend: JaxBackend, cover, seed: int = 0,
                  round_batch: int = 4096,
@@ -1271,9 +1387,18 @@ class JaxUnionSampler:
                 for n in self.order if n in self.backend.trees}
 
     def _setup_planner(self) -> None:
-        """Derive planner constants from the (possibly overridden)
-        ``piece_batches``.  Called again by the sharded engine after it
-        rescales the per-piece widths to ``world`` shards."""
+        """Derive planner constants and the survivor widths from the
+        (possibly overridden) ``piece_batches``.  Called again by the
+        sharded engine after it rescales the per-piece widths to ``world``
+        shards."""
+        # per piece, the static width its residual survivors are narrowed
+        # to before the payload gathers, membership probes and compaction
+        # (the piece's draw width where that would not narrow, and in the
+        # engines whose rounds draw full rows)
+        self.survivor_widths = tuple(
+            survivor_width(b, survival_share(t, self.cover.join_sizes.get(n)))
+            if self._narrows_residual else b
+            for b, t, n in zip(self.piece_batches, self.trees, self.order))
         if self.plan == "adaptive":
             # expanded selection slots amortize the fixed per-round cost;
             # the demand-matched widths above size the supply to fill them
@@ -1314,11 +1439,13 @@ class JaxUnionSampler:
         """One Algorithm-1 round (traceable; shared by the host-driven
         wrapper and the device loop body).  ``cat`` is
         :meth:`_catalog_args` as traced by the caller.  Returns per join the
-        accepted-compacted candidate columns plus (ok, residual, accepted,
-        predicate-reject, residual-kill) counts and the per-piece need =
-        carry + this round's targets.  Under ``plan="adaptive"`` the
-        acceptance EMAs and current bank occupancy come in too and the
-        per-piece candidate budget goes out as an eighth element."""
+        accepted-compacted candidate columns (``survivor_widths[j]`` rows
+        where the piece is narrowed, else ``piece_batches[j]``) plus (ok,
+        residual, accepted, predicate-reject, residual-kill,
+        residual-overflow) counts and the per-piece need = carry + this
+        round's targets.  Under ``plan="adaptive"`` the acceptance EMAs and
+        current bank occupancy come in too and the per-piece candidate
+        budget goes out as a ninth element."""
         with jax.named_scope("algo1_fused_round"):
             return self._round_core_impl(key, probs_cum, carry_need,
                                          extra_target, cat, ema, bank_count)
@@ -1360,13 +1487,18 @@ class JaxUnionSampler:
         # (2)+(3) per join: batched candidate draw (incl. §8.2 residual-edge
         # verification for cyclic pieces) + fused §8.3 predicate acceptance
         # + earlier-piece rejection
-        cols, okc, resc, accc, predc, killc = [], [], [], [], [], []
+        cols, okc, resc, accc, predc, killc, ovfc = [], [], [], [], [], [], []
         for j, tree in enumerate(self.trees):
-            bj = self.piece_batches[j]
+            bj, sj = self.piece_batches[j], self.survivor_widths[j]
+            narrow = sj < bj
             name = self.order[j]
             with jax.named_scope(f"walk/{name}"):
-                rows, acc, walk_ok, skel_ok = tree.draw(
-                    jks[j], bj, cat["trees"][j], skeleton=True)
+                if narrow:
+                    idx, acc, walk_ok, skel_ok = tree.walk_keys(
+                        jks[j], bj, cat["trees"][j])
+                else:
+                    rows, acc, walk_ok, skel_ok = tree.draw(
+                        jks[j], bj, cat["trees"][j], skeleton=True)
             with jax.named_scope(f"filter/{name}"):
                 if budget is not None:
                     # budget mask: the first budget[j] slots of an i.i.d.
@@ -1380,6 +1512,24 @@ class JaxUnionSampler:
                 resc.append(jnp.sum(walk_ok) - jnp.sum(acc))
                 killc.append(jnp.int32(0) if skel_ok is None
                              else jnp.sum(skel_ok) - jnp.sum(acc))
+            if narrow:
+                # residual first, payload later: the first sj walks the
+                # residual accepted, in slot order, go on; the rest are
+                # spent draws.  The cut depends on counts alone, so the
+                # kept walks stay i.i.d. uniform over the piece.
+                with jax.named_scope(f"compact/{name}"):
+                    kept = jnp.sum(acc)
+                    ovfc.append(jnp.maximum(kept - sj, 0))
+                    dst = jnp.where(acc, jnp.cumsum(acc) - 1, sj)
+                    idx = (jnp.zeros((sj, idx.shape[1]), jnp.int32)
+                           .at[dst].set(idx, mode="drop"))
+                    acc = jnp.arange(sj) < kept
+                with jax.named_scope(f"walk/{name}"):
+                    rows = tree.gather_rows(idx, cat["trees"][j])
+                bj = sj
+            else:
+                ovfc.append(jnp.int32(0))
+            with jax.named_scope(f"filter/{name}"):
                 pf = self._pred_fns[j]
                 if pf is None:
                     predc.append(jnp.int32(0))
@@ -1408,7 +1558,8 @@ class JaxUnionSampler:
                    jnp.stack(resc).astype(jnp.int32),
                    jnp.stack(accc).astype(jnp.int32),
                    jnp.stack(predc).astype(jnp.int32),
-                   jnp.stack(killc).astype(jnp.int32), need)
+                   jnp.stack(killc).astype(jnp.int32),
+                   jnp.stack(ovfc).astype(jnp.int32), need)
             if adaptive:
                 out = out + (budget.astype(jnp.int32),)
         return out
@@ -1474,13 +1625,13 @@ class JaxUnionSampler:
                     extra = jnp.clip(n - total - jnp.sum(state["owed"]),
                                      0, self._slot_width)
                 if adaptive:
-                    cols, okc, resc, accc, predc, killc, need, budget = \
-                        self._round_core(kround, probs_cum, state["owed"],
-                                         extra, cat, state["ema"],
-                                         state["bank_count"])
+                    (cols, okc, resc, accc, predc, killc, ovfc, need,
+                     budget) = self._round_core(
+                        kround, probs_cum, state["owed"], extra, cat,
+                        state["ema"], state["bank_count"])
                 else:
                     budget = None
-                    cols, okc, resc, accc, predc, killc, need = \
+                    cols, okc, resc, accc, predc, killc, ovfc, need = \
                         self._round_core(kround, probs_cum, state["owed"],
                                          extra, cat)
                 # bank take (FIFO, capped) → fresh take → carried shortfall
@@ -1500,7 +1651,9 @@ class JaxUnionSampler:
                     # rounds is empty in reality (estimation noise) — drop it
                     dropped = jnp.sum(jnp.where(state["dead"], shortfall, 0))
                     shortfall = jnp.where(state["dead"], 0, shortfall)
-                    trig = (shortfall > 0) & (accc == 0) & (count2 == 0)
+                    # (a piece whose survivors overflowed is not empty)
+                    trig = ((shortfall > 0) & (accc == 0) & (count2 == 0)
+                            & (ovfc == 0))
                     streak = jnp.where(
                         state["dead"], state["streak"],
                         jnp.where(trig, state["streak"] + 1, 0))
@@ -1515,7 +1668,7 @@ class JaxUnionSampler:
                     stats2 = stats + jnp.stack(
                         [drawn.astype(jnp.int32), drawn.astype(jnp.int32),
                          (jnp.sum(okc) - jnp.sum(resc) - jnp.sum(predc)
-                          - jnp.sum(accc)).astype(jnp.int32),
+                          - jnp.sum(accc) - jnp.sum(ovfc)).astype(jnp.int32),
                          jnp.sum(resc).astype(jnp.int32),
                          jnp.sum(predc).astype(jnp.int32),
                          dropped.astype(jnp.int32)])
@@ -1528,8 +1681,9 @@ class JaxUnionSampler:
                          pstats[:, 1] + accc,
                          pstats[:, 2] + resc,
                          pstats[:, 3] + killc,
-                         pstats[:, 4] + dt.astype(jnp.int32),
-                         jnp.maximum(pstats[:, 5],
+                         pstats[:, 4] + ovfc,
+                         pstats[:, 5] + dt.astype(jnp.int32),
+                         jnp.maximum(pstats[:, 6],
                                      count2.astype(jnp.int32))],
                         axis=1)
                     state2 = {"key": key,
@@ -1661,6 +1815,10 @@ class JaxUnionSampler:
                             "skeleton walks turned away at a §8.2 residual "
                             "edge (no match, or the d/M test) per piece",
                             ("join",)),
+                reg.counter("repro_engine_piece_residual_overflow_total",
+                            "residual survivors dropped as spent draws "
+                            "because the round's survivor width was full",
+                            ("join",)),
                 reg.counter("repro_engine_piece_bank_drained_total",
                             "rows served from the surplus bank", ("join",)),
             ]
@@ -1670,6 +1828,11 @@ class JaxUnionSampler:
                 "hwm": reg.gauge("repro_engine_piece_bank_hwm",
                                  "surplus-bank occupancy high-water mark",
                                  ("join",)),
+                "width": reg.gauge(
+                    "repro_engine_piece_survivor_width",
+                    "rows a piece keeps per round for the payload gathers, "
+                    "membership probes and compaction",
+                    ("join",)),
                 "ema": reg.gauge(
                     "repro_engine_piece_ema",
                     "adaptive-planner acceptance EMA (fraction of budget)",
@@ -1715,6 +1878,7 @@ class JaxUnionSampler:
                 if v:
                     child.inc(v)
             h["hwm"].labels(join=name).set(int(self.piece_stats[j, -1]))
+            h["width"].labels(join=name).set(self.survivor_widths[j])
             if ema is not None:
                 for i, comp in enumerate(planner.EMA_COMPONENTS):
                     h["ema"].labels(join=name, component=comp).set(
@@ -1761,8 +1925,8 @@ class JaxUnionSampler:
                                self._slot_width))
             self.key, sub = jax.random.split(self.key)
             if adaptive:
-                cols, okc, resc, accc, predc, killc, need, budget, bad = \
-                    self._round_jit(
+                (cols, okc, resc, accc, predc, killc, ovfc, need, budget,
+                 bad) = self._round_jit(
                         self._probs_base, jnp.asarray(dead),
                         jnp.asarray(owed.astype(np.int32)),
                         jnp.int32(extra), sub, jnp.asarray(self._h_ema),
@@ -1771,7 +1935,7 @@ class JaxUnionSampler:
                 budget = np.asarray(budget)
             else:
                 budget = None
-                (cols, okc, resc, accc, predc, killc, need,
+                (cols, okc, resc, accc, predc, killc, ovfc, need,
                  bad) = self._round_jit(
                     self._probs_base, jnp.asarray(dead),
                     jnp.asarray(owed.astype(np.int32)), jnp.int32(extra),
@@ -1783,6 +1947,7 @@ class JaxUnionSampler:
             accc = np.asarray(accc).astype(np.int64)
             predc = np.asarray(predc).astype(np.int64)
             killc = np.asarray(killc).astype(np.int64)
+            ovfc = np.asarray(ovfc).astype(np.int64)
             need = np.asarray(need).astype(np.int64)
             drawn = bt if budget is None else int(budget.sum())
             self.stats.iterations += drawn
@@ -1790,11 +1955,13 @@ class JaxUnionSampler:
             # residual (§8.2), predicate (§8.3) and membership rejections are
             # accounted separately (dead walks are none of the three; the
             # per-piece residual_kills column counts those that die at a
-            # residual edge)
+            # residual edge, residual_overflow the survivors past the
+            # survivor width)
             self.stats.residual_rejects += int(resc.sum())
             self.stats.pred_rejects += int(predc.sum())
             self.stats.cover_rejects += int(okc.sum() - resc.sum()
-                                            - predc.sum() - accc.sum())
+                                            - predc.sum() - accc.sum()
+                                            - ovfc.sum())
             dt = np.minimum(np.minimum(need, count), self._drain_w)
             ft = np.minimum(need - dt, accc)
             for j in range(nj):
@@ -1823,8 +1990,9 @@ class JaxUnionSampler:
             pstats[:, 1] += accc
             pstats[:, 2] += resc
             pstats[:, 3] += killc
-            pstats[:, 4] += dt
-            pstats[:, 5] = np.maximum(pstats[:, 5], count)
+            pstats[:, 4] += ovfc
+            pstats[:, 5] += dt
+            pstats[:, 6] = np.maximum(pstats[:, 6], count)
             if adaptive:
                 # numpy EMA step — planner.ema_update with xp=np runs the
                 # same int32 adds/shifts/divides as the device carry
@@ -1837,7 +2005,8 @@ class JaxUnionSampler:
             # dead-piece bookkeeping — identical rules to the device loop
             self.stats.dropped_slots += int(shortfall[dead].sum())
             shortfall[dead] = 0
-            trig = (shortfall > 0) & (accc == 0) & (count == 0)
+            trig = ((shortfall > 0) & (accc == 0) & (count == 0)
+                    & (ovfc == 0))
             streak[:] = np.where(dead, streak,
                                  np.where(trig, streak + 1, 0))
             newly = ~dead & (streak >= self.dead_rounds)
@@ -1916,6 +2085,7 @@ class JaxRecordUnionSampler(JaxUnionSampler):
 
     _KWIN = 8          # static fp1 duplicate window (cf. DeviceJoinMembership)
     _SENTINEL = 0xFFFFFFFF
+    _narrows_residual = False          # _record_round draws full rows
 
     def __init__(self, backend: JaxBackend, cover, seed: int = 0,
                  round_batch: int = 4096,

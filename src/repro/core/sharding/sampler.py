@@ -94,6 +94,8 @@ class ShardedUnionSampler(JaxUnionSampler):
     the ``shard_map``'d persistent program built here.
     """
 
+    _narrows_residual = False          # the mesh round draws full rows
+
     def __init__(self, scat: ShardedCatalog, cover, seed: int = 0,
                  round_batch: int = 4096, dead_rounds: int = 8,
                  max_rounds: int = 4096, surplus_cap: Optional[int] = None,
@@ -410,9 +412,10 @@ class ShardedUnionSampler(JaxUnionSampler):
                 g[pos:pos + a] = m[s, :a]
                 pos += a
             cols.append(g)
+        # the mesh round draws full rows: no residual survivor is dropped
         out = (cols, okc.sum(axis=0), resc.sum(axis=0), accc.sum(axis=0),
                predc.sum(axis=0), np.asarray(killc).sum(axis=0),
-               np.asarray(need)[0])
+               np.zeros(len(self.order), np.int64), np.asarray(need)[0])
         if budget is not None:
             out = out + (budget,)
         return out + (bool(np.asarray(bad)[0]),)
@@ -548,8 +551,9 @@ class ShardedUnionSampler(JaxUnionSampler):
                                        .astype(jnp.int32),
                      pstats[:, 3] + jnp.sum(gat[:, 5], axis=0)
                                        .astype(jnp.int32),
-                     pstats[:, 4] + dtg.astype(jnp.int32),
-                     jnp.maximum(pstats[:, 5], countg2.astype(jnp.int32))],
+                     pstats[:, 4],              # no survivor width here
+                     pstats[:, 5] + dtg.astype(jnp.int32),
+                     jnp.maximum(pstats[:, 6], countg2.astype(jnp.int32))],
                     axis=1)
                 nxt = (key2, shortfall.astype(jnp.int32), dead | newly,
                        streak2.astype(jnp.int32), bank2,

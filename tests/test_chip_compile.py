@@ -102,9 +102,10 @@ def test_walk_hop_compiles_for_v5e(one_chip, n_keys, n_queries):
     assert _custom_calls(text) == 1 + levels
 
 
-def _compile_loop(one_chip, monkeypatch):
-    """The fused Algorithm-1 loop of a two-join UQ1 union, catalog passed
-    as an argument, lowered from shapes and compiled: (engine, HLO text)."""
+def _compile_loop(one_chip, monkeypatch, wl=None, round_batch=256):
+    """The fused Algorithm-1 loop of a union (by default two UQ1 joins),
+    catalog passed as an argument, lowered from shapes and compiled:
+    (engine, HLO text)."""
     from repro.core.backends.jax_backend import JaxBackend, JaxUnionSampler
     from repro.core.framework import estimate_union, warmup
     from repro.data.workloads import uq1
@@ -112,11 +113,13 @@ def _compile_loop(one_chip, monkeypatch):
 
     # this process sees the CPU, where the probes would interpret
     monkeypatch.setattr(kops, "default_interpret", lambda: False)
-    wl = uq1(scale=0.1, seed=0, n_joins=2)
+    method = "exact" if wl is not None else "histogram"
+    if wl is None:
+        wl = uq1(scale=0.1, seed=0, n_joins=2)
     cover = estimate_union(warmup(wl.cat, wl.joins,
-                                  method="histogram").oracle).cover
+                                  method=method).oracle).cover
     eng = JaxUnionSampler(JaxBackend(wl.cat, wl.joins, use_pallas=True),
-                          cover, round_batch=256)
+                          cover, round_batch=round_batch)
     eng._ensure_device_inputs()
     C = 1024
     shapes = jax.tree.map(
@@ -131,8 +134,10 @@ def _compile_loop(one_chip, monkeypatch):
 
 
 def _check_probe_kernels(eng, text: str, levels: int):
-    """Every Pallas call is a probe's: ``1 + levels`` per tree node, each
-    named as the benchmark's trace reduction finds it and in ``walk/*``."""
+    """Every Pallas call is a probe's: ``1 + levels`` per node, each named
+    as the benchmark's trace reduction finds it, a tree node's in
+    ``walk/*`` and a residual node's in ``residual/*``."""
+    from collections import Counter
     from repro import obs
     assert {p.levels for t in eng.trees for p in t._prepped} == {levels}
     assert " while(" in text
@@ -142,10 +147,11 @@ def _check_probe_kernels(eng, text: str, levels: int):
     phases = obs.hlo_op_phases(text)
     kernels = re.findall(r"^\s*%([\w.-]+) = [^\n]*custom_call_target="
                          r'"tpu_custom_call"', text, re.M)
-    nodes = sum(len(t.node_cfgs) for t in eng.trees)
-    assert len(kernels) == (1 + levels) * nodes
+    kinds = Counter("residual" if c.kind == "residual" else "walk"
+                    for t in eng.trees for c in t.node_cfgs)
     assert all(k.startswith("_searchsorted_i32") for k in kernels)
-    assert {phases[k].split("/")[0] for k in kernels} == {"walk"}
+    assert Counter(phases[k].split("/")[0] for k in kernels) == Counter(
+        {kind: (1 + levels) * n for kind, n in kinds.items()})
 
 
 def test_device_loop_with_pallas_probes_compiles_for_v5e(one_chip,
@@ -153,6 +159,22 @@ def test_device_loop_with_pallas_probes_compiles_for_v5e(one_chip,
     """The Pallas probes sit inside the compiled while loop; at this size
     every index has one fence chunk, so one level."""
     eng, text = _compile_loop(one_chip, monkeypatch)
+    _check_probe_kernels(eng, text, levels=1)
+
+
+def test_device_loop_with_a_narrowed_cyclic_piece_compiles_for_v5e(
+        one_chip, monkeypatch):
+    """UQ4's cyclic piece walks its keys, keeps the walks its residual
+    accepts and gathers their payload inside the compiled loop: each node
+    is still probed once a round, the residual one in its own phase."""
+    from repro.data.workloads import uq4
+    eng, text = _compile_loop(one_chip, monkeypatch,
+                              wl=uq4(scale=0.02, seed=0), round_batch=1024)
+    narrowed = [n for n, t, s, b in zip(eng.order, eng.trees,
+                                        eng.survivor_widths,
+                                        eng.piece_batches) if s < b]
+    assert narrowed and all(t.has_residual for t in eng.trees
+                            if t.name in narrowed)
     _check_probe_kernels(eng, text, levels=1)
 
 

@@ -322,3 +322,293 @@ def test_uq4_online_refines_on_device(uq4_setup):
     # φ-refinement observed the cyclic member (wander-join walks hop the
     # residual edge) — its size accumulator has walks
     assert ou.estimator.size_stats["UQ4_CYC"].count > 0
+
+
+# ---------------------------------------------------------------------------
+# residual first, payload later: a cyclic piece narrows to the walks its
+# residual accepts before the payload gathers, membership and compaction
+# ---------------------------------------------------------------------------
+
+
+def test_key_walk_picks_the_rows_of_the_full_draw():
+    """``walk_keys`` then ``gather_rows`` on every walk gives the rows and
+    masks of ``draw`` on the same key, bit for bit."""
+    import jax
+    cat, spec = _cyclic_spec(3)
+    tree = DeviceTreeJoin(cat, spec)
+    arrays = tree.device_arrays()
+    key = jax.random.PRNGKey(17)
+    rows, acc, walk_ok, skel_ok = tree.draw(key, 1024, arrays,
+                                            skeleton=True)
+    idx, acc2, walk_ok2, skel_ok2 = tree.walk_keys(key, 1024, arrays)
+    assert idx.shape == (1024, len(tree.node_cfgs) + 1)
+    for a, b in ((acc, acc2), (walk_ok, walk_ok2), (skel_ok, skel_ok2)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    got = tree.gather_rows(idx, arrays)
+    assert set(got) == set(rows)
+    for a in rows:
+        assert np.array_equal(np.asarray(got[a]), np.asarray(rows[a])), a
+    assert 0 < int(np.asarray(acc).sum()) < 1024
+
+
+def test_survivor_width_follows_the_survival_share():
+    from repro.core.backends.jax_backend import survivor_width
+    # Q5 at SF1: about 2.45 % of 5,248 walks survive, mean 129, sigma 11
+    assert survivor_width(5248, 0.0245) == 256
+    assert survivor_width(3200, 0.0245) == 256
+    assert survivor_width(512, 0.0) == 128
+    assert survivor_width(512, None) == 512          # unknown share
+    assert survivor_width(512, 0.5) == 512           # nothing to gain
+    assert survivor_width(256, 0.3) == 256           # capped at the batch
+
+
+@pytest.fixture(scope="module")
+def q5_cycle():
+    """The test union ``q5_cycle`` (TPC-H Q5's cycle over three sources),
+    the program's joins and exact cover over it, and its union as the set
+    of row-id tuples of the reference."""
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import system
+    from bench.reference import tree
+    from bench.tests.test_bench_reference import enumerate_join, small
+    _cfg, u = small("q5_cycle", 0.002)
+    cover = system.build_sampler(u, tree.intersection_sizes(u), seed=0,
+                                 round_batch=1024).cover
+    union = set().union(*[enumerate_join(u, j) for j in range(len(u.joins))])
+    return u, system.program_joins(u), cover, union
+
+
+def _q5_engine(q5, mode="device", seed=21, round_batch=1024, **kw):
+    from repro.core.backends import get_backend
+    from repro.core.backends.jax_backend import JaxUnionSampler
+    _u, specs, cover, _ = q5
+    return JaxUnionSampler(get_backend("jax", Catalog(), specs, seed=2),
+                           cover, seed=seed, round_batch=round_batch,
+                           fused_rounds=mode, **kw)
+
+
+def _q5_uniform_p(q5, ss):
+    """Every sampled row is a tuple of the union at its home piece (no
+    earlier piece holds it); p-value of chi-square over the union's tuples."""
+    from bench.reference import tree
+    u, _, _, union = q5
+    member = tree.Membership(u)
+    found, ids = member.row_ids(ss.rows)
+    assert found.all(), "sampled a row that is no tuple of the relations"
+    inside = member.matrix(found, ids)
+    home = np.asarray(ss.home)
+    assert inside[np.arange(home.shape[0]), home].all()
+    for j in range(inside.shape[1]):
+        assert not inside[home == j][:, :j].any(), "row outside its home"
+    tuples = list(map(tuple, np.stack(ids, axis=1).tolist()))
+    assert set(tuples) <= union
+    counts = np.zeros(len(union))
+    at = {t: i for i, t in enumerate(sorted(union))}
+    np.add.at(counts, [at[t] for t in tuples], 1)
+    exp = len(tuples) / len(union)
+    chi2 = float(((counts - exp) ** 2 / exp).sum())
+    return 1 - sps.chi2.cdf(chi2, df=len(union) - 1)
+
+
+_COUNTED = ("draws", "residual_rejects", "residual_kills", "accepts")
+
+
+def test_narrow_path_keeps_the_law_and_the_counts(q5_cycle, monkeypatch):
+    """The narrowed loop samples the union uniformly and, with no survivor
+    past its width, emits the stream of the full-width loop on the same seed
+    with the same draws, kills and rejections."""
+    from repro.core.backends import jax_backend
+    n = 60 * len(q5_cycle[3])
+    narrow = _q5_engine(q5_cycle)
+    assert all(s < b for s, b in zip(narrow.survivor_widths,
+                                     narrow.piece_batches))
+    a = narrow.sample(n)
+    p = _q5_uniform_p(q5_cycle, a)
+    assert p > 1e-3, f"narrowed device loop not uniform on q5_cycle (p={p})"
+    monkeypatch.setattr(jax_backend, "survivor_width", lambda b, share: b)
+    full = _q5_engine(q5_cycle)
+    assert full.survivor_widths == full.piece_batches
+    b = full.sample(n)
+    got, want = narrow.piece_stats_dict(), full.piece_stats_dict()
+    for name in got:
+        assert got[name]["residual_overflow"] == 0
+        for f in _COUNTED:
+            assert got[name][f] == want[name][f], (name, f)
+        assert got[name]["residual_kills"] > 0
+    assert a.stats.as_dict() == b.stats.as_dict()
+    assert np.array_equal(a.matrix(), b.matrix())
+    assert np.array_equal(a.home, b.home)
+
+
+def test_narrow_path_device_loop_equals_its_host_twin(q5_cycle):
+    dev = _q5_engine(q5_cycle, "device", seed=5)
+    host = _q5_engine(q5_cycle, "host", seed=5)
+    assert all(s < b for s, b in zip(dev.survivor_widths, dev.piece_batches))
+    for n in (3000, 1500):
+        a, b = dev.sample(n), host.sample(n)
+        assert np.array_equal(a.matrix(), b.matrix())
+        assert np.array_equal(a.home, b.home)
+    assert a.stats.as_dict() == b.stats.as_dict()
+    assert np.array_equal(dev.piece_stats, host.piece_stats)
+
+
+def test_overflow_keeps_the_law_and_is_counted(q5_cycle, monkeypatch):
+    """A survivor width of 1 drops survivors every round: the stream stays
+    uniform, every call completes, and the registry counts the drops.  A
+    piece whose one kept survivor an earlier piece holds yields nothing
+    that round; a round that overflowed does not count toward the
+    dead-piece streak, so no live piece is dropped for it."""
+    from repro import obs
+    from repro.core.backends import jax_backend
+    monkeypatch.setattr(jax_backend, "survivor_width", lambda b, share: 1)
+    reg = obs.MetricsRegistry()
+    prev = obs.set_registry(reg)
+    obs.set_enabled(True)
+    try:
+        eng = _q5_engine(q5_cycle, round_batch=256)
+        assert eng.survivor_widths == (1,) * len(eng.order)
+        calls = [eng.sample(1000) for _ in range(4)]
+        snap = reg.snapshot()
+    finally:
+        obs.set_enabled(None)
+        obs.set_registry(prev)
+    assert [len(c) for c in calls] == [1000] * 4
+    from repro.core.union_sampler import SampleSet
+    rows = {a: np.concatenate([c.rows[a] for c in calls])
+            for a in calls[0].attrs}
+    home = np.concatenate([c.home for c in calls])
+    ss = SampleSet(list(calls[0].attrs), rows, home, None, eng.stats)
+    p = _q5_uniform_p(q5_cycle, ss)
+    assert p > 1e-3, f"overflowing device loop not uniform (p={p})"
+    overflow = snap["repro_engine_piece_residual_overflow_total"]["series"]
+    assert sum(overflow.values()) > 0
+    st = eng.piece_stats_dict()
+    assert sum(s["residual_overflow"] for s in st.values()) == \
+        sum(overflow.values())
+    widths = snap["repro_engine_piece_survivor_width"]["series"]
+    assert set(widths.values()) == {1}
+    # the drops are spent draws: no target is dropped, and they count as
+    # neither cover nor residual rejections (the rest of the walks are the
+    # cover rejections and the rare skeleton walk with no match)
+    assert eng.stats.dropped_slots == 0
+    assert 0 < eng.stats.cover_rejects <= sum(
+        s["draws"] - s["residual_kills"] - s["residual_overflow"]
+        - s["accepts"] for s in st.values())
+
+
+@pytest.fixture(scope="module")
+def q5_cycle_rejecting(q5_cycle):
+    """``q5_cycle`` with a §8.3 rejection predicate on every join (lines of
+    quantity at most 15, about 30 % of them): the filtered joins, their
+    exact cover, and the filtered union."""
+    from bench.tests.test_bench_reference import enumerate_join
+    from repro.core.cover import build_cover
+    from repro.core.koverlap import OverlapOracle
+    from repro.core.predicates import Pred, rejection
+    u, specs, _, _ = q5_cycle
+    at_line = [r.name for r in u.rels].index("lineitem")
+    qty = u.rels[at_line].cols["l_quantity"]
+    joins = [{t for t in enumerate_join(u, j) if qty[t[at_line]] <= 15}
+             for j in range(len(u.joins))]
+    specs = [rejection(s, [Pred("l_quantity", "<=", 15)], name=s.name)
+             for s in specs]
+    at = {s.name: i for i, s in enumerate(specs)}
+
+    def size(d):
+        return float(len(set.intersection(*[joins[at[j.name]] for j in d])))
+
+    cover = build_cover(OverlapOracle(size, lambda j: size([j]), specs))
+    return u, specs, cover, set().union(*joins)
+
+
+def test_narrow_path_sizes_its_width_before_the_rejection_predicate(
+        q5_cycle, q5_cycle_rejecting, monkeypatch):
+    """The §8.3 predicate runs after the survivors are cut, so the width is
+    sized from the share the residual accepts of the unfiltered join, not
+    of the filtered one the cover counts.  At a width so sized no survivor
+    overflows and no slot is dropped: the stream is the full-width loop's
+    on the same seed, uniform over the filtered union."""
+    from bench.tests.test_bench_reference import enumerate_join
+    from repro.core.backends import jax_backend
+    u, _, cover, union = q5_cycle_rejecting
+    n = 60 * len(union)
+    narrow = _q5_engine(q5_cycle_rejecting, round_batch=16384)
+    for j, (name, tree) in enumerate(zip(narrow.order, narrow.trees)):
+        m = np.prod([c.max_degree for c in tree.node_cfgs
+                     if c.kind == "residual"])
+        exact = len(enumerate_join(u, j)) / (tree.total_weight * m)
+        share = jax_backend.survival_share(tree, cover.join_sizes[name])
+        assert share == pytest.approx(exact, rel=0.15), name
+    assert all(s < b for s, b in zip(narrow.survivor_widths,
+                                     narrow.piece_batches))
+    a = narrow.sample(n)
+    p = _q5_uniform_p(q5_cycle_rejecting, a)
+    assert p > 1e-3, f"narrowed loop not uniform under a predicate (p={p})"
+    monkeypatch.setattr(jax_backend, "survivor_width", lambda b, share: b)
+    b = _q5_engine(q5_cycle_rejecting, round_batch=16384).sample(n)
+    got = narrow.piece_stats_dict()
+    assert all(s["residual_overflow"] == 0 for s in got.values()), got
+    assert a.stats.dropped_slots == 0 and a.stats.pred_rejects > 0
+    assert a.stats.as_dict() == b.stats.as_dict()
+    assert np.array_equal(a.matrix(), b.matrix())
+    assert np.array_equal(a.home, b.home)
+
+
+def test_record_mode_draws_and_reports_full_width(q5_cycle):
+    """The record engine's round draws full rows, so its survivor widths,
+    and the gauge that publishes them, are its draw widths."""
+    from repro import obs
+    from repro.core.backends import get_backend
+    from repro.core.backends.jax_backend import JaxRecordUnionSampler
+    _u, specs, cover, _ = q5_cycle
+    reg = obs.MetricsRegistry()
+    prev = obs.set_registry(reg)
+    obs.set_enabled(True)
+    try:
+        eng = JaxRecordUnionSampler(get_backend("jax", Catalog(), specs,
+                                                seed=2),
+                                    cover, seed=4, round_batch=1024)
+        assert len(eng.sample(200)) == 200
+        snap = reg.snapshot()
+    finally:
+        obs.set_enabled(None)
+        obs.set_registry(prev)
+    assert eng.survivor_widths == eng.piece_batches
+    widths = snap["repro_engine_piece_survivor_width"]["series"]
+    assert sorted(widths.values()) == sorted(eng.piece_batches)
+    assert sum(snap["repro_engine_piece_residual_overflow_total"]
+               ["series"].values()) == 0
+
+
+def test_acyclic_pieces_keep_their_width_and_their_stream():
+    """Chain pieces are never narrowed, and their stream on a fixed seed is
+    the one the loop gave before narrowing existed (SHA-256 of the samples
+    and homes, pinned from that program on this CPU path)."""
+    import hashlib
+    from repro.core.backends import get_backend
+    from repro.core.backends.jax_backend import JaxUnionSampler
+    R, S, T = tiny_db(0)
+    keep = np.arange(R.nrows) % 3 != 0
+    R2 = Relation("R", {a: np.concatenate(
+        [c[keep], c[~keep] + (100 if a == "rid" else 0)])
+        for a, c in R.columns.items()})
+    joins = [chain_join("A", [R, S, T], ["b", "c"]),
+             chain_join("B", [R2, S, T], ["b", "c"])]
+    cat = Catalog()
+    cover = estimate_union(warmup(cat, joins, method="exact").oracle).cover
+    for mode in ("device", "host"):
+        eng = JaxUnionSampler(get_backend("jax", cat, joins, seed=2), cover,
+                              seed=31, round_batch=512, fused_rounds=mode)
+        assert eng.survivor_widths == eng.piece_batches == (512, 256)
+        ss = eng.sample(3000)
+        digest = hashlib.sha256(
+            np.ascontiguousarray(ss.matrix()).tobytes()
+            + ss.home.astype(np.int64).tobytes()).hexdigest()
+        assert digest == ("648af2720e5561f25c9a82a7dd0d2da04f350bdb6e6fd0454"
+                          "ef9c9f7158e456b"), mode
+        assert eng.piece_stats.tolist() == [[5120, 5120, 0, 0, 0, 1113, 2874],
+                                            [2560, 827, 0, 0, 0, 0, 73]]
