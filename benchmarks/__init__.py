@@ -1,1 +1,1 @@
-"""Benchmark harness: one module per paper figure/table + roofline reader."""
+"""CPU benchmark harness: one module per paper figure or table."""

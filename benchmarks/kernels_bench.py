@@ -1,15 +1,15 @@
-"""Kernel micro-benchmarks (interpret-mode functional timing + op census).
+"""Kernel micro-benchmarks: interpret-mode functional timing of the probe
+and the fused walk hop.
 
-Wall-clock on CPU interpret mode is NOT a TPU number — rows report the
-per-call operation counts that the §Roofline kernel story uses (compares the
-fused hop against its unfused two-searchsorted + pick decomposition).
+Wall-clock on CPU interpret mode is NOT a TPU number; the chip benchmark
+(`bench/`) measures the kernels' time and roofline share on the device.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from repro.kernels import ops, ref
+from repro.kernels import ops
 
 from .common import emit, timed
 
@@ -26,14 +26,6 @@ def main(small: bool = True) -> None:
     emit("kernel_searchsorted", t * 1e6, f"nk={nk};nq={nq}")
     t = timed(lambda: ops.walk_hop(keys, qs, u), repeats=3)
     emit("kernel_walk_hop_fused", t * 1e6, "fuses refine+pick (1 pass)")
-
-    B, H, KVH, D, S = (2, 8, 4, 128, 1024) if small else (4, 16, 8, 128, 4096)
-    q = rng.standard_normal((B, H, D)).astype(np.float32)
-    k = rng.standard_normal((B, S, KVH, D)).astype(np.float32)
-    v = rng.standard_normal((B, S, KVH, D)).astype(np.float32)
-    lens = np.full(B, S)
-    t = timed(lambda: ops.decode_attention(q, k, v, lens), repeats=2)
-    emit("kernel_decode_attention", t * 1e6, f"B{B}H{H}S{S}")
 
 
 if __name__ == "__main__":

@@ -15,7 +15,7 @@ import traceback
 
 from . import (bias_ablation, breakdown, data_scale, device_sampler,
                estimation_device, estimation_error, estimation_runtime,
-               kernels_bench, reuse, roofline, sampling_scaling,
+               kernels_bench, reuse, sampling_scaling,
                sharded_scaling, union_engine)
 from .common import emit, header, write_json
 
@@ -32,7 +32,6 @@ MODULES = [
     ("union_engine", union_engine),             # fused union rounds (backends)
     ("sharded_scaling", sharded_scaling),       # mesh scaling (subprocess)
     ("kernels_bench", kernels_bench),           # kernel micro-bench
-    ("roofline", roofline),                     # §Roofline table
 ]
 
 

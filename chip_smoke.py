@@ -7,7 +7,7 @@
 One chip.  The main phase builds UQ1 (five chain joins
 nation ⋈ supplier ⋈ customer ⋈ orders ⋈ lineitem, default overlap 0.2) at
 TPC-H SF1 row counts (``scale=100``: 6,000,000 base lineitem rows) through
-the builder ``repro.launch.serve --mode samples`` uses (histogram warm-up,
+the builder ``repro.launch.serve`` uses (histogram warm-up,
 union estimate, ``SetUnionSampler(backend="jax")`` with its persistent
 device loop), then serves 8 requests of 4,096 samples through
 ``SampleService``.  A small phase then draws UQ1 (2 joins) and cyclic UQ4

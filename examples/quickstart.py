@@ -1,4 +1,5 @@
-"""Quickstart: sample i.i.d. tuples from a union of joins, then train on them.
+"""Quickstart: sample i.i.d. tuples from a union of joins, then batch them
+as tokens for a trainer.
 
     PYTHONPATH=src python examples/quickstart.py
 """
@@ -7,6 +8,8 @@ import numpy as np
 
 from repro.core import (JoinSampler, SetUnionSampler, estimate_union,
                         exact_union_size, warmup)
+from repro.data.encode import TokenEncoder
+from repro.data.pipeline import UnionSamplePipeline
 from repro.data.workloads import uq3
 
 
@@ -36,11 +39,11 @@ def main() -> None:
           f"{np.bincount(ss.home, minlength=len(wl.joins)).tolist()}; "
           f"cover rejects: {ss.stats.cover_rejects}")
 
-    # 4. feed an LM a few training steps from the stream
-    from repro.launch.train import main as train_main
-    train_main(["--arch", "unionlm-100m", "--smoke", "--workload", "UQ3",
-                "--steps", "20", "--batch", "4", "--seq", "128",
-                "--lr", "1e-3", "--checkpoint-dir", "/tmp/repro_quickstart"])
+    # 4. the same stream as fixed-shape token batches for a trainer
+    enc = TokenEncoder(sorted(wl.joins[0].output_attrs), vocab_size=2048)
+    pipe = UnionSamplePipeline(sampler, enc, batch=4, seq_len=128)
+    tokens, targets = pipe.next_batch()
+    print(f"token batch {tokens.shape} from {pipe.stats.tuples} tuples")
 
 
 if __name__ == "__main__":

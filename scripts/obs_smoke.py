@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Observability smoke: scrape /metrics from a short-lived serve CLI.
 
-Spawns ``python -m repro.launch.serve --mode samples`` with an ephemeral
+Spawns ``python -m repro.launch.serve`` with an ephemeral
 ``--metrics-port`` and a linger window, polls the printed URL, and asserts:
 
 * ``/healthz`` answers ``ok``;
@@ -29,7 +29,7 @@ import time
 import urllib.request
 
 SERVE_ARGS = [
-    sys.executable, "-m", "repro.launch.serve", "--mode", "samples",
+    sys.executable, "-m", "repro.launch.serve",
     "--workload", "UQ1", "--scale", "0.05", "--requests", "4",
     "--samples", "1024", "--round-batch", "1024",
     "--metrics-port", "0", "--linger", "30",

@@ -120,22 +120,3 @@ class UnionSamplePipeline:
         if rng is not None and state.get("rng_state") is not None:
             rng.bit_generator.state = state["rng_state"]
 
-
-class SyntheticPipeline:
-    """PRNG token stream with the same interface (smoke tests / dry-runs)."""
-
-    def __init__(self, vocab_size: int, batch: int, seq_len: int, seed: int = 0):
-        self.vocab_size, self.batch, self.seq_len = vocab_size, batch, seq_len
-        self.rng = np.random.default_rng(seed)
-        self.stats = PipelineStats()
-
-    def next_batch(self) -> Tuple[np.ndarray, np.ndarray]:
-        tokens = self.rng.integers(4, self.vocab_size, (self.batch, self.seq_len),
-                                   dtype=np.int64).astype(np.int32)
-        targets = np.concatenate([tokens[:, 1:], np.zeros((self.batch, 1), np.int32)], 1)
-        self.stats.batches += 1
-        return tokens, targets
-
-    def __iter__(self):
-        while True:
-            yield self.next_batch()

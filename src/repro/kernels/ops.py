@@ -12,12 +12,11 @@ lexicographic integer compare machinery applies unchanged (hi word = 0).
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import numpy as np
 
-from .attention import decode_attention_pallas
 from .searchsorted import PreparedKeys, searchsorted_pallas
 from .walk import walk_hop_pallas
 
@@ -36,13 +35,6 @@ def searchsorted(keys, queries) -> Tuple[np.ndarray, np.ndarray]:
 
 def walk_hop(keys, queries, u) -> Tuple[np.ndarray, np.ndarray]:
     return walk_hop_pallas(keys, queries, u, interpret=default_interpret())
-
-
-def decode_attention(q, k, v, lengths, scale: Optional[float] = None,
-                     softcap: float = 0.0, window: int = 0):
-    return decode_attention_pallas(q, k, v, lengths, scale=scale,
-                                   softcap=softcap, window=window,
-                                   interpret=default_interpret())
 
 
 def ranged_weighted_pick(cs: np.ndarray, lo: np.ndarray, hi: np.ndarray,

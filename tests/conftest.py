@@ -1,7 +1,7 @@
 """Shared fixtures: tiny relational databases and workloads.
 
-NOTE: no XLA_FLAGS here — tests must see 1 CPU device (the dry-run sets its
-own device count in its own process).
+NOTE: no XLA_FLAGS here — tests must see 1 CPU device (the multi-device
+tests set their own device count in their own processes).
 """
 
 import numpy as np

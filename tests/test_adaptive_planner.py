@@ -11,7 +11,8 @@ argument of the shortfall carry is untouched.  Pinned here:
 * ``plan="adaptive"`` device loop == host twin, samples *and* stats, across
   calls whose EMAs/banks carry over — unsharded and world=1 sharded;
 * chi-square uniformity of adaptive streams on UQ1 (acyclic) and UQ4
-  (cyclic), jax engine and 1-device mesh;
+  (cyclic) on the 1-device mesh (the unsharded adaptive engine is a column
+  of the engine × union matrix, ``test_conformance_device.py``);
 * ``SamplerStats.psi()`` / ``samples_emitted`` accounting and the
   per-piece draw/accept counters a waste ratio derives from;
 * the ONLINE-UNION host twin (``OnlineUnionSampler(plan="adaptive")``)
@@ -24,10 +25,10 @@ import re
 
 import numpy as np
 import pytest
-from scipy import stats as sps
 
 import jax.numpy as jnp
 
+from _conformance import chi2_p
 from repro.core import planner
 from repro.core.framework import estimate_union, warmup
 from repro.core.online import OnlineUnionSampler
@@ -46,16 +47,6 @@ def _assert_same_samples(a, b):
         np.testing.assert_array_equal(a.rows[attr], b.rows[attr])
     np.testing.assert_array_equal(a.home, b.home)
     np.testing.assert_array_equal(a.fingerprint, b.fingerprint)
-
-
-def _chi2_p(matrix, n_universe):
-    uni, counts = np.unique(
-        matrix.view([("", matrix.dtype)] * matrix.shape[1]).ravel(),
-        return_counts=True)
-    exp = matrix.shape[0] / n_universe
-    chi2 = (float(((counts - exp) ** 2 / exp).sum())
-            + (n_universe - uni.shape[0]) * exp)
-    return 1 - sps.chi2.cdf(chi2, df=n_universe - 1)
 
 
 # ---------------------------------------------------------------------------
@@ -191,17 +182,7 @@ def _uniform_p(wl, mesh=None, n_per_cell=120, rb=1024):
                         round_batch=rb, mesh=mesh, fused_rounds="device",
                         plan="adaptive")
     ss = s.sample(n_per_cell * U)
-    return _chi2_p(ss.matrix(), U)
-
-
-def test_adaptive_uniform_uq1():
-    p = _uniform_p(uq1(scale=0.02, overlap=0.5, seed=1, n_joins=2))
-    assert p > 1e-3, p
-
-
-def test_adaptive_uniform_uq4_cyclic():
-    p = _uniform_p(uq4(scale=0.01, seed=0))
-    assert p > 1e-3, p
+    return chi2_p(ss.matrix(), U)
 
 
 def test_adaptive_uniform_uq1_sharded():

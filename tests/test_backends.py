@@ -4,12 +4,14 @@ Covers the acceptance criteria of the backend refactor: the jax backend's
 candidate sources, membership oracle, and fused Algorithm-1 rounds must be
 distributionally equivalent to the numpy reference on TPC-H-style union
 workloads (chains, high-overlap predicate unions, and a branching tree).
+The fused rounds' uniformity on every union is the engine × union matrix
+(``test_conformance_*``).
 """
 
 import numpy as np
 import pytest
-from scipy import stats as sps
 
+from _conformance import chi2_p
 from conftest import tiny_db
 
 from repro.core.backends import NumpyBackend, get_backend
@@ -20,9 +22,8 @@ from repro.core.backends.jax_backend import (DeviceJoinMembership,
 from repro.core.framework import estimate_union, warmup
 from repro.core.index import Catalog
 from repro.core.joins import JoinNode, JoinSpec, chain_join, full_join_matrix
-from repro.core.overlap import exact_union_size
 from repro.core.union_sampler import SetUnionSampler
-from repro.data.workloads import uq1, uq2, uq3, uq4
+from repro.data.workloads import uq1, uq3, uq4
 
 
 def _tree_spec(seed=0):
@@ -35,31 +36,6 @@ def _tree_spec(seed=0):
         JoinNode("S", S, "R", ("b",)),
         JoinNode("T", T, "R", ("b",)),
     ])
-
-
-def _chi2_vs_expected(sample_matrix, expected_matrix):
-    """Chi-square of sampled tuple counts against the exact multiplicity law."""
-    def keyed(m):
-        return m.view([("", m.dtype)] * m.shape[1]).ravel()
-    uni, exp_counts = np.unique(keyed(expected_matrix), return_counts=True)
-    s_uni, s_counts = np.unique(keyed(sample_matrix), return_counts=True)
-    assert np.isin(s_uni, uni).all(), "sampled a tuple outside the join"
-    counts = np.zeros(uni.shape[0])
-    counts[np.searchsorted(uni, s_uni)] = s_counts
-    N = sample_matrix.shape[0]
-    exp = N * exp_counts / exp_counts.sum()
-    chi2 = float(((counts - exp) ** 2 / exp).sum())
-    return 1 - sps.chi2.cdf(chi2, df=uni.shape[0] - 1)
-
-
-def _chi2_uniform(sample_matrix, n_universe):
-    uni, counts = np.unique(
-        sample_matrix.view([("", sample_matrix.dtype)] * sample_matrix.shape[1]).ravel(),
-        return_counts=True)
-    N = sample_matrix.shape[0]
-    exp = N / n_universe
-    chi2 = float(((counts - exp) ** 2 / exp).sum()) + (n_universe - uni.shape[0]) * exp
-    return 1 - sps.chi2.cdf(chi2, df=n_universe - 1)
 
 
 # ---------------------------------------------------------------------------
@@ -95,7 +71,7 @@ def test_jax_tree_source_distribution():
     rows, draws = src.draw(np.random.default_rng(0), 40_000)
     assert draws >= 40_000
     got = np.stack([rows[a] for a in spec.output_attrs], axis=1)
-    p = _chi2_vs_expected(got, mat)
+    p = chi2_p(got, mat)
     assert p > 1e-3, f"device tree sampler distribution off (p={p})"
 
 
@@ -213,28 +189,6 @@ def test_device_membership_fp_duplicate_window():
 # ---------------------------------------------------------------------------
 # fused Algorithm-1 rounds: jax == numpy distribution on union workloads
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("wl_fn,kw", [
-    (uq1, dict(scale=0.05, overlap=0.5, seed=1, n_joins=2)),   # chains
-    (uq2, dict(scale=0.02, seed=0)),                           # high overlap
-    (uq2, dict(scale=0.02, seed=0, pred_mode="rejection")),    # §8.3 in-round
-    (uq3, dict(scale=0.01, overlap=0.3, seed=0)),              # tree join
-    (uq4, dict(scale=0.02, seed=0)),                           # cyclic (§8.2)
-], ids=["uq1-chains", "uq2-overlap", "uq2-rejection", "uq3-tree",
-        "uq4-cyclic"])
-def test_set_union_jax_uniform(wl_fn, kw):
-    wl = wl_fn(**kw)
-    wr = warmup(wl.cat, wl.joins, method="exact")
-    est = estimate_union(wr.oracle)
-    U = exact_union_size(wl.cat, wl.joins)
-    s = SetUnionSampler(wl.cat, wl.joins, est.cover, seed=7, backend="jax",
-                        round_batch=2048)
-    N = 120 * U
-    ss = s.sample(N)
-    assert len(ss) == N
-    p = _chi2_uniform(ss.matrix(), U)
-    assert p > 1e-3, f"device Algorithm-1 not uniform on {wl.name} (p={p})"
 
 
 def test_set_union_jax_matches_numpy_home_marginal():

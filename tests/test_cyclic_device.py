@@ -2,7 +2,8 @@
 
 Acceptance bar of the cyclic tentpole: the device engine must run cyclic
 joins end-to-end with host-identical uniformity — chi-square against the
-exact universe on the UQ4 workload for both engines, the residual d/M
+exact universe on the UQ4 workload for every engine (the engine × union
+matrix, ``test_conformance_*``), the residual d/M
 accept/reject decision bit-equal to the host reference on a shared
 (injected-uniform) trace, residual-rejection accounting present in
 ``SamplerStats`` on both engines, and a 1-device mesh reproducing the
@@ -11,8 +12,8 @@ unsharded fused engine bit for bit on the cyclic union.
 
 import numpy as np
 import pytest
-from scipy import stats as sps
 
+from _conformance import chi2_p
 from conftest import brute_force_join, tiny_db
 
 from repro.core.backends import NumpyBackend
@@ -52,32 +53,6 @@ def _cyclic_spec(seed=0, n_q=40):
     return Catalog(), spec
 
 
-def _chi2_vs_expected(sample_matrix, expected_matrix):
-    def keyed(m):
-        return m.view([("", m.dtype)] * m.shape[1]).ravel()
-    uni, exp_counts = np.unique(keyed(expected_matrix), return_counts=True)
-    s_uni, s_counts = np.unique(keyed(sample_matrix), return_counts=True)
-    assert np.isin(s_uni, uni).all(), "sampled a tuple outside the join"
-    counts = np.zeros(uni.shape[0])
-    counts[np.searchsorted(uni, s_uni)] = s_counts
-    N = sample_matrix.shape[0]
-    exp = N * exp_counts / exp_counts.sum()
-    chi2 = float(((counts - exp) ** 2 / exp).sum())
-    return 1 - sps.chi2.cdf(chi2, df=uni.shape[0] - 1)
-
-
-def _chi2_uniform(sample_matrix, n_universe):
-    uni, counts = np.unique(
-        sample_matrix.view([("", sample_matrix.dtype)] *
-                           sample_matrix.shape[1]).ravel(),
-        return_counts=True)
-    N = sample_matrix.shape[0]
-    exp = N / n_universe
-    chi2 = (float(((counts - exp) ** 2 / exp).sum())
-            + (n_universe - uni.shape[0]) * exp)
-    return 1 - sps.chi2.cdf(chi2, df=n_universe - 1)
-
-
 # ---------------------------------------------------------------------------
 # single cyclic join: device draws follow the exact multiplicity law
 # ---------------------------------------------------------------------------
@@ -95,7 +70,7 @@ def test_device_cyclic_source_distribution():
     rows, draws = src.draw(np.random.default_rng(0), 30_000)
     assert draws > 30_000            # residual rejection costs extra draws
     got = np.stack([rows[a] for a in attrs], axis=1)
-    p = _chi2_vs_expected(got, mat)
+    p = chi2_p(got, mat)
     assert p > 1e-3, f"device cyclic sampler distribution off (p={p})"
     assert src.pop_residual_rejects() > 0
     assert src.pop_residual_rejects() == 0        # drained
@@ -111,7 +86,7 @@ def test_device_cyclic_source_matches_host_distribution():
     be = NumpyBackend(cat, [spec])
     rows, _ = be.source(spec.name).draw(np.random.default_rng(1), 30_000)
     got = np.stack([rows[a] for a in attrs], axis=1)
-    p = _chi2_vs_expected(got, mat)
+    p = chi2_p(got, mat)
     assert p > 1e-3, f"host cyclic sampler distribution off (p={p})"
 
 
@@ -270,7 +245,8 @@ def test_residual_kills_read_zero_on_an_acyclic_join():
 
 
 # ---------------------------------------------------------------------------
-# UQ4 end-to-end: device == host uniformity; 1-device mesh bit-for-bit
+# UQ4 end-to-end: 1-device mesh bit-for-bit (UQ4's uniformity on every
+# engine is the engine × union matrix, ``test_conformance_*``)
 # ---------------------------------------------------------------------------
 
 
@@ -280,18 +256,6 @@ def uq4_setup():
     est = estimate_union(warmup(wl.cat, wl.joins, method="exact").oracle)
     U = exact_union_size(wl.cat, wl.joins)
     return wl, est, U
-
-
-def test_uq4_device_vs_host_uniformity(uq4_setup):
-    wl, est, U = uq4_setup
-    N = 120 * U
-    for backend in ("numpy", "jax"):
-        s = SetUnionSampler(wl.cat, wl.joins, est.cover, seed=7,
-                            backend=backend, round_batch=2048)
-        ss = s.sample(N)
-        assert len(ss) == N
-        p = _chi2_uniform(ss.matrix(), U)
-        assert p > 1e-3, f"{backend} not uniform on UQ4 (p={p})"
 
 
 def test_uq4_one_shard_mesh_bitwise_equals_jax_engine(uq4_setup):
@@ -404,14 +368,9 @@ def _q5_uniform_p(q5, ss):
     assert inside[np.arange(home.shape[0]), home].all()
     for j in range(inside.shape[1]):
         assert not inside[home == j][:, :j].any(), "row outside its home"
-    tuples = list(map(tuple, np.stack(ids, axis=1).tolist()))
-    assert set(tuples) <= union
-    counts = np.zeros(len(union))
-    at = {t: i for i, t in enumerate(sorted(union))}
-    np.add.at(counts, [at[t] for t in tuples], 1)
-    exp = len(tuples) / len(union)
-    chi2 = float(((counts - exp) ** 2 / exp).sum())
-    return 1 - sps.chi2.cdf(chi2, df=len(union) - 1)
+    tuples = np.stack(ids, axis=1)
+    assert set(map(tuple, tuples.tolist())) <= union
+    return chi2_p(tuples, len(union))
 
 
 _COUNTED = ("draws", "residual_rejects", "residual_kills", "accepts")

@@ -1,4 +1,4 @@
-"""Persistent device-resident round loop: parity, banking, pipelined serve.
+"""Persistent device-resident round loop: parity and banking.
 
 Acceptance bar of the perf tentpole: moving the whole multi-round
 Algorithm-1 loop into one jitted ``lax.while_loop`` (device-resident
@@ -9,24 +9,22 @@ replaces.  Pinned here:
 * device loop vs host loop — bit-equal rows/home/fingerprint *and* identical
   ``SamplerStats`` across multiple calls whose surplus banks carry over;
 * FIFO-bank equivalence with a tiny ring capacity (wrap-around exercised);
-* chi-square uniformity of UQ1 and cyclic UQ4 streams served through the
-  pipelined ``SampleService`` (``sample_async`` dispatch-then-drain);
 * a 1-device sharded pin: the in-loop fingerprint exchange (collectives
   inside the device loop) matches the between-round exchange of the host
   mode, and both match the unsharded engine.
+
+The served stream's uniformity is the ``served`` column of the engine ×
+union matrix (``test_conformance_mesh_served.py``).
 """
 
 import numpy as np
 from _hypothesis_compat import given, settings, st
-from scipy import stats as sps
 
 from repro.core.backends import get_backend
 from repro.core.backends.jax_backend import JaxUnionSampler
 from repro.core.framework import estimate_union, warmup
-from repro.core.overlap import exact_union_size
 from repro.core.union_sampler import SetUnionSampler
-from repro.data.workloads import uq1, uq4
-from repro.serve.service import SampleService
+from repro.data.workloads import uq1
 
 
 def _cover(wl):
@@ -39,16 +37,6 @@ def _assert_same_samples(a, b):
         np.testing.assert_array_equal(a.rows[attr], b.rows[attr])
     np.testing.assert_array_equal(a.home, b.home)
     np.testing.assert_array_equal(a.fingerprint, b.fingerprint)
-
-
-def _chi2_p(matrix, n_universe):
-    uni, counts = np.unique(
-        matrix.view([("", matrix.dtype)] * matrix.shape[1]).ravel(),
-        return_counts=True)
-    exp = matrix.shape[0] / n_universe
-    chi2 = (float(((counts - exp) ** 2 / exp).sum())
-            + (n_universe - uni.shape[0]) * exp)
-    return 1 - sps.chi2.cdf(chi2, df=n_universe - 1)
 
 
 # ---------------------------------------------------------------------------
@@ -131,32 +119,6 @@ def test_ring_bank_cap_wrap_property(ns):
         for n in ns:
             _assert_same_samples(dev.sample(n), host.sample(n))
         assert dev.stats.as_dict() == host.stats.as_dict()
-
-
-# ---------------------------------------------------------------------------
-# pipelined serve path stays exactly uniform
-# ---------------------------------------------------------------------------
-
-
-def _serve_uniform(wl, n_per_cell=120):
-    cover = _cover(wl)
-    U = exact_union_size(wl.cat, wl.joins)
-    s = SetUnionSampler(wl.cat, wl.joins, cover, seed=13, backend="jax",
-                        round_batch=1024, fused_rounds="device")
-    assert callable(getattr(s, "sample_async", None))  # pipelined path taken
-    with SampleService(s, batch=2048, prefetch=2) as svc:
-        ss = svc.request(n_per_cell * U)
-    assert len(ss) == n_per_cell * U
-    p = _chi2_p(ss.matrix(), U)
-    assert p > 1e-3, p
-
-
-def test_pipelined_serve_uniform_uq1():
-    _serve_uniform(uq1(scale=0.02, overlap=0.5, seed=1, n_joins=2))
-
-
-def test_pipelined_serve_uniform_uq4_cyclic():
-    _serve_uniform(uq4(scale=0.01, seed=0))
 
 
 # ---------------------------------------------------------------------------

@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 from scipy import stats as sps
 
+from _conformance import chi2_p
 from conftest import tiny_db
 
 from repro.core.index import Catalog
@@ -32,12 +33,8 @@ def test_jax_sampler_uniform_chi2():
     N = 60 * n_tuples
     rows = js.sample_uniform(N, batch=4096)
     got = np.stack([rows[a] for a in spec.output_attrs], axis=1)
-    uni, counts = np.unique(got.view([("", got.dtype)] * got.shape[1]).ravel(),
-                            return_counts=True)
-    assert uni.shape[0] == n_tuples
-    exp = N / n_tuples
-    chi2 = float(((counts - exp) ** 2 / exp).sum())
-    p = 1 - sps.chi2.cdf(chi2, df=n_tuples - 1)
+    assert np.unique(got, axis=0).shape[0] == n_tuples
+    p = chi2_p(got, n_tuples)
     assert p > 1e-3, f"jitted sampler not uniform (p={p})"
 
 

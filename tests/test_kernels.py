@@ -4,7 +4,6 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-import jax.numpy as jnp
 
 from repro.kernels import ops, ref
 from repro.kernels.searchsorted import (FENCE_CHUNK, KEY_BLOCK,
@@ -158,40 +157,3 @@ def test_ranged_weighted_pick_distribution():
     freq = np.bincount(pos, minlength=5) / N
     np.testing.assert_allclose(freq, w / w.sum(), atol=0.02)
 
-
-# ---------------------------------------------------------------------------
-# decode attention
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("B,H,KVH,D,S,cap,win", [
-    (2, 8, 4, 128, 384, 0.0, 0),
-    (1, 16, 8, 128, 256, 50.0, 0),
-    (2, 4, 1, 128, 512, 0.0, 128),
-    (1, 8, 8, 64, 256, 30.0, 64),
-    (3, 4, 2, 64, 130, 0.0, 0),     # unaligned S -> padding path
-])
-def test_decode_attention_allclose(B, H, KVH, D, S, cap, win):
-    rng = np.random.default_rng(B * 1000 + S)
-    q = rng.standard_normal((B, H, D)).astype(np.float32)
-    k = rng.standard_normal((B, S, KVH, D)).astype(np.float32)
-    v = rng.standard_normal((B, S, KVH, D)).astype(np.float32)
-    lens = rng.integers(max(S // 2, 1), S + 1, B)
-    out = ops.decode_attention(q, k, v, lens, softcap=cap, window=win)
-    want = ref.decode_attention_ref(q, k, v, lens, softcap=cap, window=win)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_decode_attention_bf16():
-    rng = np.random.default_rng(5)
-    B, H, KVH, D, S = 2, 8, 4, 128, 256
-    q = rng.standard_normal((B, H, D)).astype(jnp.bfloat16)
-    k = rng.standard_normal((B, S, KVH, D)).astype(jnp.bfloat16)
-    v = rng.standard_normal((B, S, KVH, D)).astype(jnp.bfloat16)
-    lens = np.full(B, S)
-    out = np.asarray(ops.decode_attention(q, k, v, lens), dtype=np.float32)
-    want = np.asarray(ref.decode_attention_ref(
-        np.asarray(q, np.float32), np.asarray(k, np.float32),
-        np.asarray(v, np.float32), lens))
-    np.testing.assert_allclose(out, want, rtol=5e-2, atol=5e-2)

@@ -3,8 +3,8 @@
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
-from scipy import stats as sps
 
+from _conformance import chi2_p
 from conftest import brute_force_join, tiny_db
 
 from repro.core.index import Catalog
@@ -45,12 +45,8 @@ def test_ew_sampling_uniform_chi2():
     # batch's granularity)
     assert draws <= N + 4096
     got = np.stack([rows[a] for a in spec.output_attrs], axis=1)
-    uni, counts = np.unique(got.view([("", got.dtype)] * got.shape[1]).ravel(),
-                            return_counts=True)
-    assert uni.shape[0] == n_tuples
-    exp = N / n_tuples
-    chi2 = float(((counts - exp) ** 2 / exp).sum())
-    p = 1 - sps.chi2.cdf(chi2, df=n_tuples - 1)
+    assert np.unique(got, axis=0).shape[0] == n_tuples
+    p = chi2_p(got, n_tuples)
     assert p > 1e-3, f"EW sampling not uniform (p={p})"
 
 
@@ -64,11 +60,7 @@ def test_eo_sampling_uniform_chi2():
     rows, draws = s.sample_uniform(rng, N, batch=4096)
     assert draws > N  # EO rejects
     got = np.stack([rows[a] for a in spec.output_attrs], axis=1)
-    uni, counts = np.unique(got.view([("", got.dtype)] * got.shape[1]).ravel(),
-                            return_counts=True)
-    exp = N / n_tuples
-    chi2 = float(((counts - exp) ** 2 / exp).sum()) + (n_tuples - uni.shape[0]) * exp
-    p = 1 - sps.chi2.cdf(chi2, df=n_tuples - 1)
+    p = chi2_p(got, n_tuples)
     assert p > 1e-3, f"EO sampling not uniform (p={p})"
 
 
@@ -128,11 +120,7 @@ def test_cyclic_sampling_uniform():
     N = 60 * n_tuples
     rows, draws = s.sample_uniform(rng, N, batch=8192)
     got = np.stack([rows[a] for a in spec.output_attrs], axis=1)
-    uni, counts = np.unique(got.view([("", got.dtype)] * got.shape[1]).ravel(),
-                            return_counts=True)
-    exp = N / n_tuples
-    chi2 = float(((counts - exp) ** 2 / exp).sum()) + (n_tuples - uni.shape[0]) * exp
-    p = 1 - sps.chi2.cdf(chi2, df=n_tuples - 1)
+    p = chi2_p(got, n_tuples)
     assert p > 1e-3, f"cyclic sampling not uniform (p={p})"
 
 

@@ -18,8 +18,8 @@ import threading
 
 import numpy as np
 import pytest
-from scipy import stats as sps
 
+from _conformance import chi2_p
 from repro.core.distributed import (DistributedUnionSampler, merge_statistics,
                                     merge_streams, partition_of)
 from repro.core.framework import estimate_union, warmup
@@ -28,12 +28,13 @@ from repro.core.size_estimation import RunningMean
 from repro.core.union_sampler import SamplerStats, SetUnionSampler
 from repro.data.workloads import uq1, uq3
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+TESTS = os.path.dirname(os.path.abspath(__file__))
+SRC = os.path.join(TESTS, "..", "src")
 
 
 def _run_sub(code: str, devices: int = 4, timeout: int = 600) -> str:
     env = dict(os.environ)
-    env["PYTHONPATH"] = SRC
+    env["PYTHONPATH"] = os.pathsep.join([SRC, TESTS])     # _conformance
     # drop any inherited device-count flag (e.g. from the sharded-smoke CI
     # job) so the subprocess sees exactly one
     kept = [f for f in env.get("XLA_FLAGS", "").split()
@@ -44,18 +45,6 @@ def _run_sub(code: str, devices: int = 4, timeout: int = 600) -> str:
                        text=True, env=env, timeout=timeout)
     assert r.returncode == 0, r.stderr[-3000:]
     return r.stdout
-
-
-def _chi2_uniform(sample_matrix, n_universe):
-    uni, counts = np.unique(
-        sample_matrix.view([("", sample_matrix.dtype)] *
-                           sample_matrix.shape[1]).ravel(),
-        return_counts=True)
-    N = sample_matrix.shape[0]
-    exp = N / n_universe
-    chi2 = (float(((counts - exp) ** 2 / exp).sum())
-            + (n_universe - uni.shape[0]) * exp)
-    return 1 - sps.chi2.cdf(chi2, df=n_universe - 1)
 
 
 # ---------------------------------------------------------------------------
@@ -195,7 +184,7 @@ def test_seed_split_vs_hash_partition_uniformity():
             # per-rank streams are partition-pure
             for rank, p in enumerate(parts):
                 assert (partition_of(p.fingerprint, world) == rank).all()
-        p_val = _chi2_uniform(merged.matrix(), U)
+        p_val = chi2_p(merged.matrix(), U)
         assert p_val > 1e-3, f"{scheme} union stream not uniform (p={p_val})"
 
 
@@ -266,7 +255,7 @@ print("OK")
 def test_multi_shard_uniform_and_matches_host_marginal():
     out = _run_sub(r"""
 import numpy as np
-from scipy import stats as sps
+from _conformance import chi2_p
 from repro.core.framework import estimate_union, warmup
 from repro.core.overlap import exact_union_size
 from repro.core.sharding import ShardedCatalog, make_sampler_mesh
@@ -282,12 +271,7 @@ s = SetUnionSampler(wl.cat, wl.joins, est.cover, seed=11, backend="jax",
 N = 120 * U
 ss = s.sample(N)
 assert len(ss) == N
-m = ss.matrix()
-uni, counts = np.unique(m.view([("", m.dtype)] * m.shape[1]).ravel(),
-                        return_counts=True)
-exp = N / U
-chi2 = float(((counts - exp) ** 2 / exp).sum()) + (U - uni.shape[0]) * exp
-p = 1 - sps.chi2.cdf(chi2, df=U - 1)
+p = chi2_p(ss.matrix(), U)
 assert p > 1e-3, p
 
 host = SetUnionSampler(wl.cat, wl.joins, est.cover, seed=3).sample(8000)
@@ -314,7 +298,7 @@ def test_multi_shard_cyclic_union_uniform():
     fingerprint exchange, and the union stream stays exactly uniform."""
     out = _run_sub(r"""
 import numpy as np
-from scipy import stats as sps
+from _conformance import chi2_p
 from repro.core.framework import estimate_union, warmup
 from repro.core.overlap import exact_union_size
 from repro.core.sharding import make_sampler_mesh
@@ -330,12 +314,7 @@ s = SetUnionSampler(wl.cat, wl.joins, est.cover, seed=11, backend="jax",
 N = 120 * U
 ss = s.sample(N)
 assert len(ss) == N
-m = ss.matrix()
-uni, counts = np.unique(m.view([("", m.dtype)] * m.shape[1]).ravel(),
-                        return_counts=True)
-exp = N / U
-chi2 = float(((counts - exp) ** 2 / exp).sum()) + (U - uni.shape[0]) * exp
-p = 1 - sps.chi2.cdf(chi2, df=U - 1)
+p = chi2_p(ss.matrix(), U)
 assert p > 1e-3, p
 print("OK")
 """, devices=4, timeout=900)

@@ -2,8 +2,8 @@
 
 import numpy as np
 import pytest
+from _conformance import chi2_p
 from _hypothesis_compat import given, settings, st
-from scipy import stats as sps
 
 from repro.core.cover import build_cover
 from repro.core.framework import estimate_union, warmup
@@ -87,36 +87,15 @@ def test_cover_partition_identity(seed, n_sets):
 
 
 # ---------------------------------------------------------------------------
-# Algorithm 1 (uniformity; probe mode exact, record mode converging)
+# Algorithm 1 (uniformity; probe mode exact, record mode converging).  The
+# probe engine's uniformity on every union is the engine × union matrix
+# (``test_conformance_host.py``).
 # ---------------------------------------------------------------------------
 
 
 @pytest.fixture(scope="module")
 def wl3():
     return uq3(scale=0.01, overlap=0.3, seed=0)
-
-
-def _chi2_uniform(sample_matrix, n_universe):
-    uni, counts = np.unique(
-        sample_matrix.view([("", sample_matrix.dtype)] * sample_matrix.shape[1]).ravel(),
-        return_counts=True)
-    N = sample_matrix.shape[0]
-    exp = N / n_universe
-    chi2 = float(((counts - exp) ** 2 / exp).sum()) + (n_universe - uni.shape[0]) * exp
-    return 1 - sps.chi2.cdf(chi2, df=n_universe - 1)
-
-
-def test_setunion_probe_uniform(wl3):
-    cat, joins = wl3.cat, wl3.joins
-    wr = warmup(cat, joins, method="exact")
-    est = estimate_union(wr.oracle)
-    U = exact_union_size(cat, joins)
-    assert est.union_size_cover == pytest.approx(U)
-    assert est.union_size_eq1 == pytest.approx(U)
-    s = SetUnionSampler(cat, joins, est.cover, membership="probe", seed=7)
-    ss = s.sample(120 * U)
-    p = _chi2_uniform(ss.matrix(), U)
-    assert p > 1e-3, f"Algorithm 1 (probe) not uniform: p={p}"
 
 
 def test_setunion_record_mode_converges(wl3):
@@ -127,7 +106,7 @@ def test_setunion_record_mode_converges(wl3):
     s = SetUnionSampler(cat, joins, est.cover, membership="record", seed=8)
     ss = s.sample(60 * U)
     # record mode discovers the cover lazily; allow a looser bar
-    p = _chi2_uniform(ss.matrix(), U)
+    p = chi2_p(ss.matrix(), U)
     assert p > 1e-5, f"record mode wildly non-uniform: p={p}"
     assert ss.stats.revisions >= 0
 
@@ -139,7 +118,7 @@ def test_bernoulli_union_uniform(wl3):
     U = exact_union_size(cat, joins)
     s = BernoulliUnionSampler(cat, joins, sizes, float(U), seed=9)
     ss = s.sample(80 * U)
-    p = _chi2_uniform(ss.matrix(), U)
+    p = chi2_p(ss.matrix(), U)
     assert p > 1e-3, f"Bernoulli union sampler not uniform: p={p}"
     assert ss.stats.canonical_rejects > 0
 
@@ -214,7 +193,7 @@ def test_rejection_mode_predicate(wl3):
                         predicate=RejectingPredicate(preds))
     ss = s.sample(60 * U_f)
     assert (ss.rows["odate"] <= 1500).all()
-    p = _chi2_uniform(ss.matrix(), U_f)
+    p = chi2_p(ss.matrix(), U_f)
     assert p > 1e-3, f"rejection-mode predicate sampling not uniform: p={p}"
 
 
