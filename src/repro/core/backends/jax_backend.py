@@ -22,8 +22,10 @@ Three layers, bottom-up:
   sorted-row-fingerprint lookups resident on device: per base relation, rows
   are indexed by a 32-bit primary fingerprint (sorted) with a 32-bit
   secondary for verification (64 bits total; the host oracle uses 128 — see
-  DESIGN.md for the collision budget).  A probe is one ``searchsorted`` per
-  relation plus a ``kmax``-wide duplicate window check, AND-reduced.
+  DESIGN.md for the collision budget).  A probe is, per relation, one
+  Pallas fence search of the 64-bit pair key on TPU (``use_pallas``), or a
+  ``jnp.searchsorted`` plus a ``kmax``-wide duplicate window check on CPU;
+  AND-reduced.
 * :class:`JaxUnionSampler` — runs the *entire multi-round* Algorithm-1 loop
   as one device-resident jitted program: a ``lax.while_loop`` over fused
   rounds (multinomial cover selection, candidate generation for all joins,
@@ -60,7 +62,7 @@ import dataclasses
 import functools
 import math
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -673,16 +675,70 @@ class DeviceTreeJoin:
 # ---------------------------------------------------------------------------
 
 
+class _MemberIndex(NamedTuple):
+    """One base relation's membership index."""
+    relation: str
+    attrs: Tuple[str, ...]      # probed columns, sorted
+    probe: object               # see :func:`_fp_index`
+    kmax: int                   # fp1 duplicate window (jnp path only)
+    n: int                      # rows
+
+
+def _fp_index(fp1: np.ndarray, fp2: np.ndarray, use_pallas: bool
+              ) -> Tuple[object, int]:
+    """``(probe, kmax)`` of a relation's row fingerprints.  The jnp path
+    keeps ``(sorted fp1, fp2 in fp1 order)`` on device and the size of the
+    largest run of equal ``fp1``; the Pallas path keeps the sorted pair key
+    ``(fp1, fp2)`` as :class:`~repro.kernels.searchsorted.PreparedKeys`."""
+    if use_pallas:
+        from ...kernels.searchsorted import PreparedKeys, pair_keys_np
+        return PreparedKeys(np.sort(pair_keys_np(fp1, fp2))), 0
+    order = np.argsort(fp1, kind="stable")
+    s1 = fp1[order]
+    kmax = int(np.unique(s1, return_counts=True)[1].max()) if s1.size else 0
+    return (jnp.asarray(s1), jnp.asarray(fp2[order])), kmax
+
+
+# analysis: traced
+def _fp_contains(arrays, kmax: int, n: int, q1: jnp.ndarray,
+                 q2: jnp.ndarray, use_pallas: bool) -> jnp.ndarray:
+    """Whether some row of a non-empty index (device arrays ``arrays`` of
+    :func:`_fp_index`'s probe) has fingerprints ``(q1, q2)``, per query.
+    The Pallas path probes the pair key once; the jnp path binary-searches
+    ``fp1`` and checks ``fp2`` over a ``kmax``-wide window."""
+    b = q1.shape[0]
+    if use_pallas:
+        from ...kernels.ops import default_interpret
+        from ...kernels.searchsorted import _member_probe_i32, pair_tiles
+        hit = _member_probe_i32(*pair_tiles(q1, q2), *arrays, n=n,
+                                interpret=default_interpret())
+        return hit.reshape(-1)[:b]
+    s1, s2 = arrays
+    lo = jnp.searchsorted(s1, q1, side="left")
+    m = jnp.zeros((b,), bool)
+    for k in range(kmax):  # duplicate window (kmax is tiny, static)
+        pos = jnp.minimum(lo + k, n - 1)
+        m = m | ((lo + k < n) & (s1[pos] == q1) & (s2[pos] == q2))
+    return m
+
+
 class DeviceJoinMembership:
     """Batched 'is tuple in join J' probes on device.
 
     Mirrors the host :class:`~repro.core.membership.MembershipProber`
     semantics: a tuple is in the join iff every base relation contains the
     tuple's projection onto that relation's attributes (the shared output
-    schema makes connectivity automatic).
+    schema makes connectivity automatic).  ``use_pallas`` (default: on a
+    TPU) probes each relation's pair-key index with the Pallas fence search
+    (``_member_probe_i32``); otherwise ``jnp.searchsorted`` and a duplicate
+    window.  Both give the same answers.
     """
 
-    def __init__(self, spec: JoinSpec):
+    def __init__(self, spec: JoinSpec, use_pallas: Optional[bool] = None):
+        if use_pallas is None:
+            from ...kernels.ops import on_tpu
+            use_pallas = on_tpu()
+        self.use_pallas = bool(use_pallas)
         self.join_name = spec.name
         # §8.3 rejection predicates: membership in the *filtered* join is the
         # base membership AND the predicate over the tuple's own columns
@@ -694,9 +750,7 @@ class DeviceJoinMembership:
             from ..predicates import compile_preds_jnp
             self._pred_fn = compile_preds_jnp(spec.reject_preds,
                                               spec.output_attrs)
-        # (attrs, sorted_fp1, fp2_in_fp1_order, kmax, nrows) per base relation
-        self.rels: List[Tuple[Tuple[str, ...], jnp.ndarray, jnp.ndarray,
-                              int, int]] = []
+        self.rels: List[_MemberIndex] = []
         seen = set()
         for node in spec.nodes:
             rel = node.relation
@@ -711,21 +765,25 @@ class DeviceJoinMembership:
                 _as_i32(rel.columns[a], f"{rel.name}.{a}")  # domain check
             fp1 = fp32_np([rel.columns[a] for a in attrs], salt=1)
             fp2 = fp32_np([rel.columns[a] for a in attrs], salt=2)
-            order = np.argsort(fp1, kind="stable")
-            s1 = fp1[order]
-            if s1.shape[0]:
-                _, counts = np.unique(s1, return_counts=True)
-                kmax = int(counts.max())
-            else:
-                kmax = 0
-            self.rels.append((attrs, jnp.asarray(s1), jnp.asarray(fp2[order]),
-                              kmax, int(rel.nrows)))
+            probe, kmax = _fp_index(fp1, fp2, self.use_pallas)
+            self.rels.append(_MemberIndex(rel.name, attrs, probe, kmax,
+                                          int(rel.nrows)))
+        if self.use_pallas and obs.enabled():
+            levels = obs.get_registry().gauge(
+                "repro_engine_member_probe_levels",
+                "fence levels of a membership index's Pallas probe (1 or 2)",
+                ("join", "relation"))
+            for r in self.rels:
+                levels.labels(join=self.join_name,
+                              relation=r.relation).set(r.probe.levels)
 
-    def device_arrays(self) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
-        """``(sorted fp1, fp2)`` per base relation: the probe's device state
-        as a pytree, passed into the device loops as an argument (see
+    def device_arrays(self) -> List[Tuple[jnp.ndarray, ...]]:
+        """Each base relation's probe arrays (:func:`_fp_index`;
+        ``PreparedKeys.arrays`` on the Pallas path): the probe's device
+        state as a pytree, passed into the device loops as an argument (see
         :meth:`DeviceTreeJoin.device_arrays`)."""
-        return [(s1, s2) for _attrs, s1, s2, _kmax, _n in self.rels]
+        return [r.probe.arrays() if self.use_pallas else r.probe
+                for r in self.rels]
 
     # analysis: traced
     def contains(self, rows: Dict[str, jnp.ndarray],
@@ -738,17 +796,13 @@ class DeviceJoinMembership:
         b = rows[next(iter(rows))].shape[0]
         res = (jnp.ones((b,), bool) if self._pred_fn is None
                else self._pred_fn(rows))
-        for (attrs, _s1, _s2, kmax, n), (s1, s2) in zip(self.rels, arrays):
-            if n == 0:
+        for r, arr in zip(self.rels, arrays):
+            if r.n == 0:
                 return jnp.zeros((b,), bool)
-            q1 = fp32_jnp([rows[a] for a in attrs], salt=1)
-            q2 = fp32_jnp([rows[a] for a in attrs], salt=2)
-            lo = jnp.searchsorted(s1, q1, side="left")
-            m = jnp.zeros((b,), bool)
-            for k in range(kmax):  # duplicate window (kmax is tiny, static)
-                pos = jnp.minimum(lo + k, n - 1)
-                m = m | ((lo + k < n) & (s1[pos] == q1) & (s2[pos] == q2))
-            res = res & m
+            cols = [rows[a] for a in r.attrs]
+            res = res & _fp_contains(arr, r.kmax, r.n,
+                                     fp32_jnp(cols, salt=1),
+                                     fp32_jnp(cols, salt=2), self.use_pallas)
         return res
 
 
@@ -908,6 +962,7 @@ class JaxBackend(Backend):
                              "(eo/wj walks stay on the numpy backend)")
         self.cat = cat
         self.joins = list(joins)
+        self.use_pallas = use_pallas
         schemas = {tuple(sorted(j.output_attrs)) for j in self.joins}
         if len(schemas) > 1:
             raise ValueError(
@@ -953,8 +1008,9 @@ class JaxBackend(Backend):
     @property
     def members(self) -> Dict[str, DeviceJoinMembership]:
         if self._members is None:
-            self._members = {j.name: DeviceJoinMembership(j)
-                             for j in self.joins}
+            self._members = {
+                j.name: DeviceJoinMembership(j, use_pallas=self.use_pallas)
+                for j in self.joins}
         return self._members
 
     def source(self, join_name: str):

@@ -273,7 +273,8 @@ class JaxEstimator(EstimationLoop):
         # (OnlineUnionSampler shares them) — otherwise build our own
         self.members: Dict[str, DeviceJoinMembership] = (
             members if members is not None
-            else {j.name: DeviceJoinMembership(j) for j in self.joins})
+            else {j.name: DeviceJoinMembership(j, use_pallas=use_pallas)
+                  for j in self.joins})
         self._stats: Dict[FrozenSet[str], DeviceRunning] = {}
         self._size_stats: Dict[str, DeviceRunning] = {}
         self._pool = ReservoirPool(cap=pool_cap, seed=seed)

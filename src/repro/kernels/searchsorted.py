@@ -29,6 +29,12 @@ becomes a **dense-compare search over fences** (DESIGN.md §2/§6):
 * **Phase B — refine** (`refine_kernel`): one dense ``(TQ, 128)`` compare per
   tile finishes the exact position.
 
+Two jitted entry points run the same Pallas calls: ``_searchsorted_i32``,
+the range probe of the join trees, and ``_member_probe_i32``, the
+cover-membership probe of a 64-bit row-fingerprint pair key
+(:func:`pair_keys_np`, :func:`pair_tiles`).  XLA names each kernel after its
+entry point, so a trace tells the two apart.
+
 int64 keys are carried as (hi32, biased-lo32) pairs with lexicographic
 compares (TPU vector ALUs are 32-bit; the split happens host-side in numpy so
 the jitted graph is pure int32).  Padding uses +inf sentinels (INT32_MAX
@@ -228,16 +234,53 @@ def fence_blocks(q_hi3, q_lo3, t_hi2, t_lo2, f_hi2, f_lo2, n_fences: int,
                     gather_rows(f_hi2, f_lo2, s_l, s_r), interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _searchsorted_i32(q_hi3, q_lo3, t_hi2, t_lo2, f_hi2, f_lo2, keys2d_hi,
-                      keys2d_lo, interpret: bool = True):
+def probe_counts(q_hi3, q_lo3, t_hi2, t_lo2, f_hi2, f_lo2, keys2d_hi,
+                 keys2d_lo, interpret: bool):
     """``(lo, hi)`` tiles of the queries against one index's arrays
-    (:meth:`PreparedKeys.arrays`)."""
+    (:meth:`PreparedKeys.arrays`): phase A, the key-row gather, phase B."""
     blk_l, blk_r = fence_blocks(q_hi3, q_lo3, t_hi2, t_lo2, f_hi2, f_lo2,
                                 keys2d_hi.shape[0], interpret)
     return row_call(refine_kernel, q_hi3, q_lo3, blk_l, blk_r,
                     gather_rows(keys2d_hi, keys2d_lo, blk_l, blk_r),
                     interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _searchsorted_i32(q_hi3, q_lo3, t_hi2, t_lo2, f_hi2, f_lo2, keys2d_hi,
+                      keys2d_lo, interpret: bool = True):
+    """The range probe: :func:`probe_counts` as one jitted call."""
+    return probe_counts(q_hi3, q_lo3, t_hi2, t_lo2, f_hi2, f_lo2, keys2d_hi,
+                        keys2d_lo, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "interpret"))
+def _member_probe_i32(q_hi3, q_lo3, t_hi2, t_lo2, f_hi2, f_lo2, keys2d_hi,
+                      keys2d_lo, n: int, interpret: bool = True):
+    """The membership probe: per query tile lane, whether the query equals
+    one of the index's ``n`` real keys.  The same Pallas calls as the range
+    probe, under a name of their own, so a trace tells the two apart.  The
+    counts are capped at ``n``, so a query equal to the padding sentinel
+    is a member only if a real key equals it."""
+    lo, hi = probe_counts(q_hi3, q_lo3, t_hi2, t_lo2, f_hi2, f_lo2,
+                          keys2d_hi, keys2d_lo, interpret)
+    return jnp.minimum(hi, n) > jnp.minimum(lo, n)
+
+
+def pair_keys_np(hi: np.ndarray, lo: np.ndarray) -> np.ndarray:
+    """int64 keys of uint32 word pairs: ``hi`` viewed as int32 is the high
+    word and ``lo`` the low one.  Injective, and :func:`split64_np` of a key
+    gives back ``(hi as int32, lo biased)``, as :func:`pair_tiles` does."""
+    hi = np.asarray(hi, np.uint32).view(np.int32).astype(np.int64)
+    return (hi << 32) | np.asarray(lo, np.uint32).astype(np.int64)
+
+
+def pair_tiles(hi: jnp.ndarray, lo: jnp.ndarray
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Traced query tiles ``(q_hi3, q_lo3)`` of uint32 word pairs: the
+    split of :func:`pair_keys_np`'s key, without a 64-bit type."""
+    q_hi = jax.lax.bitcast_convert_type(hi, jnp.int32)
+    q_lo = jax.lax.bitcast_convert_type(lo, jnp.int32) ^ jnp.int32(-(1 << 31))
+    return to_tiles(q_hi), to_tiles(q_lo)
 
 
 class PreparedKeys:

@@ -180,10 +180,117 @@ def test_device_membership_fp_duplicate_window():
     attrs = tuple(sorted(rel.attrs))
     fp1 = fp32_np([rel.columns[a] for a in attrs], salt=1)
     # 500 rows over 16 value pairs: fp1 duplicates guaranteed
-    assert dm.rels[0][3] >= 2
+    assert dm.rels[0].kmax >= 2
     import jax, jax.numpy as jnp
     rows = {a: jnp.asarray(rel.columns[a].astype(np.int32)) for a in rel.attrs}
     assert np.asarray(jax.jit(dm.contains)(rows)).all()
+
+
+def _member_case(kind):
+    """(spec, probe rows) of one membership parity case: the relation's
+    own rows first, then rows it lacks."""
+    from repro.core.relation import Relation
+    from repro.kernels.searchsorted import (FENCE_CHUNK, KEY_BLOCK,
+                                            TWO_LEVEL_MIN_CHUNKS)
+    rng = np.random.default_rng(5)
+    if kind == "fp_duplicates":       # the duplicate-window relation
+        n, hi = 500, 4
+    elif kind == "two_levels":
+        n, hi = TWO_LEVEL_MIN_CHUNKS * KEY_BLOCK * FENCE_CHUNK + 300, 1 << 20
+    else:                             # "absent_and_shifted", "empty"
+        n, hi = (0 if kind == "empty" else 3_000), 5_000
+    rel = Relation("R", {"a": rng.integers(0, hi, n),
+                         "b": rng.integers(0, hi, n)})
+    pick = rng.integers(0, n, 700) if n else np.zeros(0, np.int64)
+    own = {a: rel.columns[a][pick] for a in ("a", "b")}
+    rows = {a: np.concatenate([own[a], own[a] + 1009,
+                               rng.integers(0, 2 * hi, 400)])
+            for a in ("a", "b")}
+    return chain_join("J", [rel], []), rows
+
+
+@pytest.mark.parametrize("kind", ["fp_duplicates", "absent_and_shifted",
+                                  "empty", "two_levels"])
+def test_pallas_member_probe_matches_jnp(kind):
+    """The pair-key fence probe answers as the fp1 search and duplicate
+    window, member for member."""
+    import jax, jax.numpy as jnp
+    spec, rows = _member_case(kind)
+    dev = {a: jnp.asarray(c.astype(np.int32)) for a, c in rows.items()}
+    m_jnp = DeviceJoinMembership(spec, use_pallas=False)
+    m_pal = DeviceJoinMembership(spec, use_pallas=True)
+    assert [r.probe.levels for r in m_pal.rels] == [
+        2 if kind == "two_levels" else 1]
+    got_jnp = np.asarray(jax.jit(m_jnp.contains)(dev))
+    got_pal = np.asarray(jax.jit(m_pal.contains)(dev))
+    assert np.array_equal(got_jnp, got_pal)
+    n_own = 0 if kind == "empty" else 700
+    assert got_pal[:n_own].all()
+    assert (kind == "empty") == (not got_pal.any())
+    assert not got_pal[n_own:].all()
+
+
+@pytest.mark.parametrize("present", [True, False])
+def test_pallas_member_probe_at_the_padding_sentinel(present):
+    """A query whose pair key is INT64_MAX, the key blocks' padding, is a
+    member only where a real row has those fingerprints."""
+    import jax.numpy as jnp
+    from repro.core.backends.jax_backend import _fp_contains, _fp_index
+    rng = np.random.default_rng(9)
+    fp1 = rng.integers(0, 1 << 32, 300, dtype=np.uint64).astype(np.uint32)
+    fp2 = rng.integers(0, 1 << 32, 300, dtype=np.uint64).astype(np.uint32)
+    edge = np.array([[0x7FFFFFFF, 0xFFFFFFFE], [0x80000000, 0],
+                     [0xFFFFFFFF, 0xFFFFFFFF], [0, 0]], np.uint32)
+    fp1[:4], fp2[:4] = edge[:, 0], edge[:, 1]
+    if present:
+        fp1[4], fp2[4] = 0x7FFFFFFF, 0xFFFFFFFF
+    q1 = np.concatenate([[0x7FFFFFFF, 0x7FFFFFFF, 0x80000000, 0xFFFFFFFF,
+                          0, 0x7FFFFFFF], fp1]).astype(np.uint32)
+    q2 = np.concatenate([[0xFFFFFFFF, 0xFFFFFFFE, 0, 0xFFFFFFFF, 0, 0],
+                         fp2]).astype(np.uint32)
+    got = {}
+    for use_pallas in (False, True):
+        probe, kmax = _fp_index(fp1, fp2, use_pallas)
+        arrays = probe.arrays() if use_pallas else probe
+        got[use_pallas] = np.asarray(_fp_contains(
+            arrays, kmax, 300, jnp.asarray(q1), jnp.asarray(q2), use_pallas))
+    assert np.array_equal(got[False], got[True])
+    assert list(got[True][:6]) == [present, True, True, True, True, False]
+    assert got[True][6:].all()
+
+
+def test_member_probe_levels_gauge():
+    """repro_engine_member_probe_levels: per relation of each join."""
+    from repro import obs
+    cat, spec = _two_level_chain()
+    reg = obs.MetricsRegistry()
+    prev = obs.set_registry(reg)
+    try:
+        DeviceJoinMembership(spec, use_pallas=True)
+        series = reg.snapshot()["repro_engine_member_probe_levels"]["series"]
+    finally:
+        obs.set_registry(prev)
+    assert series == {(("join", "two_level"), ("relation", r)): lv
+                      for r, lv in (("A", 1), ("B", 2), ("C", 1))}
+
+
+def test_pallas_member_probe_serves_the_same_stream():
+    """A device loop whose membership (and range) probes take the Pallas
+    path serves the rows of the jnp loop for the same seed."""
+    wl = uq1(scale=0.05, overlap=0.5, seed=1, n_joins=3)
+    cover = estimate_union(warmup(wl.cat, wl.joins,
+                                  method="exact").oracle).cover
+    out = []
+    for use_pallas in (False, True):
+        be = JaxBackend(wl.cat, wl.joins, use_pallas=use_pallas)
+        s = SetUnionSampler(wl.cat, wl.joins, cover, seed=4, backend=be,
+                            round_batch=256)
+        out.append(s.sample(1500))
+        assert all(m.use_pallas == use_pallas for m in be.members.values())
+    a, b = out
+    assert np.array_equal(a.home, b.home)
+    for attr in wl.joins[0].output_attrs:
+        assert np.array_equal(a.rows[attr], b.rows[attr]), attr
 
 
 # ---------------------------------------------------------------------------

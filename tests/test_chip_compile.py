@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.searchsorted import (FENCE_CHUNK, KEY_BLOCK, QUERY_TILE,
-                                        _searchsorted_i32, probe_levels)
+                                        _member_probe_i32, _searchsorted_i32,
+                                        probe_levels)
 from repro.kernels.walk import _hop_i32
 
 
@@ -96,6 +97,19 @@ def test_searchsorted_compiles_for_v5e(one_chip, n_keys, n_queries):
 
 
 @pytest.mark.parametrize("n_keys,n_queries", WIDTHS)
+def test_member_probe_compiles_for_v5e(one_chip, n_keys, n_queries):
+    """The membership probe: the range probe's Pallas calls under a name
+    the range probe's trace reduction does not match."""
+    args, levels = _kernel_args(one_chip, n_keys, n_queries, with_u=False)
+    text = _member_probe_i32.lower(*args, n=n_keys,
+                                   interpret=False).compile().as_text()
+    kernels = re.findall(r"^\s*%([\w.-]+) = [^\n]*custom_call_target="
+                         r'"tpu_custom_call"', text, re.M)
+    assert len(kernels) == _custom_calls(text) == 1 + levels
+    assert all(k.startswith("_member_probe_i32") for k in kernels)
+
+
+@pytest.mark.parametrize("n_keys,n_queries", WIDTHS)
 def test_walk_hop_compiles_for_v5e(one_chip, n_keys, n_queries):
     args, levels = _kernel_args(one_chip, n_keys, n_queries, with_u=True)
     text = _hop_i32.lower(*args, interpret=False).compile().as_text()
@@ -134,12 +148,16 @@ def _compile_loop(one_chip, monkeypatch, wl=None, round_batch=256):
 
 
 def _check_probe_kernels(eng, text: str, levels: int):
-    """Every Pallas call is a probe's: ``1 + levels`` per node, each named
-    as the benchmark's trace reduction finds it, a tree node's in
-    ``walk/*`` and a residual node's in ``residual/*``."""
+    """Every Pallas call is a probe's: ``1 + levels`` per tree node, each
+    named as the benchmark's trace reduction finds it, a tree node's in
+    ``walk/*`` and a residual node's in ``residual/*``; and ``1 + levels``
+    per membership index of each earlier piece a piece probes, under the
+    membership probe's own name, in ``member/*``."""
     from collections import Counter
     from repro import obs
+    members = [eng.backend.members[n] for n in eng.order]
     assert {p.levels for t in eng.trees for p in t._prepped} == {levels}
+    assert {r.probe.levels for m in members for r in m.rels} == {levels}
     assert " while(" in text
     # the loop's phase scopes are metadata only: the probe kernels keep the
     # names the benchmark's trace reduction finds them by, and each sits in
@@ -149,15 +167,23 @@ def _check_probe_kernels(eng, text: str, levels: int):
                          r'"tpu_custom_call"', text, re.M)
     kinds = Counter("residual" if c.kind == "residual" else "walk"
                     for t in eng.trees for c in t.node_cfgs)
-    assert all(k.startswith("_searchsorted_i32") for k in kernels)
-    assert Counter(phases[k].split("/")[0] for k in kernels) == Counter(
+    probes = [k for k in kernels if k.startswith("_searchsorted_i32")]
+    member = [k for k in kernels if k.startswith("_member_probe_i32")]
+    assert len(probes) + len(member) == len(kernels)
+    assert Counter(phases[k].split("/")[0] for k in probes) == Counter(
         {kind: (1 + levels) * n for kind, n in kinds.items()})
+    # piece j probes the indexes of the j pieces before it
+    n_member = sum(len(m.rels) * (len(members) - 1 - q)
+                   for q, m in enumerate(members))
+    assert n_member and Counter(phases[k].split("/")[0] for k in member) \
+        == Counter({"member": (1 + levels) * n_member})
 
 
 def test_device_loop_with_pallas_probes_compiles_for_v5e(one_chip,
                                                          monkeypatch):
-    """The Pallas probes sit inside the compiled while loop; at this size
-    every index has one fence chunk, so one level."""
+    """The Pallas probes, range and membership, sit inside the compiled
+    while loop; at this size every index has one fence chunk, so one
+    level."""
     eng, text = _compile_loop(one_chip, monkeypatch)
     _check_probe_kernels(eng, text, levels=1)
 

@@ -128,12 +128,12 @@ def test_sharded_catalog_world1_degenerates_to_device_engine():
         sm = scat.members[j.name]
         assert len(sm.rels) == len(dm.rels)
         for r_s, r_d in zip(sm.rels, dm.rels):
-            assert r_s.attrs == r_d[0]
-            assert r_s.kmax == r_d[3]
+            assert r_s.attrs == r_d.attrs
+            assert r_s.kmax == r_d.kmax
             n = int(np.asarray(r_s.n_owned)[0])
-            assert n == r_d[4]
+            assert n == r_d.n
             np.testing.assert_array_equal(np.asarray(r_s.fp1)[0, :n],
-                                          np.asarray(r_d[1]))
+                                          np.asarray(r_d.probe[0]))
 
 
 def test_sharded_catalog_columns_for_roundtrip():
